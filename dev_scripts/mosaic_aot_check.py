@@ -1,12 +1,12 @@
 """Deviceless Mosaic compile check: every Pallas kernel variant is
 AOT-compiled for TPU v5e with the LOCAL libtpu compiler — no chip, no
-tunnel, no interpret-mode proxy.
+interpret-mode proxy.
 
 `jax.experimental.topologies.get_topology_desc("v5e:2x2")` builds a
 compile-only PJRT client from the libtpu bundled in this image, and
 `jax.jit(...).lower(...).compile()` against its abstract devices runs
-the REAL Mosaic lowering + TPU backend compile. This closes the gap
-VERDICT r4 weak #1 named: interpret-mode parity proves semantics, not
+the REAL Mosaic lowering + TPU backend compile. Interpret-mode parity
+proves semantics, not
 that Mosaic legalizes the kernel (it immediately caught a real one:
 vector-valued `scf.if` from the line-search tail's `lax.cond` fails to
 legalize — now KERNEL.md constraint #6, fixed as a 0/1-trip
@@ -272,8 +272,7 @@ def main() -> int:
             lambda f, u: f.rmatvec(u)).lower(feats, arg((n_r,))).compile()
 
     # Prefix match, not reversed substring membership: `any(s in "sortperm")`
-    # would let selectors like "t" or "o" silently enable unrelated groups
-    # (ADVICE r5).
+    # would let selectors like "t" or "o" silently enable unrelated groups.
     if not selected or any("sortperm".startswith(s) for s in selected):
         run_group(sortperm_checks())
 

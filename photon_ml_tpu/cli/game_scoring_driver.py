@@ -40,6 +40,7 @@ from pathlib import Path
 import numpy as np
 
 from photon_ml_tpu import telemetry
+from photon_ml_tpu.cli import device_summary
 from photon_ml_tpu.cli.obs import DriverObservability, add_observability_args
 from photon_ml_tpu.data.avro_reader import read_game_dataset
 from photon_ml_tpu.evaluation import build_evaluator
@@ -47,6 +48,7 @@ from photon_ml_tpu.io import schemas
 from photon_ml_tpu.io.avro_codec import write_container
 from photon_ml_tpu.io.model_io import load_game_model
 from photon_ml_tpu.telemetry import span
+from photon_ml_tpu.utils.compile_cache import enable_compile_cache
 from photon_ml_tpu.utils.date_range import resolve_input_dirs
 from photon_ml_tpu.utils.logging_utils import setup_photon_logger
 
@@ -195,9 +197,7 @@ def _device_scores(model, data, logger):
 
 
 def run(argv=None) -> dict:
-    from photon_ml_tpu.cli import _honor_jax_platforms_env
-
-    _honor_jax_platforms_env()
+    enable_compile_cache()
     _maybe_enable_cpu_x64()
     args = build_parser().parse_args(argv)
     out_dir = Path(args.output_dir)
@@ -232,6 +232,7 @@ def run(argv=None) -> dict:
 
         wall = time.perf_counter() - t0
         summary["total_seconds"] = wall
+        summary["device"] = device_summary()
         _apply_legacy_aliases(summary)
         obs.finish(summary)
         summary["telemetry"] = telemetry.attribution_summary(wall)

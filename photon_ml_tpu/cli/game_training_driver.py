@@ -21,6 +21,7 @@ import time
 from pathlib import Path
 
 from photon_ml_tpu import telemetry
+from photon_ml_tpu.cli import device_summary
 from photon_ml_tpu.cli.obs import DriverObservability, add_observability_args
 from photon_ml_tpu.data.avro_reader import read_game_dataset
 from photon_ml_tpu.data.random_effect import RandomEffectDataConfiguration
@@ -38,6 +39,7 @@ from photon_ml_tpu.optimization.config import (
 )
 from photon_ml_tpu.telemetry import span
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils.compile_cache import enable_compile_cache
 from photon_ml_tpu.utils.date_range import resolve_input_dirs
 from photon_ml_tpu.utils.events import (
     EventEmitter,
@@ -309,9 +311,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def run(argv=None) -> dict:
-    from photon_ml_tpu.cli import _honor_jax_platforms_env
-
-    _honor_jax_platforms_env()
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     out_dir = Path(args.output_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
@@ -679,6 +679,7 @@ def _write_summary(args, out_dir, logger, task, sequence, t0, results,
         "coordinateSeconds": best_result.timings,
         "totalSeconds": wall,
         "total_seconds": wall,
+        "device": device_summary(),
     }
     if stream_info is not None:
         # ``stream_train`` is the canonical snake_case schema; the

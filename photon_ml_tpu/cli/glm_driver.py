@@ -19,6 +19,7 @@ from pathlib import Path
 
 import numpy as np
 
+from photon_ml_tpu.cli import device_summary
 from photon_ml_tpu.data.avro_reader import read_labeled_points
 from photon_ml_tpu.data.index_map import IdentityIndexMap, IndexMap
 from photon_ml_tpu.data.libsvm import read_libsvm
@@ -57,6 +58,7 @@ from photon_ml_tpu.utils import (
     TrainingFinishEvent,
     TrainingStartEvent,
 )
+from photon_ml_tpu.utils.compile_cache import enable_compile_cache
 from photon_ml_tpu.utils.events import EventEmitter
 from photon_ml_tpu.utils.logging_utils import setup_photon_logger
 from photon_ml_tpu.utils.profiling import maybe_trace
@@ -317,9 +319,7 @@ def _run_diagnostics(mode, out_dir, task, trained, metrics_by_lambda,
 
 
 def run(argv=None) -> dict:
-    from photon_ml_tpu.cli import _honor_jax_platforms_env
-
-    _honor_jax_platforms_env()
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     out_dir = Path(args.output_directory)
     out_dir.mkdir(parents=True, exist_ok=True)
@@ -531,6 +531,7 @@ def run(argv=None) -> dict:
                               for k, v in metrics_by_lambda.items()},
         "phaseSeconds": timer.phases,
         "totalSeconds": duration,
+        "device": device_summary(),
     }
     (out_dir / "summary.json").write_text(json.dumps(summary, indent=2))
     emitter.send_event(TrainingFinishEvent(args.job_name, duration))

@@ -1,8 +1,8 @@
 """Block-streaming GameDataset feeder: bounded-memory, C-decoded, prefetched.
 
 The serving engine dispatches streamed scoring at ~10x the rate the
-pure-python avro record loop can feed it (BENCH_full.json
-`extra.serving.batch_curve` vs the ~13k rows/s record path), so `--stream`
+pure-python avro record loop can feed it (bench
+`extra.serving.batch_curve` vs the record path), so `--stream`
 scoring was feeder-bound. This module closes that gap with the same two
 mechanisms the training ingest already uses, re-pointed at bounded batches
 instead of whole files:
@@ -533,7 +533,7 @@ def read_game_dataset_via_blocks(
     paths — the same `_ColumnBuffer.take` contract the per-batch identity
     tests pin down). This is `read_game_dataset`'s single-process fast
     path: the block decode runs ~3x the generic C datum-decode record
-    loop (BENCH_full.json `extra.stream_scoring`), and it makes the block
+    loop (bench `extra.stream_scoring`), and it makes the block
     path the ONE C decode implementation for both streamed and one-shot
     reads. Returns None when the native path does not apply (extension
     unbuilt, schema mismatch) — callers fall back as before."""

@@ -284,6 +284,9 @@ def _run_workers(n_workers: int, shards, init: dict):
     pkg_root = str(Path(__file__).resolve().parents[2])
     env = dict(os.environ)
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+    # Decoder workers never need a device, and the parent may hold the
+    # one chip: whatever they import, no backend but the CPU's can start.
+    env["JAX_PLATFORMS"] = "cpu"
 
     procs = []
     err_files = []

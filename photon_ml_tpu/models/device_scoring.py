@@ -12,8 +12,8 @@ GAME scoring CLI; `GameModel.score` (host numpy) remains for final Avro
 writes and one-off host scoring.
 
 All static data is passed to the jitted function as ARGUMENTS, never
-captured in the closure: closed-over device constants measured ~25-50ms of
-extra per-call latency on a remote-TPU backend.
+captured in the closure: closed-over device constants are baked into the
+executable and re-staged on every call.
 """
 
 from __future__ import annotations

@@ -3,8 +3,9 @@
 The only native-ish dependency of the reference is BLAS-under-Breeze plus
 PalDB (SURVEY §2 preamble) — its decode hot path runs on the JVM. Here the
 device math is XLA; the host-side ingest is where native code pays, so the
-Avro datum decoder is a C extension (_avro_native.c). Everything degrades
-gracefully: if no C compiler is available the pure-python codec is used.
+Avro datum decoder is a C extension (_avro_native.c). If it cannot be
+built or loaded the pure-python codec is used, and a warning says so once
+per process.
 
 Set PHOTON_ML_TPU_NO_NATIVE=1 to force the pure-python paths.
 """
@@ -16,6 +17,7 @@ import logging
 import os
 import subprocess
 import sysconfig
+import tempfile
 from pathlib import Path
 from typing import Optional
 
@@ -30,20 +32,27 @@ def _compile(src: Path, out: Path) -> bool:
     cc = sysconfig.get_config_var("CC") or "cc"
     include = sysconfig.get_paths()["include"]
     out.parent.mkdir(parents=True, exist_ok=True)
-    tmp = out.with_suffix(out.suffix + ".tmp")
+    # Each builder writes a file of its own and renames it into place:
+    # processes that find no .so at the same moment (test workers, decoder
+    # workers after chip_smoke.py removed _build/) race harmlessly.
+    fd, tmp = tempfile.mkstemp(dir=out.parent, suffix=out.suffix + ".tmp")
+    os.close(fd)
     cmd = [cc.split()[0], "-O2", "-shared", "-fPIC", f"-I{include}",
-           str(src), "-o", str(tmp)]
+           str(src), "-o", tmp]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True,
                              timeout=120)
+        if res.returncode != 0:
+            logger.warning("native build failed:\n%s", res.stderr)
+            return False
+        os.replace(tmp, out)
+        return True
     except (OSError, subprocess.TimeoutExpired) as e:
-        logger.debug("native build failed to launch: %s", e)
+        logger.warning("native build failed to launch: %s", e)
         return False
-    if res.returncode != 0:
-        logger.debug("native build failed:\n%s", res.stderr)
-        return False
-    os.replace(tmp, out)  # atomic: concurrent builders race harmlessly
-    return True
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def load_avro_native() -> Optional[object]:
@@ -69,6 +78,7 @@ def load_avro_native() -> Optional[object]:
         _module = mod
         logger.debug("native avro decoder loaded from %s", so)
     except Exception as e:  # noqa: BLE001 — fall back to pure python
-        logger.debug("native avro decoder unavailable: %s", e)
+        logger.warning("native avro decoder unavailable, decoding in pure "
+                       "python: %s", e)
         _module = None
     return _module

@@ -72,6 +72,20 @@ _CAUTIOUS_EPS = 1e-10
 DEFAULT_M = 10
 DEFAULT_MAX_LINE_SEARCH = 30
 
+# The routing guard (algorithm/coordinates.py) sends a bucket to the
+# kernel only while entity_solver_vmem_bytes stays under this.
+VMEM_GUARD_BYTES = 10 << 20
+
+# Scoped-VMEM limit handed to Mosaic. Its default on v5e is 16 MiB, and
+# the kernel's real working set — the window buffers
+# entity_solver_vmem_bytes estimates, plus Mosaic's own stack for the
+# unrolled row loop and the per-row line-search blocks — runs 2-4x that
+# estimate: at the default the v5e compiler refused buckets the routing
+# guard admits (r=256 d=32, r=512 d=8, r=4 d=512). Half of v5e's 128 MiB
+# VMEM compiles everything VMEM_GUARD_BYTES lets through;
+# tests/test_mosaic_aot.py pins that boundary with the real compiler.
+VMEM_LIMIT_BYTES = 64 << 20
+
 
 def entity_solver_vmem_bytes(
     r: int, d: int, itemsize: int, *, m: int = DEFAULT_M,
@@ -83,7 +97,8 @@ def entity_solver_vmem_bytes(
     friends, the [T, 128] line-search block, and the [r, 128] vectors.
     Normalization adds double-buffered factor/shift tiles; bounds add
     lower/upper tiles. Keep callers' eligibility checks on THIS function
-    so the guard and the kernel cannot disagree about the working set."""
+    so the guard and the kernel cannot disagree about the working set.
+    What Mosaic really allocates is 2-4x this (see VMEM_LIMIT_BYTES)."""
     units = 2 * r * d + 2 * m * d + 8 * d + 8 * r + 2 * (max_line_search + 1)
     units += 2  # scalars / slack
     if normalized:
@@ -970,6 +985,8 @@ def pallas_entity_lbfgs(
         ] + [bspec(d) for _ in extra_inputs],
         out_specs=(bspec(d), bspec(1), bspec(1), bspec(1), bspec(1)),
         out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.asarray(l2_weight, dtype).reshape(1),
       jnp.asarray(l1_weight, dtype).reshape(1),

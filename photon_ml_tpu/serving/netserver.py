@@ -538,7 +538,6 @@ class NetServer:
             return
         self._closing = True
         self._server.close()
-        await self._server.wait_closed()
         for conn in list(self._conns):
             # EOF-from-within: readers blocked on the next frame wake
             # with a clean end-of-stream; readers mid-request finish
@@ -547,6 +546,9 @@ class NetServer:
         tasks = [c.task for c in list(self._conns) if c.task is not None]
         if tasks:
             await asyncio.gather(*tasks, return_exceptions=True)
+        # Last: on Python 3.12 wait_closed() returns only once every
+        # connection is gone, so it can only follow the drain.
+        await self._server.wait_closed()
         self._server = None
 
     # -- accounting --------------------------------------------------------

@@ -133,12 +133,14 @@ class ReplicaRouter:
         if self._server is None:
             return
         self._server.close()
-        await self._server.wait_closed()
         for task in list(self._conns):
             task.cancel()
         if self._conns:
             await asyncio.gather(*list(self._conns),
                                  return_exceptions=True)
+        # After the handlers: on Python 3.12 wait_closed() returns only
+        # once every client connection is gone.
+        await self._server.wait_closed()
         for b in self.backends:
             if b.pump is not None:
                 b.pump.cancel()

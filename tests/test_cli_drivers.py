@@ -87,6 +87,7 @@ def test_glm_driver_avro_end_to_end(tmp_path, rng):
     assert summary["stages"] == ["INIT", "PREPROCESSED", "TRAINED",
                                  "VALIDATED"]
     assert summary["bestLambda"] in (10.0, 1.0, 0.1)
+    assert summary["device"]["platform"] == "cpu"
     assert (out / "best-model" / "model.txt").exists()
     assert (out / "best-model" / "model.avro").exists()
     assert (out / "log-message.txt").exists()
@@ -152,6 +153,27 @@ def test_glm_driver_normalization_and_constraints(tmp_path, rng):
     assert "TRAINED" in summary["stages"]
 
 
+def test_compile_cache_goes_where_the_environment_says(monkeypatch,
+                                                        tmp_path):
+    """JAX_COMPILATION_CACHE_DIR decides when set — no directory is set
+    in code — and otherwise the cache is <checkout>/.jax_cache."""
+    import pathlib
+
+    import jax
+
+    from photon_ml_tpu.utils.compile_cache import enable_compile_cache
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in updates
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    assert enable_compile_cache() == str(checkout / ".jax_cache")
+    assert updates["jax_compilation_cache_dir"] == str(checkout / ".jax_cache")
+
+
 def test_game_pipeline_train_then_score(tmp_path, rng):
     train = tmp_path / "train"
     valid = tmp_path / "valid"
@@ -179,6 +201,9 @@ def test_game_pipeline_train_then_score(tmp_path, rng):
     assert summary["numCombos"] == 1
     assert len(summary["validationHistory"]) == 2
     assert summary["validationHistory"][-1]["AUC"] > 0.6
+    # No number is read without its device (8 virtual CPU devices here).
+    cpu8 = {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert json.loads((out / "metrics.json").read_text())["device"] == cpu8
     assert (out / "best" / "model-metadata.json").exists()
     assert (out / "best" / "feature-indexes" / "global.json").exists()
 
@@ -190,6 +215,8 @@ def test_game_pipeline_train_then_score(tmp_path, rng):
         "--evaluators", "AUC",
     ])
     assert score_summary["numRows"] == 150
+    assert json.loads(
+        (score_out / "metrics.json").read_text())["device"] == cpu8
     # Scoring the same validation data reproduces the training-time AUC.
     np.testing.assert_allclose(
         score_summary["metrics"]["AUC"],

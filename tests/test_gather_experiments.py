@@ -104,8 +104,8 @@ def test_variant_args_rolls_named_arrays_together(monkeypatch):
 
 def test_variant_args_forces_nonzero_effective_shift(monkeypatch):
     """A raw shift that is a MULTIPLE of the rolled axis length must not
-    degrade to an identity roll (that would re-open the same-args caching
-    hole): the effective shift falls back to 1 (ADVICE r5)."""
+    degrade to an identity roll (the "distinct" rep would repeat the
+    warm-up): the effective shift falls back to 1."""
     import jax.numpy as jnp
 
     import dev_scripts.gather_experiments as ge

@@ -1,8 +1,7 @@
 """Parity: normalization and box constraints folded into the fused
 Pallas entity kernel vs the vmapped host path.
 
-Closes VERDICT r3 weak #4 — STANDARDIZATION
-(NormalizationContext.scala:38-83) and box constraints
+STANDARDIZATION (NormalizationContext.scala:38-83) and box constraints
 (OptimizationUtils.scala:53) are first-class reference features on
 random-effect problems (RandomEffectOptimizationProblem.scala:105-125);
 they must keep the kernel path, not silently shed it. All kernel runs

@@ -1,4 +1,4 @@
-"""Two-process jax.distributed test on localhost (VERDICT Missing #4).
+"""Two-process jax.distributed test on localhost.
 
 The reference proves its multi-node paths with Spark local[4]
 (photon-test-utils/.../SparkTestUtils.scala:55-70) — threads standing in

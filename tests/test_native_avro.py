@@ -270,3 +270,26 @@ def test_varint_extremes():
     out = native.decode_block(buf.getvalue(), len(vals), prog.prog,
                               prog.root, prog.strings)
     assert out == vals
+
+
+def test_load_failure_warns_once_per_process(monkeypatch, caplog):
+    """A decoder that cannot be built or loaded is not silent: one
+    warning, then the pure-python fallback without repeating it."""
+    import logging
+
+    import photon_ml_tpu.native as nat
+
+    monkeypatch.setattr(nat, "_loaded", False)
+    monkeypatch.setattr(nat, "_module", None)
+
+    def broken_loader(*_a, **_k):
+        raise OSError("no such shared object")
+
+    monkeypatch.setattr(nat.importlib.util, "spec_from_file_location",
+                        broken_loader)
+    with caplog.at_level(logging.WARNING, logger=nat.__name__):
+        assert nat.load_avro_native() is None
+        assert nat.load_avro_native() is None
+    warnings = [r for r in caplog.records if r.levelno == logging.WARNING]
+    assert len(warnings) == 1
+    assert "pure python" in warnings[0].getMessage()

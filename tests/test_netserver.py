@@ -740,6 +740,9 @@ def test_router_cold_start_concurrent_clients_one_conn_per_backend():
                         await writer.drain()
                 except (asyncio.IncompleteReadError, ConnectionError):
                     pass
+                finally:
+                    # 3.12: Server.wait_closed() waits for this transport
+                    writer.close()
             return handle
 
         backends = [await asyncio.start_server(
@@ -816,6 +819,32 @@ def test_router_backend_death_is_typed_internal_error():
     assert [e.kind for e in errs] == ["internal", "internal"]
     assert "backend connection lost" in errs[0].message
     assert st["backend_errors"] == 2 and st["forwarded"] == 2
+
+
+def test_router_close_returns_with_a_client_attached():
+    """``close()`` with a client still connected must return: on Python
+    3.12 ``Server.wait_closed()`` waits for every connection, so the
+    handlers are wound up first."""
+
+    async def main():
+        async def echo_ok(reader, writer):
+            writer.close()
+
+        backend = await asyncio.start_server(
+            echo_ok, host="127.0.0.1", port=0)
+        port = backend.sockets[0].getsockname()[1]
+        router = await ReplicaRouter([("127.0.0.1", port)]).start()
+        r, w = await asyncio.open_connection("127.0.0.1", router.port)
+        await asyncio.sleep(0.05)  # the router has accepted the client
+        try:
+            await asyncio.wait_for(router.close(), timeout=10)
+            assert await asyncio.wait_for(r.read(), timeout=10) == b""
+        finally:
+            w.close()
+            backend.close()
+            await asyncio.wait_for(backend.wait_closed(), timeout=10)
+
+    asyncio.run(main())
 
 
 def test_router_rejects_malformed_magic():

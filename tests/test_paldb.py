@@ -1,4 +1,4 @@
-"""PalDB 1.1 read-only store interop (VERDICT r2 item 4).
+"""PalDB 1.1 read-only store interop.
 
 The reference's feature-index stores are PalDB (ml/util/PalDBIndexMap.scala:
 43-220, built by ml/FeatureIndexingJob.scala:145-174); its GAME integ
@@ -234,7 +234,7 @@ def test_glm_driver_accepts_offheap_indexmap_dir(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Writer (VERDICT r3 missing #1): write -> read round trip + layout parity
+# Writer: write -> read round trip + layout parity
 # with the reference's own fixture structure.
 # ---------------------------------------------------------------------------
 

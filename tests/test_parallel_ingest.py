@@ -266,3 +266,26 @@ def test_column_consumer_sees_rows_in_order(training_file):
     assert [s for s, _ in seen] == sorted(s for s, _ in seen)
     np.testing.assert_array_equal(
         np.concatenate([a for _, a in seen]), res.labels)
+
+
+def test_workers_are_held_to_the_cpu(training_file, monkeypatch):
+    """Decoder workers never need a device and the parent may hold the
+    one chip: whatever the parent's environment says, they start with
+    JAX_PLATFORMS=cpu."""
+    import subprocess
+
+    from photon_ml_tpu.data.avro_reader import build_index_map
+
+    imap = build_index_map(training_file, ingest_workers=1)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    real_popen, seen = subprocess.Popen, []
+
+    def recording_popen(*args, **kwargs):
+        seen.append(kwargs["env"]["JAX_PLATFORMS"])
+        return real_popen(*args, **kwargs)
+
+    monkeypatch.setattr(subprocess, "Popen", recording_popen)
+    res = parallel_fast_ingest(
+        [str(training_file)], {"global": imap},
+        {"global": imap.intercept_index}, workers=2)
+    assert res is not None and seen == ["cpu", "cpu"]
