@@ -1,0 +1,627 @@
+#!/usr/bin/env python3
+"""Where a fit's device time goes, by the program's own names.
+
+    python3 dev_scripts/trace_scopes.py --workload glmix.fit --seed <n>
+    python3 dev_scripts/trace_scopes.py --from-json <recorded.json>
+
+Builds the benchmark cell's job (``benchmark/`` is imported read-only: the
+cell, its recipe and ``jobs/cd_fit.py``), warms it up, traces ``--jobs``
+jobs in a profiler session of its own, reads the ``.xplane.pb`` KEEPING
+each device event's scope path (the ``op_name`` of the HLO metadata, which
+``jax.named_scope`` writes and the benchmark's ``trace_reduce.flatten``
+drops), and prints per job:
+
+- device ms by scope of ``photon_ml_tpu/telemetry/scopes.py``: an operation
+  counts under the innermost table scope on its path; a scope's time is the
+  union of its operations' intervals; the size classes ``r<rows>`` are
+  listed under ``photon.re.solve`` with the path each took;
+- the same rolled up by ``photon.cd.<coordinate>``;
+- the exchange (gather + margins + scatter);
+- the remainder under no ``photon.*`` scope, with its largest operations;
+- every idle gap over ``--gap-ms`` with the innermost ``photon.cd.*`` host
+  span that covers it (``bench.*`` where none does).
+
+Where the path lives (looked at by hand on the v5e, JAX 0.9.0, PR 29): in
+the stat ``tf_op`` of the event's METADATA (one per HLO instruction of a
+program: ``jit(cd_block)/while/body/closed_call/photon.cd.perUser/
+jit(_solve_block)/photon.re.solve/r32/.../pallas_call:``), not of the
+event, whose own stats are ``device_offset_ps``, ``device_duration_ps``
+and ``Time Scale Multiplier``. ``jax.profiler.ProfileData`` shows only the
+event's own stats and names an event by its metadata's name, which two
+programs can share, so this script reads the file's protobuf wire format
+itself (``read_xspace``: six message types, no dependency).
+
+The persistent compile cache's key leaves metadata out, so a program
+compiled before a scope was named is loaded with its OLD names (seen on
+the chip, PR 29: ``jit(_re_score_impl)`` came from an older checkout's
+entry, without ``photon.re.scatter``). This script therefore turns the
+persistent cache off for its own process: it compiles what it traces,
+with the tree's names, and leaves the machine's cache as it found it.
+
+``--save-trace`` keeps the flattened trace with its paths (one job with
+``--cut-jobs 1``: the recorded trace of ``tests/test_fit_tracing.py``);
+``--dump-stats N`` prints every stat of the N longest device events, to
+see by hand which one carries the path on a new chip or JAX.
+
+Wiring the same reduction into the benchmark's ``ctx`` is the next
+``benchmark`` issue's (PERF.md §7); this script edits nothing there.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import re
+import struct
+import sys
+from pathlib import Path
+from typing import Dict, Iterable, List, Tuple
+
+ROOT = Path(__file__).resolve().parent.parent
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from benchmark.trace_reduce import (  # noqa: E402
+    DEVICE_PLANE_PREFIXES,
+    JOB_SPAN,
+    OPS_LINE,
+    clip,
+    short_name,
+    total,
+    union,
+)
+from photon_ml_tpu.telemetry import scopes  # noqa: E402
+
+HOST_SPAN_PREFIXES = (scopes.PREFIX, "bench.")
+# The stat that carries the HLO metadata's op_name (``tf_op`` on the v5e,
+# PERF.md §5); any other stat whose value holds a ``photon.`` scope is
+# taken where a later profiler renames it.
+PATH_STATS = ("tf_op", "op_name")
+_SIZE_CLASS = re.compile(r"^r\d+$")
+
+Interval = Tuple[int, int]
+
+
+# -- from the profiler's file to plain data, paths kept -----------------------
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message: an int for a varint,
+    a memoryview for a length-delimited or fixed field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        else:
+            if wire == 2:
+                size, i = _varint(buf, i)
+            elif wire in (1, 5):
+                size = 8 if wire == 1 else 4
+            else:
+                raise ValueError(f"wire type {wire} at byte {i}")
+            value, i = buf[i:i + size], i + size
+        yield key >> 3, value
+
+
+def _stat(buf) -> Tuple[int, object, bool]:
+    """XStat -> (metadata id, value, whether the value is a ``ref_value``:
+    the id of the stat metadata whose name is the string meant)."""
+    ident, value, ref = 0, None, False
+    for field, v in _fields(buf):
+        if field == 1:
+            ident = v
+        elif field == 2:
+            value = struct.unpack("<d", v)[0]
+        elif field in (3, 4, 7):
+            value, ref = v, field == 7
+        elif field == 5:
+            value = bytes(v).decode("utf-8", "replace")
+        elif field == 6:
+            value = bytes(v)
+    return ident, value, ref
+
+
+def _map_entry(buf) -> Tuple[int, memoryview]:
+    key, value = 0, memoryview(b"")
+    for field, v in _fields(buf):
+        if field == 1:
+            key = v
+        elif field == 2:
+            value = v
+    return key, value
+
+
+def read_xspace(path: Path) -> List[dict]:
+    """The planes of an ``.xplane.pb`` (tsl/profiler/protobuf/xplane.proto):
+    ``{"name", "lines": [{"name", "events": [{"name", "start_ns",
+    "duration_ns", "stats", "metadata_stats"}]}]}``, stats by name. An
+    event's start is its line's ``timestamp_ns`` plus its ``offset_ps``,
+    as ``ProfileData`` has it: all planes share that clock."""
+    planes = []
+    for field, plane_buf in _fields(memoryview(Path(path).read_bytes())):
+        if field != 1:
+            continue
+        name, line_bufs, metadata, stat_names = "", [], {}, {}
+        for f, v in _fields(plane_buf):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f == 3:
+                line_bufs.append(v)
+            elif f == 4:
+                ident, md = _map_entry(v)
+                entry = {"name": "", "stats": []}
+                for mf, mv in _fields(md):
+                    if mf == 2:
+                        entry["name"] = bytes(mv).decode("utf-8", "replace")
+                    elif mf == 5:
+                        entry["stats"].append(_stat(mv))
+                metadata[ident] = entry
+            elif f == 5:
+                ident, md = _map_entry(v)
+                stat_names[ident] = next(
+                    (bytes(mv).decode() for mf, mv in _fields(md)
+                     if mf == 2), "")
+
+        def named(stats):
+            return {stat_names.get(i, str(i)):
+                    stat_names.get(v, v) if ref else v
+                    for i, v, ref in stats}
+
+        for entry in metadata.values():
+            entry["stats"] = named(entry["stats"])
+        lines = []
+        for line_buf in line_bufs:
+            line_name, t0_ns, events = "", 0, []
+            for f, v in _fields(line_buf):
+                if f == 2:
+                    line_name = bytes(v).decode()
+                elif f == 3:
+                    t0_ns = v
+                elif f == 4:
+                    ident = offset_ps = duration_ps = 0
+                    stats = []
+                    for ef, ev in _fields(v):
+                        if ef == 1:
+                            ident = ev
+                        elif ef == 2:
+                            offset_ps = ev
+                        elif ef == 3:
+                            duration_ps = ev
+                        elif ef == 4:
+                            stats.append(_stat(ev))
+                    events.append((ident, offset_ps, duration_ps, stats))
+            lines.append({"name": line_name, "events": [
+                {"name": metadata.get(i, {"name": ""})["name"],
+                 "start_ns": t0_ns + off // 1000,
+                 "duration_ns": dur // 1000, "stats": named(st),
+                 "metadata_stats": metadata.get(i, {"stats": {}})["stats"]}
+                for i, off, dur, st in events]})
+        planes.append({"name": name, "lines": lines})
+    return planes
+
+
+def path_of(stats: dict) -> str:
+    """The scope path among an event's (metadata's) stats, without the
+    colon the profiler ends it with."""
+    for key in PATH_STATS:
+        value = stats.get(key)
+        if isinstance(value, str) and (scopes.PREFIX in value
+                                       or "jit(" in value):
+            return value.rstrip(":")
+    for value in stats.values():  # a stat this list does not know yet
+        if isinstance(value, str) and scopes.PREFIX in value:
+            return value.rstrip(":")
+    return ""
+
+
+def flatten_with_paths(planes: List[dict]) -> dict:
+    """``read_xspace``'s planes -> ``{"planes": [{"name", "lines":
+    [{"name", "events": [[name, start_ns, duration_ns, path], ...]}]}]}``:
+    ``benchmark/trace_reduce.flatten``'s form with a fourth field; of the
+    host's events only the ``photon.*`` and ``bench.*`` spans."""
+    out = []
+    for plane in planes:
+        device = plane["name"].startswith(DEVICE_PLANE_PREFIXES)
+        lines = []
+        for line in plane["lines"]:
+            keep_path = device and line["name"] == OPS_LINE
+            lines.append({"name": line["name"], "events": [
+                [ev["name"], ev["start_ns"], ev["duration_ns"],
+                 path_of({**ev["metadata_stats"], **ev["stats"]})
+                 if keep_path else ""]
+                for ev in line["events"]
+                if device or ev["name"].startswith(HOST_SPAN_PREFIXES)]})
+        out.append({"name": plane["name"], "lines": lines})
+    return {"planes": out}
+
+
+def pack(trace: dict) -> dict:
+    """Names and paths through one string table: a job's trace in ~0.5 MB."""
+    table: Dict[str, int] = {}
+
+    def ix(s: str) -> int:
+        return table.setdefault(s, len(table))
+
+    planes = [{"name": p["name"], "lines": [
+        {"name": ln["name"],
+         "events": [[ix(n), s, d, ix(path)] for n, s, d, path in ln["events"]]}
+        for ln in p["lines"]]} for p in trace["planes"]]
+    return {"strings": list(table), "planes": planes,
+            **{k: v for k, v in trace.items() if k != "planes"}}
+
+
+def unpack(packed: dict) -> dict:
+    if "strings" not in packed:
+        return packed
+    strings = packed["strings"]
+    planes = [{"name": p["name"], "lines": [
+        {"name": ln["name"],
+         "events": [[strings[n], s, d, strings[path]]
+                    for n, s, d, path in ln["events"]]}
+        for ln in p["lines"]]} for p in packed["planes"]]
+    return {**{k: v for k, v in packed.items() if k != "strings"},
+            "planes": planes}
+
+
+def cut_jobs(trace: dict, jobs: int) -> dict:
+    """Only what overlaps the first ``jobs`` ``bench.job`` spans, times
+    moved so the first starts at 0 (for a small recorded trace)."""
+    spans = job_spans(trace)[:jobs]
+    if not spans:
+        return trace
+    lo, hi = spans[0][0], spans[-1][1]
+    planes = [{"name": p["name"], "lines": [
+        {"name": ln["name"],
+         "events": [[n, s - lo, d, path] for n, s, d, path in ln["events"]
+                    if s + d > lo and s < hi]}
+        for ln in p["lines"]]} for p in trace["planes"]]
+    return {**trace, "planes": planes}
+
+
+# -- intervals: union, clip and total are benchmark/trace_reduce.py's ---------
+
+def covered_ms(intervals: Iterable[Interval], lo: int, hi: int) -> float:
+    return total(clip(union(intervals), lo, hi)) / 1e6
+
+
+# -- an operation's place in the table ----------------------------------------
+
+def place(path: str) -> dict:
+    """``leaf``: the innermost table scope on the path; ``coordinate``: its
+    ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
+    ``photon.re.solve``; ``scoped``: under any ``photon.*`` at all."""
+    leaf = coordinate = size_class = None
+    parts = path.split("/")
+    for i, part in enumerate(parts):
+        if part in scopes.DEVICE_SCOPES:
+            leaf = part
+            if (part == scopes.RE_SOLVE and i + 1 < len(parts)
+                    and _SIZE_CLASS.match(parts[i + 1])):
+                size_class = parts[i + 1]
+        elif part.startswith(scopes.cd_coordinate("")):
+            coordinate = part
+    return {"leaf": leaf, "coordinate": coordinate, "size_class": size_class,
+            "scoped": leaf is not None or coordinate is not None}
+
+
+def job_spans(trace: dict) -> List[Tuple[int, int, str]]:
+    return host_spans(trace, lambda n: n == JOB_SPAN)
+
+
+def host_spans(trace: dict, want) -> List[Tuple[int, int, str]]:
+    out = []
+    for plane in trace["planes"]:
+        if plane["name"].startswith(DEVICE_PLANE_PREFIXES):
+            continue
+        for line in plane["lines"]:
+            out += [(s, s + d, n) for n, s, d, _ in line["events"]
+                    if want(n)]
+    return sorted(out)
+
+
+def phase_of(spans: List[Tuple[int, int, str]], lo: int, hi: int) -> str:
+    """The innermost host span covering [lo, hi): the shortest of those
+    that overlap at least half of it; where none does, the one that
+    overlaps it most."""
+    covering, most = [], (0, "outside photon and bench spans")
+    for a, b, name in spans:
+        cover = min(b, hi) - max(a, lo)
+        if 2 * cover >= hi - lo:
+            covering.append((b - a, name))
+        most = max(most, (cover, name))
+    return min(covering)[1] if covering else most[1]
+
+
+# -- the reduction -------------------------------------------------------------
+
+def reduce_job(events: List[list], spans, lo: int, hi: int,
+               gap_ms: float) -> dict:
+    by_leaf: Dict[str, list] = {}
+    by_coord: Dict[str, list] = {}
+    by_class: Dict[str, dict] = {}
+    scoped, everything, loose = [], [], {}
+    for name, s, d, path in events:
+        if s + d <= lo or s >= hi or d <= 0:
+            continue
+        iv = (s, s + d)
+        everything.append(iv)
+        where = place(path)
+        if not where["scoped"]:
+            n = short_name(name)
+            loose.setdefault(n, []).append(iv)
+            continue
+        scoped.append(iv)
+        if where["leaf"]:
+            by_leaf.setdefault(where["leaf"], []).append(iv)
+        if where["coordinate"]:
+            by_coord.setdefault(where["coordinate"], []).append(iv)
+        if where["size_class"]:
+            cls = by_class.setdefault(where["size_class"],
+                                      {"ivs": [], "kernel": False})
+            cls["ivs"].append(iv)
+            if short_name(name).lstrip("%").startswith(scopes.KERNEL):
+                cls["kernel"] = True
+    busy = clip(union(everything), lo, hi)
+    busy_ms = total(busy) / 1e6
+    scoped_ms = covered_ms(scoped, lo, hi)
+    # Operations under no scope that run while a scoped one does (a scan's
+    # ``while`` around everything) take nothing from the remainder.
+    scoped_union = clip(union(scoped), lo, hi)
+    loose_ops = {}
+    for n, ivs in loose.items():
+        mine = clip(union(ivs), lo, hi)
+        loose_ops[n] = (total(mine)
+                        - total(_intersect(mine, scoped_union))) / 1e6
+    edges = [lo] + [t for iv in busy for t in iv] + [hi]
+    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] - edges[i] > gap_ms * 1e6]
+    scope_ms = {s: covered_ms(by_leaf.get(s, []), lo, hi)
+                for s in scopes.DEVICE_SCOPES}
+    return {
+        "window_ms": (hi - lo) / 1e6, "busy_ms": busy_ms,
+        "scope_ms": scope_ms,
+        "exchange_ms": sum(scope_ms[s] for s in scopes.EXCHANGE_SCOPES),
+        "size_class_ms": {
+            c: {"ms": covered_ms(v["ivs"], lo, hi),
+                "path": "kernel" if v["kernel"] else "vmapped"}
+            for c, v in sorted(by_class.items(),
+                               key=lambda kv: int(kv[0][1:]))},
+        "coordinate_ms": {c: covered_ms(ivs, lo, hi)
+                          for c, ivs in sorted(by_coord.items())},
+        "unattributed_ms": busy_ms - scoped_ms,
+        "unattributed_share": (busy_ms - scoped_ms) / busy_ms
+        if busy_ms else 0.0,
+        "unattributed_ops": sorted(
+            ([n, ms] for n, ms in loose_ops.items() if ms > 0),
+            key=lambda kv: -kv[1])[:8],
+        "idle_gaps": [{"ms": (b - a) / 1e6, "at_ms": (a - lo) / 1e6,
+                       "phase": phase_of(spans, a, b)} for a, b in gaps],
+    }
+
+
+def _intersect(a: List[Interval], b: List[Interval]) -> List[Interval]:
+    """Of two sorted disjoint lists."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            out.append((lo, hi))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def reduce_scopes(trace: dict, gap_ms: float = 0.2) -> dict:
+    trace = unpack(trace)
+    planes = [p for p in trace["planes"]
+              if p["name"].startswith(DEVICE_PLANE_PREFIXES)]
+    if not planes:
+        raise ValueError("the trace holds no device plane")
+    events = [e for ln in planes[0]["lines"] if ln["name"] == OPS_LINE
+              for e in ln["events"]]
+    jobs = job_spans(trace)
+    if not jobs:
+        raise ValueError(f"the trace holds no {JOB_SPAN} span")
+    spans = host_spans(trace, lambda n: n != JOB_SPAN
+                       and n.startswith(HOST_SPAN_PREFIXES))
+    per_job = [reduce_job(events, spans, lo, hi, gap_ms)
+               for lo, hi, _ in jobs]
+    return {"jobs": per_job, "mean": _mean(per_job)}
+
+
+def _mean(per_job: List[dict]) -> dict:
+    n = len(per_job)
+    out = {k: sum(j[k] for j in per_job) / n
+           for k in ("window_ms", "busy_ms", "exchange_ms",
+                     "unattributed_ms", "unattributed_share")}
+    for key in ("scope_ms", "coordinate_ms"):
+        names = sorted({s for j in per_job for s in j[key]})
+        out[key] = {s: sum(j[key].get(s, 0.0) for j in per_job) / n
+                    for s in names}
+    classes = sorted({c for j in per_job for c in j["size_class_ms"]},
+                     key=lambda c: int(c[1:]))
+    out["size_class_ms"] = {
+        c: {"ms": sum(j["size_class_ms"].get(c, {"ms": 0.0})["ms"]
+                      for j in per_job) / n,
+            "path": next(j["size_class_ms"][c]["path"] for j in per_job
+                         if c in j["size_class_ms"])}
+        for c in classes}
+    return out
+
+
+# -- printing ----------------------------------------------------------------
+
+def print_report(result: dict, out=sys.stdout) -> None:
+    def table(block: dict, title: str) -> None:
+        busy = block["busy_ms"]
+        print(f"\n{title}: window {block['window_ms']:.3f} ms, device busy "
+              f"{busy:.3f} ms", file=out)
+        print("| scope | ms | share of busy |", file=out)
+        print("| --- | --- | --- |", file=out)
+        for s in scopes.DEVICE_SCOPES:
+            ms = block["scope_ms"].get(s, 0.0)
+            print(f"| `{s}` | {ms:.3f} | {100 * ms / busy:.2f}% |", file=out)
+            if s == scopes.RE_SOLVE:
+                for c, v in block["size_class_ms"].items():
+                    print(f"| &nbsp;&nbsp;`{c}` ({v['path']}) | "
+                          f"{v['ms']:.3f} | {100 * v['ms'] / busy:.2f}% |",
+                          file=out)
+        print(f"| exchange (gather + margins + scatter) | "
+              f"{block['exchange_ms']:.3f} | "
+              f"{100 * block['exchange_ms'] / busy:.2f}% |", file=out)
+        for c, ms in block["coordinate_ms"].items():
+            print(f"| `{c}` (whole update) | {ms:.3f} | "
+                  f"{100 * ms / busy:.2f}% |", file=out)
+        print(f"| under no `photon.*` scope | "
+              f"{block['unattributed_ms']:.3f} | "
+              f"{100 * block['unattributed_share']:.2f}% |", file=out)
+
+    for k, job in enumerate(result["jobs"]):
+        table(job, f"job {k}")
+        for n, ms in job["unattributed_ops"]:
+            print(f"  unattributed: {n} {ms:.3f} ms", file=out)
+        for g in job["idle_gaps"]:
+            print(f"  idle {g['ms']:.3f} ms at {g['at_ms']:.3f} ms: "
+                  f"{g['phase']}", file=out)
+    table(result["mean"], f"mean of {len(result['jobs'])} jobs")
+
+
+def dump_stats(planes: List[dict], n: int, out=sys.stdout) -> None:
+    """Every stat of the ``n`` longest device operations (the event's own
+    and its metadata's), and the lines of every plane: what a trace of
+    this chip and JAX looks like."""
+    for plane in planes:
+        print(f"plane {plane['name']!r}: " + ", ".join(
+            f"{ln['name']!r} ({len(ln['events'])})"
+            for ln in plane["lines"]), file=out)
+        if not plane["name"].startswith(DEVICE_PLANE_PREFIXES):
+            continue
+        for line in plane["lines"]:
+            if line["name"] != OPS_LINE:
+                continue
+            longest = sorted(line["events"],
+                             key=lambda e: -e["duration_ns"])[:n]
+            for ev in longest:
+                print(f"  {ev['name'][:120]!r} "
+                      f"{ev['duration_ns'] / 1e6:.3f} ms", file=out)
+                for kind in ("stats", "metadata_stats"):
+                    for key, value in ev[kind].items():
+                        print(f"      {kind}.{key} = {str(value)[:300]!r}",
+                              file=out)
+
+
+# -- the traced run ------------------------------------------------------------
+
+def trace_cell(workload: str, seed: int, jobs: int, rehearse_rows: int,
+               trace_dir: Path):
+    """The cell's job as the harness builds it, warmed up, then ``jobs``
+    jobs under a profiler session; returns the ``.xplane.pb``'s path."""
+    import importlib
+    import shutil
+
+    import jax
+
+    from benchmark import harness
+    from photon_ml_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # for the compile ledger; the cache itself off:
+    # executables whose metadata is the tree's (the module's docstring).
+    jax.config.update("jax_enable_compilation_cache", False)
+    loaded = harness.load_cell(workload)
+    config, wl = loaded["config"], loaded["workload"]
+    device = harness.device_block(int(loaded["cell"]["chips"]),
+                                  require_chip=not rehearse_rows)
+    print(f"trace_scopes: device {device}", file=sys.stderr)
+    recipe = importlib.import_module(f"benchmark.recipes.{config['recipe']}")
+    if rehearse_rows:
+        config = recipe.scale_down(config, rehearse_rows)
+    problem = recipe.make(config, seed)
+    job = importlib.import_module(
+        f"benchmark.jobs.{wl['job']}").build(config, wl, problem)
+    job.warm_up(seed)
+    job.run_job(seed + 1)  # a second: nothing of the first call is left
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(str(trace_dir))
+    for k in range(jobs):
+        job.run_job(seed + 2 + k)
+    jax.profiler.stop_trace()
+    files = sorted(trace_dir.rglob("*.xplane.pb"))
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return files[-1]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", default="glmix.fit")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--jobs", type=int, default=3)
+    ap.add_argument("--rehearse-rows", type=int, default=0)
+    ap.add_argument("--gap-ms", type=float, default=0.2)
+    ap.add_argument("--from-json", type=Path)
+    ap.add_argument("--from-xplane", type=Path)
+    ap.add_argument("--out", type=Path,
+                    default=ROOT / "chiprun_out" / "trace_scopes")
+    ap.add_argument("--save-trace", action="store_true")
+    ap.add_argument("--cut-jobs", type=int, default=0)
+    ap.add_argument("--dump-stats", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    if args.from_json:
+        trace = json.loads(args.from_json.read_text())
+    else:
+        args.out.mkdir(parents=True, exist_ok=True)
+        xplane = args.from_xplane or trace_cell(
+            args.workload, args.seed, args.jobs, args.rehearse_rows,
+            args.out / "profile")
+        planes = read_xspace(xplane)
+        if args.dump_stats:
+            with open(args.out / "stats.txt", "w") as f:
+                dump_stats(planes, args.dump_stats, out=f)
+            print((args.out / "stats.txt").read_text()[:6000])
+        trace = flatten_with_paths(planes)
+        trace["recorded"] = {"workload": args.workload, "seed": args.seed,
+                             "rehearsal": bool(args.rehearse_rows)}
+        if args.save_trace:
+            kept = cut_jobs(trace, args.cut_jobs) if args.cut_jobs else trace
+            (args.out / "trace_with_paths.json").write_text(
+                json.dumps(pack(kept), separators=(",", ":")))
+    ledger = None
+    if not (args.from_json or args.from_xplane):
+        from photon_ml_tpu.utils.compile_cache import compile_ledger
+
+        ledger = compile_ledger(top=12)  # what compiling cost this process
+        print("compile ledger (s): " + json.dumps(ledger["totals"]),
+              file=sys.stderr)
+        for name, row in ledger["functions"].items():
+            print(f"  {name}: trace {row['trace_s']:.2f} lower "
+                  f"{row['lower_s']:.2f} backend {row['backend_s']:.2f}",
+                  file=sys.stderr)
+    try:
+        result = reduce_scopes(trace, args.gap_ms)
+    except ValueError as e:  # a CPU rehearsal has no device plane
+        print(f"trace_scopes: {e}", file=sys.stderr)
+        return 1
+    print_report(result)
+    if not args.from_json:
+        result["compile_ledger"] = ledger
+        (args.out / "scopes.json").write_text(json.dumps(result, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

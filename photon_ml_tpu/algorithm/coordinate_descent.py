@@ -30,6 +30,8 @@ from photon_ml_tpu.data.game_data import GameDataset
 from photon_ml_tpu.evaluation.evaluators import Evaluator
 from photon_ml_tpu.models.game_model import GameModel
 from photon_ml_tpu.ops.losses import loss_for_task
+from photon_ml_tpu.telemetry import scopes
+from photon_ml_tpu.telemetry.spans import phase
 from photon_ml_tpu.types import TaskType
 from photon_ml_tpu.utils.tracing_guard import TracingGuard
 
@@ -95,7 +97,26 @@ class CoordinateDescentResult:
     # coordinate name -> per-update OptimizerResults (device telemetry is
     # fetched lazily on first access — see LazyTrackers)
     trackers: Mapping[str, list]
+    # coordinate name -> HOST DISPATCH seconds: what the host spent
+    # enqueueing that coordinate's updates (a fused block's enqueue is
+    # split evenly over its coordinates). JAX dispatch is asynchronous, so
+    # this is not the device's time: read that under the
+    # ``photon.cd.<coordinate>`` scope of a profiler trace
+    # (docs/OBSERVABILITY.md, "The training fit").
     timings: Dict[str, float]
+
+
+def _full_objective(loss, total, rows, penalties):
+    """sum_i w_i l(total_i + offset_i, y_i) + every coordinate's penalties
+    (``penalties``: (coefficients, l1, l2) triples), under its own scope
+    of the device trace."""
+    labels, offsets, weights = rows
+    with jax.named_scope(scopes.CD_OBJECTIVE):
+        obj = jnp.sum(weights * loss.loss(total + offsets, labels))
+        for c, l1, l2 in penalties:
+            obj = obj + 0.5 * l2 * jnp.sum(jnp.square(c))
+            obj = obj + l1 * jnp.sum(jnp.abs(c))
+    return obj
 
 
 class CoordinateDescent:
@@ -120,6 +141,31 @@ class CoordinateDescent:
         # loop's compile-count invariant — each executable traces exactly
         # once — instead of trusting it silently.
         self.tracing_guard = TracingGuard()
+        self._publish_work()
+
+    def _publish_work(self) -> None:
+        """Gauges of the work this fit was built with, summed over its
+        random-effect coordinates: slots and true rows (padding waste =
+        1 - rows / slots), and the entities the fused kernel and the
+        vmapped fallback solve (``coordinate.routing()`` has each bucket's
+        reason). Set once, here, and only while telemetry is enabled: the
+        true-row count is one small device reduction a bucket."""
+        from photon_ml_tpu import telemetry
+
+        if not telemetry.enabled():
+            return
+        routed = [c for c in self.coordinates.values()
+                  if hasattr(c, "routing")]
+        buckets = [b for c in routed for b in c.routing()]
+        on_kernel = sum(b["entities"] for b in buckets
+                        if b["path"] == "kernel")
+        telemetry.gauge(scopes.GAUGE_RE_SLOTS).set(
+            sum(b["slots"] for b in buckets))
+        telemetry.gauge(scopes.GAUGE_RE_ROWS).set(
+            sum(c.true_rows() for c in routed))
+        telemetry.gauge(scopes.GAUGE_RE_KERNEL_ENTITIES).set(on_kernel)
+        telemetry.gauge(scopes.GAUGE_RE_FALLBACK_ENTITIES).set(
+            sum(b["entities"] for b in buckets) - on_kernel)
 
     def _fused_update_fns(self):
         """One jitted function per coordinate performing the ENTIRE update —
@@ -139,27 +185,24 @@ class CoordinateDescent:
         def make(n):
             coord = self.coordinates[n]
 
-            def fused(data, pdata_all, params_all, other_scores, base_key,
-                      step, rows):
-                residual = None
-                for s in other_scores:
-                    residual = s if residual is None else residual + s
-                key = jax.random.fold_in(base_key, step)
-                new_p, tracker = coord.pure_update(
-                    data, params_all[n], residual, key)
-                score = coord.pure_score(data, new_p)
-                total = score if residual is None else residual + score
-                labels, offsets, weights = rows
-                obj = jnp.sum(weights * loss.loss(total + offsets, labels))
-                for m in names:
-                    pm = new_p if m == n else params_all[m]
-                    for c, l1, l2 in self.coordinates[m].pure_penalties(
-                            pm, pdata_all[m]):
-                        obj = obj + 0.5 * l2 * jnp.sum(jnp.square(c))
-                        obj = obj + l1 * jnp.sum(jnp.abs(c))
+            def cd_step(data, pdata_all, params_all, other_scores,
+                        base_key, step, rows):
+                with jax.named_scope(scopes.cd_coordinate(n)):
+                    residual = None
+                    for s in other_scores:
+                        residual = s if residual is None else residual + s
+                    key = jax.random.fold_in(base_key, step)
+                    new_p, tracker = coord.pure_update(
+                        data, params_all[n], residual, key)
+                    score = coord.pure_score(data, new_p)
+                    total = score if residual is None else residual + score
+                obj = _full_objective(loss, total, rows, [
+                    pen for m in names
+                    for pen in self.coordinates[m].pure_penalties(
+                        new_p if m == n else params_all[m], pdata_all[m])])
                 return new_p, score, obj, tracker
 
-            return jax.jit(fused)
+            return jax.jit(cd_step)
 
         self._fused_fns = {n: make(n) for n in names}
         for n, fn in self._fused_fns.items():
@@ -190,10 +233,8 @@ class CoordinateDescent:
         names = list(self.coordinates)
         n_coords = len(names)
 
-        def block(data_args, pdata_args, params, scores, base_key, step0,
-                  rows):
-            labels, offsets, weights = rows
-
+        def cd_block(data_args, pdata_args, params, scores, base_key, step0,
+                     rows):
             def one_iteration(carry, it_idx):
                 params, scores = carry
                 objs = []
@@ -202,27 +243,24 @@ class CoordinateDescent:
                     coord = self.coordinates[n]
                     step = (step0 + it_idx * np.uint32(n_coords)
                             + np.uint32(ci + 1))
-                    residual = None
-                    for m in names:
-                        if m == n:
-                            continue
-                        residual = (scores[m] if residual is None
-                                    else residual + scores[m])
-                    key = jax.random.fold_in(base_key, step)
-                    new_p, tracker = coord.pure_update(
-                        data_args[n], params[n], residual, key)
-                    sc = coord.pure_score(data_args[n], new_p)
-                    params = {**params, n: new_p}
-                    scores = {**scores, n: sc}
-                    total = sc if residual is None else residual + sc
-                    obj = jnp.sum(
-                        weights * loss.loss(total + offsets, labels))
-                    for m in names:
-                        for c, l1, l2 in self.coordinates[m].pure_penalties(
-                                params[m], pdata_args[m]):
-                            obj = obj + 0.5 * l2 * jnp.sum(jnp.square(c))
-                            obj = obj + l1 * jnp.sum(jnp.abs(c))
-                    objs.append(obj)
+                    with jax.named_scope(scopes.cd_coordinate(n)):
+                        residual = None
+                        for m in names:
+                            if m == n:
+                                continue
+                            residual = (scores[m] if residual is None
+                                        else residual + scores[m])
+                        key = jax.random.fold_in(base_key, step)
+                        new_p, tracker = coord.pure_update(
+                            data_args[n], params[n], residual, key)
+                        sc = coord.pure_score(data_args[n], new_p)
+                        params = {**params, n: new_p}
+                        scores = {**scores, n: sc}
+                        total = sc if residual is None else residual + sc
+                    objs.append(_full_objective(loss, total, rows, [
+                        pen for m in names
+                        for pen in self.coordinates[m].pure_penalties(
+                            params[m], pdata_args[m])]))
                     trs[n] = tracker
                 return (params, scores), (jnp.stack(objs), trs)
 
@@ -231,7 +269,7 @@ class CoordinateDescent:
                 jnp.arange(n_iters, dtype=jnp.uint32))
             return params, scores, objs, trs
 
-        fn = jax.jit(block)
+        fn = jax.jit(cd_block)
         self._block_fns[n_iters] = fn
         self.tracing_guard.track(f"block:{n_iters}", fn)
         return fn
@@ -251,307 +289,345 @@ class CoordinateDescent:
         per-step keys use fold_in so a resumed run is bit-identical to an
         uninterrupted one). checkpoint_tag: caller-supplied configuration
         fingerprint (str or mapping) folded into the checkpoint identity
-        check; mappings are compared canonically (key order is cosmetic)."""
+        check; mappings are compared canonically (key order is cosmetic).
+
+        The host phases below are ``telemetry.spans.phase`` spans named in
+        telemetry/scopes.py (``photon.cd.run`` and its children): any
+        profiler session sees them on the device operations' clock."""
         from photon_ml_tpu.utils import checkpoint as ckpt
 
-        if checkpoint_interval < 1:
-            raise ValueError(
-                f"checkpoint_interval must be >= 1, got {checkpoint_interval}")
-        names = list(self.coordinates)
+        # One ``with`` around the whole body and no helper method: a Python
+        # frame between here and the solvers is paid for at every traced
+        # equation (JAX captures a traceback per equation; one frame more
+        # was +5 s of a 32 s warm-up on the chip's host, PERF.md PR 29).
+        with phase(scopes.CD_RUN):
+            if checkpoint_interval < 1:
+                raise ValueError("checkpoint_interval must be >= 1, got "
+                                 f"{checkpoint_interval}")
+            names = list(self.coordinates)
 
-        if initial_model is None:
-            models = {n: c.initialize_model()
-                      for n, c in self.coordinates.items()}
-        else:
-            models = {n: initial_model.get_model(n) for n in names}
+            base_key = jax.random.PRNGKey(seed)
+            objective_history: List[float] = []
+            validation_history: List[Dict[str, float]] = []
+            trackers: Dict[str, list] = {n: [] for n in names}
+            timings: Dict[str, float] = {n: 0.0 for n in names}
+            best_model, best_metric = None, None
+            done_steps = 0
+            meta = {"seed": seed, "coordinates": names,
+                    "taskType": self.task_type.value,
+                    "tag": (dict(checkpoint_tag)
+                            if isinstance(checkpoint_tag, Mapping)
+                            else checkpoint_tag)}
 
-        base_key = jax.random.PRNGKey(seed)
-        objective_history: List[float] = []
-        validation_history: List[Dict[str, float]] = []
-        trackers: Dict[str, list] = {n: [] for n in names}
-        timings: Dict[str, float] = {n: 0.0 for n in names}
-        best_model, best_metric = None, None
-        done_steps = 0
-        meta = {"seed": seed, "coordinates": names,
-                "taskType": self.task_type.value,
-                "tag": (dict(checkpoint_tag)
-                        if isinstance(checkpoint_tag, Mapping)
-                        else checkpoint_tag)}
+            def _save(step):
+                with phase(scopes.CD_CHECKPOINT):
+                    _sync_models()
+                    _materialize_all()
+                    ckpt.save_checkpoint(checkpoint_dir, ckpt.CheckpointState(
+                        step=step, models=models,
+                        objective_history=list(objective_history),
+                        validation_history=validation_history,
+                        best_metric=best_metric,
+                        best_models=(dict(best_model.models)
+                                     if best_model is not None else None),
+                        timings=timings, trackers=trackers, meta=meta))
 
-        def _save(step):
-            _sync_models()
-            _materialize_all()
-            ckpt.save_checkpoint(checkpoint_dir, ckpt.CheckpointState(
-                step=step, models=models,
-                objective_history=list(objective_history),
-                validation_history=validation_history,
-                best_metric=best_metric,
-                best_models=(dict(best_model.models)
-                             if best_model is not None else None),
-                timings=timings, trackers=trackers, meta=meta))
-
-        if checkpoint_dir is not None:
-            latest = ckpt.latest_checkpoint(checkpoint_dir)
-            if latest is not None:
-                state = ckpt.load_checkpoint(latest)
-                # Canonical-fingerprint comparison: benign dict reordering
-                # (insertion order of the tag/config mapping) hashes the
-                # same, and mapping tags also match their legacy flattened
-                # string form; a changed seed, task type, or updating
-                # SEQUENCE (list order is semantic) still hard-errors.
-                if (state.meta is not None
-                        and not (ckpt.meta_fingerprints(state.meta)
-                                 & ckpt.meta_fingerprints(meta))):
-                    raise ValueError(
-                        f"checkpoint {latest} belongs to a different "
-                        f"configuration (saved {state.meta}, current {meta});"
-                        " point --checkpoint-dir elsewhere or delete it")
-                done_steps = state.step
-                models = dict(state.models)
-                objective_history = list(state.objective_history)
-                validation_history = list(state.validation_history)
-                best_metric = state.best_metric
-                timings = dict(state.timings)
-                trackers = {n: list(state.trackers.get(n, []))
-                            for n in names}
-                if state.best_models is not None:
-                    best_model = GameModel(dict(state.best_models),
-                                           self.task_type)
-                logger.info("resumed from %s (step %d)", latest, done_steps)
-
-        # The fused path: params/scores dicts are the authoritative training
-        # state on device; model objects are materialized lazily (checkpoint,
-        # validation, return) so the hot loop is exactly ONE dispatch per
-        # coordinate update.
-        data_args = {n: self.coordinates[n].step_data() for n in names}
-        pdata_args = {n: self.coordinates[n].penalty_data() for n in names}
-        params = {n: self.coordinates[n].params_of(models[n]) for n in names}
-        # Canonicalize param leaves to device arrays: checkpoint-loaded
-        # models carry host np.ndarray leaves, and np inputs key a
-        # SEPARATE pjit executable from the device arrays of steady-state
-        # calls — one silent recompile per coordinate on every resume
-        # (surfaced by tracing_guard's per_fn=1 invariant below).
-        params = {n: jax.tree.map(jnp.asarray, p)
-                  for n, p in params.items()}
-        fused = self._fused_update_fns()
-
-        def _sync_models():
-            for m in names:
-                models[m] = self.coordinates[m].model_of(params[m], models[m])
-
-        scores: Dict[str, Array] = {
-            n: self.coordinates[n].pure_score(data_args[n], params[n])
-            for n in names}
-        rows = self._training_rows(next(iter(scores.values())).dtype)
-
-        # Objective history lives in a FIXED-CAPACITY device vector updated
-        # by a tiny jitted set (enqueue-only); materialization is ONE
-        # device->host transfer. Per-entry float() syncs each wait for the
-        # device and would dominate whole runs. Capacity is padded to a
-        # power of two so the updater executable is shared across runs of
-        # different lengths.
-        total_steps = max(num_iterations * len(names),
-                          len(objective_history))
-        cap = max(64, 1 << max(0, total_steps - 1).bit_length())
-        hist_dtype = np.dtype(next(iter(scores.values())).dtype)
-        hist_dev = jnp.zeros(cap, hist_dtype)
-        hist_len = len(objective_history)  # absolute step count written
-        mat_hist_len = hist_len  # prefix already materialized (resumed)
-
-        # Device-resident results of fused iteration BLOCKS, appended in
-        # step order and fetched host-side in ONE transfer per sync point.
-        pending_blocks: List[tuple] = []
-        # Tracker blocks left on device at run end (lazy fetch).
-        pending_tracker_blocks: List[dict] = []
-        n_coords = len(names)
-
-        def _materialize_history():
-            nonlocal mat_hist_len
-            if hist_len > mat_hist_len:
-                vals = np.asarray(hist_dev)[mat_hist_len:hist_len]
-                objective_history.extend(float(v) for v in vals)
-                mat_hist_len = hist_len
-
-        def _materialize_pending(include_trackers: bool = True):
-            if not pending_blocks:
-                return
-            if include_trackers:
-                host_blocks = jax.device_get(pending_blocks)
-                for objs, trs in host_blocks:
-                    for i in range(objs.shape[0]):
-                        for ci in range(n_coords):
-                            objective_history.append(float(objs[i, ci]))
-                    _unstack_tracker_block(trs, names, trackers)
-            else:
-                # Objectives only (small); tracker blocks stay on device
-                # for lazy fetch via LazyTrackers.
-                objs_host = jax.device_get([b[0] for b in pending_blocks])
-                for objs in objs_host:
-                    for i in range(objs.shape[0]):
-                        for ci in range(n_coords):
-                            objective_history.append(float(objs[i, ci]))
-                pending_tracker_blocks.extend(
-                    b[1] for b in pending_blocks)
-            pending_blocks.clear()
-
-        def _materialize_all():
-            # Per-step entries always precede block entries (the per-step
-            # path only runs before blocks start or exclusively), so this
-            # order keeps objective_history in step order.
-            _materialize_history()
-            _materialize_pending()
-
-        validating = (self.validation_data is not None
-                      and bool(self.validation_evaluators))
-        # Blocks cover whole iterations; they apply when checkpoint saves
-        # land on iteration boundaries (otherwise the per-step path below
-        # preserves the exact mid-iteration save behavior).
-        blockable = (checkpoint_dir is None
-                     or checkpoint_interval % n_coords == 0)
-
-        def _run_validation(it):
-            nonlocal best_metric, best_model
-            _sync_models()
-            game_model = GameModel(dict(models), self.task_type)
-            # Device-side scoring: the validation shards live in HBM
-            # (uploaded once at first use); per-iteration scoring is one
-            # jitted dispatch + ONE transfer of the score vector, vs the
-            # reference's per-submodel score joins
-            # (FixedEffectModel.scala:94-105, RandomEffectModel.scala).
-            if self._val_scorer is None:
-                from photon_ml_tpu.models.device_scoring import (
-                    DeviceGameScorer,
-                )
-                self._val_scorer = DeviceGameScorer(
-                    game_model, self.validation_data, dtype=hist_dtype)
-            val_scores = np.asarray(self._val_scorer.score(game_model))
-            metrics = {
-                ev.name: ev.evaluate_dataset(val_scores,
-                                             self.validation_data)
-                for ev in self.validation_evaluators}
-            validation_history.append(metrics)
-            head = self.validation_evaluators[0]
-            m0 = metrics[head.name]
-            if head.better_than(m0, best_metric):
-                best_metric, best_model = m0, game_model
-            logger.info("iter %d validation: %s", it, metrics)
-
-        step = 0
-        it = 0
-        while it < num_iterations:
-            if step + n_coords <= done_steps:
-                # Whole iteration was restored, incl. its validation.
-                step += n_coords
-                it += 1
-                continue
-            partial_resume = step < done_steps  # resume lands mid-iteration
-
-            if blockable and not partial_resume:
-                # -------- fused block path: one dispatch per sync span ----
-                if validating:
-                    span = 1
-                elif checkpoint_dir is not None:
-                    next_save = ((step // checkpoint_interval) + 1
-                                 ) * checkpoint_interval
-                    span = (next_save - step) // n_coords
+            with phase(scopes.CD_PREPARE):
+                if initial_model is None:
+                    models = {n: c.initialize_model()
+                              for n, c in self.coordinates.items()}
                 else:
-                    span = num_iterations - it
-                span = max(1, min(span, num_iterations - it))
-                t0 = time.perf_counter()
-                params, scores, objs, trs = self._fused_block_fn(span)(
-                    data_args, pdata_args, params, scores, base_key,
-                    np.uint32(step), rows)
-                pending_blocks.append((objs, trs))
-                elapsed = time.perf_counter() - t0
-                for n in names:
-                    timings[n] += elapsed / n_coords
-                step += span * n_coords
-                it += span
-                logger.info(
-                    "iterations %d-%d dispatched as one device block "
-                    "(%.1f ms)", it - span, it - 1, 1e3 * elapsed)
-                if validating:
-                    _run_validation(it - 1)
-                if (checkpoint_dir is not None
-                        and (validating or step % checkpoint_interval == 0)):
-                    # Iteration-boundary save (carries this iteration's
-                    # validation entry + best model when validating).
-                    _save(step)
-                continue
+                    models = {n: initial_model.get_model(n) for n in names}
 
-            # -------- per-step path: partial-iteration resume or ---------
-            # -------- non-iteration-aligned checkpoint intervals ---------
-            for ci, n in enumerate(names):
-                step += 1
-                if step <= done_steps:
-                    continue  # resumed past this update
-                t0 = time.perf_counter()
-                # One dispatch: residual reduce (the reference's
-                # partial-score reduce, CoordinateDescent.scala:150-158,
-                # recomputed FRESH each step so a resumed run matches an
-                # uninterrupted one bit-for-bit), per-step fold_in key,
-                # solve, re-score, full objective incl. every coordinate's
-                # penalties. The step index is passed as a device scalar so
-                # the compiled executable is reused across steps.
-                new_p, new_score, obj, tracker = fused[n](
-                    data_args[n], pdata_args, params,
-                    tuple(scores[m] for m in names if m != n),
-                    base_key, np.uint32(step), rows)
-                params[n] = new_p
-                scores[n] = new_score
-                if isinstance(tracker, tuple):
-                    tracker = list(tracker)
-                trackers[n].append(tracker)
-                timings[n] += time.perf_counter() - t0
-
-                # Device-side history write — NOT synced here (a float()
-                # per update waits for the device); materialized in one
-                # transfer at checkpoint/return.
-                hist_dev = _hist_set(hist_dev, np.uint32(step - 1), obj)
-                hist_len = max(hist_len, step)
-                logger.info("iter %d coordinate %s updated (%.1f ms)", it,
-                            n, 1e3 * (time.perf_counter() - t0))
-                # Defer the last-coordinate save to after validation: one
-                # save per iteration boundary, and a crash during validation
-                # resumes from before the final update, so the re-run never
-                # skips the iteration's validation/best-model bookkeeping.
-                last_of_iteration = ci == n_coords - 1
-                if (checkpoint_dir is not None
-                        and step % checkpoint_interval == 0
-                        and not (last_of_iteration and validating)):
-                    _save(step)
-
-            if validating:
-                _run_validation(it)
                 if checkpoint_dir is not None:
-                    _save(step)
-            it += 1
+                    latest = ckpt.latest_checkpoint(checkpoint_dir)
+                    if latest is not None:
+                        state = ckpt.load_checkpoint(latest)
+                        # Canonical-fingerprint comparison: benign dict
+                        # reordering (insertion order of the tag/config
+                        # mapping) hashes the same, and mapping tags also
+                        # match their legacy flattened string form; a
+                        # changed seed, task type, or updating SEQUENCE
+                        # (list order is semantic) still hard-errors.
+                        if (state.meta is not None
+                                and not (ckpt.meta_fingerprints(state.meta)
+                                         & ckpt.meta_fingerprints(meta))):
+                            raise ValueError(
+                                f"checkpoint {latest} belongs to a "
+                                "different configuration (saved "
+                                f"{state.meta}, current {meta}); point "
+                                "--checkpoint-dir elsewhere or delete it")
+                        done_steps = state.step
+                        models = dict(state.models)
+                        objective_history = list(state.objective_history)
+                        validation_history = list(state.validation_history)
+                        best_metric = state.best_metric
+                        timings = dict(state.timings)
+                        trackers = {n: list(state.trackers.get(n, []))
+                                    for n in names}
+                        if state.best_models is not None:
+                            best_model = GameModel(dict(state.best_models),
+                                                   self.task_type)
+                        logger.info("resumed from %s (step %d)", latest,
+                                    done_steps)
 
-        _sync_models()
-        _materialize_history()
-        _materialize_pending(include_trackers=False)
-        # Hot-loop compile invariant: every fused executable (per-
-        # coordinate step fns, per-span block fns) traced exactly once
-        # this run — the runtime complement of jaxlint's retrace-hazard
-        # rule. A trip here means argument shapes/dtypes/statics drifted
-        # call-to-call and every "one dispatch" above silently paid a
-        # recompile.
-        self.tracing_guard.assert_max_retraces(per_fn=1)
-        if logger.isEnabledFor(logging.INFO) and objective_history:
-            logger.info("objective history: %s",
-                        ["%.6f" % v for v in objective_history])
-        final = GameModel(dict(models), self.task_type)
-        if best_model is None:
-            best_model = final
-        return CoordinateDescentResult(
-            model=final,
-            objective_history=list(objective_history),
-            validation_history=validation_history,
-            best_model=best_model,
-            best_metric=best_metric,
-            trackers=LazyTrackers(trackers, pending_tracker_blocks, names),
-            timings=timings,
-        )
+                # The fused path: params/scores dicts are the authoritative
+                # training state on device; model objects are materialized
+                # lazily (checkpoint, validation, return) so the hot loop is
+                # exactly ONE dispatch per coordinate update.
+                data_args = {n: self.coordinates[n].step_data() for n in names}
+                pdata_args = {n: self.coordinates[n].penalty_data()
+                              for n in names}
+                params = {n: self.coordinates[n].params_of(models[n])
+                          for n in names}
+                # Canonicalize param leaves to device arrays: checkpoint-
+                # loaded models carry host np.ndarray leaves, and np inputs
+                # key a SEPARATE pjit executable from the device arrays of
+                # steady-state calls — one silent recompile per coordinate
+                # on every resume (surfaced by tracing_guard's per_fn=1
+                # invariant below).
+                params = {n: jax.tree.map(jnp.asarray, p)
+                          for n, p in params.items()}
+                fused = self._fused_update_fns()
+
+            def _sync_models():
+                for m in names:
+                    models[m] = self.coordinates[m].model_of(
+                        params[m], models[m])
+
+            with phase(scopes.CD_INITIAL_SCORES):
+                scores: Dict[str, Array] = {
+                    n: self.coordinates[n].pure_score(data_args[n], params[n])
+                    for n in names}
+                rows = self._training_rows(next(iter(scores.values())).dtype)
+
+            # Objective history lives in a FIXED-CAPACITY device vector updated
+            # by a tiny jitted set (enqueue-only); materialization is ONE
+            # device->host transfer. Per-entry float() syncs each wait for the
+            # device and would dominate whole runs. Capacity is padded to a
+            # power of two so the updater executable is shared across runs of
+            # different lengths.
+            total_steps = max(num_iterations * len(names),
+                              len(objective_history))
+            cap = max(64, 1 << max(0, total_steps - 1).bit_length())
+            hist_dtype = np.dtype(next(iter(scores.values())).dtype)
+            hist_dev = jnp.zeros(cap, hist_dtype)
+            hist_len = len(objective_history)  # absolute step count written
+            mat_hist_len = hist_len  # prefix already materialized (resumed)
+
+            # Device-resident results of fused iteration BLOCKS, appended in
+            # step order and fetched host-side in ONE transfer per sync point.
+            pending_blocks: List[tuple] = []
+            # Tracker blocks left on device at run end (lazy fetch).
+            pending_tracker_blocks: List[dict] = []
+            n_coords = len(names)
+
+            def _materialize_history():
+                nonlocal mat_hist_len
+                if hist_len > mat_hist_len:
+                    with phase(scopes.CD_WAIT):
+                        vals = np.asarray(hist_dev)[mat_hist_len:hist_len]
+                    objective_history.extend(float(v) for v in vals)
+                    mat_hist_len = hist_len
+
+            def _materialize_pending(include_trackers: bool = True):
+                if not pending_blocks:
+                    return
+                if include_trackers:
+                    with phase(scopes.CD_WAIT):
+                        host_blocks = jax.device_get(pending_blocks)
+                    for objs, trs in host_blocks:
+                        for i in range(objs.shape[0]):
+                            for ci in range(n_coords):
+                                objective_history.append(float(objs[i, ci]))
+                        _unstack_tracker_block(trs, names, trackers)
+                else:
+                    # Objectives only (small); tracker blocks stay on device
+                    # for lazy fetch via LazyTrackers.
+                    with phase(scopes.CD_WAIT):
+                        objs_host = jax.device_get(
+                            [b[0] for b in pending_blocks])
+                    for objs in objs_host:
+                        for i in range(objs.shape[0]):
+                            for ci in range(n_coords):
+                                objective_history.append(float(objs[i, ci]))
+                    pending_tracker_blocks.extend(
+                        b[1] for b in pending_blocks)
+                pending_blocks.clear()
+
+            def _materialize_all():
+                # Per-step entries always precede block entries (the per-step
+                # path only runs before blocks start or exclusively), so this
+                # order keeps objective_history in step order.
+                _materialize_history()
+                _materialize_pending()
+
+            validating = (self.validation_data is not None
+                          and bool(self.validation_evaluators))
+            # Blocks cover whole iterations; they apply when checkpoint saves
+            # land on iteration boundaries (otherwise the per-step path below
+            # preserves the exact mid-iteration save behavior).
+            blockable = (checkpoint_dir is None
+                         or checkpoint_interval % n_coords == 0)
+
+            def _run_validation(it):
+                nonlocal best_metric, best_model
+                with phase(scopes.CD_VALIDATE):
+                    _sync_models()
+                    game_model = GameModel(dict(models), self.task_type)
+                    # Device-side scoring: the validation shards live in
+                    # HBM (uploaded once at first use); per-iteration scoring
+                    # is one jitted dispatch + ONE transfer of the score
+                    # vector, vs the reference's per-submodel score joins
+                    # (FixedEffectModel.scala:94-105, RandomEffectModel.scala).
+                    if self._val_scorer is None:
+                        from photon_ml_tpu.models.device_scoring import (
+                            DeviceGameScorer,
+                        )
+                        self._val_scorer = DeviceGameScorer(
+                            game_model, self.validation_data, dtype=hist_dtype)
+                    val_scores = np.asarray(self._val_scorer.score(game_model))
+                    metrics = {
+                        ev.name: ev.evaluate_dataset(val_scores,
+                                                     self.validation_data)
+                        for ev in self.validation_evaluators}
+                    validation_history.append(metrics)
+                    head = self.validation_evaluators[0]
+                    m0 = metrics[head.name]
+                    if head.better_than(m0, best_metric):
+                        best_metric, best_model = m0, game_model
+                    logger.info("iter %d validation: %s", it, metrics)
+
+            step = 0
+            it = 0
+            while it < num_iterations:
+                if step + n_coords <= done_steps:
+                    # Whole iteration was restored, incl. its validation.
+                    step += n_coords
+                    it += 1
+                    continue
+                # resume lands mid-iteration
+                partial_resume = step < done_steps
+
+                if blockable and not partial_resume:
+                    # ------ fused block path: one dispatch per sync span ---
+                    if validating:
+                        span = 1
+                    elif checkpoint_dir is not None:
+                        next_save = ((step // checkpoint_interval) + 1
+                                     ) * checkpoint_interval
+                        span = (next_save - step) // n_coords
+                    else:
+                        span = num_iterations - it
+                    span = max(1, min(span, num_iterations - it))
+                    t0 = time.perf_counter()
+                    with phase(scopes.CD_DISPATCH):
+                        params, scores, objs, trs = self._fused_block_fn(span)(
+                            data_args, pdata_args, params, scores, base_key,
+                            np.uint32(step), rows)
+                    pending_blocks.append((objs, trs))
+                    # Host seconds to ENQUEUE the block (and to trace, lower
+                    # and load it on the first call), split evenly: not any
+                    # coordinate's device time (CoordinateDescentResult).
+                    elapsed = time.perf_counter() - t0
+                    for n in names:
+                        timings[n] += elapsed / n_coords
+                    step += span * n_coords
+                    it += span
+                    logger.info(
+                        "iterations %d-%d enqueued as one device block "
+                        "(host dispatch %.1f ms)", it - span, it - 1,
+                        1e3 * elapsed)
+                    if validating:
+                        _run_validation(it - 1)
+                    if (checkpoint_dir is not None
+                            and (validating
+                                 or step % checkpoint_interval == 0)):
+                        # Iteration-boundary save (carries this iteration's
+                        # validation entry + best model when validating).
+                        _save(step)
+                    continue
+
+                # -------- per-step path: partial-iteration resume or ---------
+                # -------- non-iteration-aligned checkpoint intervals ---------
+                for ci, n in enumerate(names):
+                    step += 1
+                    if step <= done_steps:
+                        continue  # resumed past this update
+                    t0 = time.perf_counter()
+                    # One dispatch: residual reduce (the reference's
+                    # partial-score reduce, CoordinateDescent.scala:150-158,
+                    # recomputed FRESH each step so a resumed run matches an
+                    # uninterrupted one bit-for-bit), per-step fold_in key,
+                    # solve, re-score, full objective incl. every coordinate's
+                    # penalties. The step index is passed as a device scalar so
+                    # the compiled executable is reused across steps.
+                    with phase(scopes.CD_DISPATCH):
+                        new_p, new_score, obj, tracker = fused[n](
+                            data_args[n], pdata_args, params,
+                            tuple(scores[m] for m in names if m != n),
+                            base_key, np.uint32(step), rows)
+                    params[n] = new_p
+                    scores[n] = new_score
+                    if isinstance(tracker, tuple):
+                        tracker = list(tracker)
+                    trackers[n].append(tracker)
+                    timings[n] += time.perf_counter() - t0
+
+                    # Device-side history write — NOT synced here (a float()
+                    # per update waits for the device); materialized in one
+                    # transfer at checkpoint/return.
+                    hist_dev = _hist_set(hist_dev, np.uint32(step - 1), obj)
+                    hist_len = max(hist_len, step)
+                    logger.info("iter %d coordinate %s enqueued (host "
+                                "dispatch %.1f ms)", it, n,
+                                1e3 * (time.perf_counter() - t0))
+                    # Defer the last-coordinate save to after validation:
+                    # one save per iteration boundary, and a crash during
+                    # validation resumes from before the final update, so the
+                    # re-run never skips the iteration's validation/best-model
+                    # bookkeeping.
+                    last_of_iteration = ci == n_coords - 1
+                    if (checkpoint_dir is not None
+                            and step % checkpoint_interval == 0
+                            and not (last_of_iteration and validating)):
+                        _save(step)
+
+                if validating:
+                    _run_validation(it)
+                    if checkpoint_dir is not None:
+                        _save(step)
+                it += 1
+
+            # The host blocks here, on the objectives' transfer, until the
+            # device has finished the last block (trackers stay on the device).
+            _materialize_history()
+            _materialize_pending(include_trackers=False)
+            with phase(scopes.CD_FINISH):
+                _sync_models()
+                # Hot-loop compile invariant: every fused executable (per-
+                # coordinate step fns, per-span block fns) traced exactly
+                # once this run — the runtime complement of jaxlint's
+                # retrace-hazard rule. A trip here means argument
+                # shapes/dtypes/statics drifted call-to-call and every "one
+                # dispatch" above silently paid a recompile.
+                self.tracing_guard.assert_max_retraces(per_fn=1)
+                if logger.isEnabledFor(logging.INFO) and objective_history:
+                    logger.info("objective history: %s",
+                                ["%.6f" % v for v in objective_history])
+                final = GameModel(dict(models), self.task_type)
+                if best_model is None:
+                    best_model = final
+                return CoordinateDescentResult(
+                    model=final,
+                    objective_history=list(objective_history),
+                    validation_history=validation_history,
+                    best_model=best_model,
+                    best_metric=best_metric,
+                    trackers=LazyTrackers(trackers, pending_tracker_blocks,
+                                          names),
+                    timings=timings,
+                )
 
     def _training_rows(self, dtype) -> Tuple[Array, Array, Array]:
         """(labels, offsets, weights) aligned with the global row order,

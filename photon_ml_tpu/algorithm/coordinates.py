@@ -20,6 +20,7 @@ objective computations as needed).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import List, Optional, Tuple
@@ -40,6 +41,7 @@ from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
 from photon_ml_tpu.optimization.convergence import OptimizerResult
 from photon_ml_tpu.optimization.solver import solve_glm
+from photon_ml_tpu.telemetry import scopes
 from photon_ml_tpu.types import TaskType
 
 Array = jax.Array
@@ -589,6 +591,31 @@ class RandomEffectCoordinate(Coordinate):
             _gather_block_bounds(self.lower_bounds, self.upper_bounds, b)
             for b in self.dataset.blocks)
 
+    def routing(self) -> List[dict]:
+        """Per bucket, what the solve will do with it: the size class
+        (``rows``), its entities and slots, and the path the guard picks
+        (``kernel``, or ``vmapped`` with the guard's ``reason``). Decided
+        from shapes and configuration alone, as the trace decides it."""
+        out = []
+        for block, norm, bounds in zip(self.dataset.blocks,
+                                       self._norm_blocks,
+                                       self._bounds_blocks):
+            e, r, _ = block.x.shape
+            refusal = _kernel_refusal(
+                self._objective, self.config, block.x,
+                sharded=False, norm=norm, bounds=bounds)
+            out.append({"rows": int(r), "entities": int(e),
+                        "slots": int(e) * int(r),
+                        "path": "kernel" if refusal is None else "vmapped",
+                        "reason": refusal and refusal[0]})
+        return out
+
+    def true_rows(self) -> int:
+        """Rows that are data and not padding, over all buckets (one small
+        device reduction a bucket: for set-up and reports, not the loop)."""
+        return sum(int(jnp.sum(b.row_ids < self.dataset.n_rows))
+                   for b in self.dataset.blocks)
+
     def initialize_model(self) -> RandomEffectModel:
         dt = (self.dataset.blocks[0].x.dtype if self.dataset.blocks
               else jnp.float32)
@@ -647,14 +674,16 @@ class RandomEffectCoordinate(Coordinate):
         for block, c0, norm, bounds in zip(blocks, params, norm_blocks,
                                            bounds_blocks):
             if norm is not None:
-                c0 = gathered_to_normalized_space(c0, *norm)
+                with _re_solve_scope(block):
+                    c0 = gathered_to_normalized_space(c0, *norm)
             result = _solve_block(
                 self._objective, self.config, block, residual, c0,
                 sharded=self.mesh is not None, mesh=self.mesh,
                 norm=norm, bounds=bounds)
             coef = result.x
             if norm is not None:
-                coef = gathered_to_original_space(coef, *norm)
+                with _re_solve_scope(block):
+                    coef = gathered_to_original_space(coef, *norm)
             new_coefs.append(coef)
             results.append(result)
         return tuple(new_coefs), results
@@ -1191,6 +1220,7 @@ def _solve_latent_matrix(
     return solve_glm(objective, batch, config, coef0)
 
 
+@jax.named_scope(scopes.RE_GATHER)
 def _gather_residual(residual_scores: Optional[Array],
                      block: EntityBlock) -> Optional[Array]:
     """Per-row residual for a block: a zero sentinel slot is appended so
@@ -1201,6 +1231,15 @@ def _gather_residual(residual_scores: Optional[Array],
         [residual_scores,
          jnp.zeros((1,), residual_scores.dtype)])
     return ext[block.row_ids]
+
+
+@contextlib.contextmanager
+def _re_solve_scope(block: EntityBlock):
+    """``photon.re.solve/r<rows>``: the bucket's solve (kernel or vmapped,
+    normalisation transforms included), one child per size class."""
+    with jax.named_scope(scopes.RE_SOLVE), \
+            jax.named_scope(scopes.re_size_class(block.x.shape[1])):
+        yield
 
 
 def _dispatch_pallas_solver(objective, config, x, labels, offsets,
@@ -1293,7 +1332,22 @@ def _warn_fallback(reason: str):
 def _use_pallas_entity_solver(objective, config, x,
                               sharded: bool, norm=None,
                               bounds=None) -> bool:
-    """The fused Pallas kernel covers the random-effect solve
+    """Whether this bucket's solve goes to the fused Pallas kernel; warns
+    once per distinct reason where a TPU run loses it (see
+    ``_kernel_refusal`` for the rules)."""
+    refusal = _kernel_refusal(objective, config, x, sharded, norm, bounds)
+    if refusal is not None and refusal[1]:
+        _warn_fallback(refusal[0])
+    return refusal is None
+
+
+def _kernel_refusal(objective, config, x, sharded: bool, norm=None,
+                    bounds=None) -> Optional[Tuple[str, bool]]:
+    """Why this bucket's solve does NOT go to the fused Pallas kernel, as
+    ``(reason, loud)``, or None where it does; loud where a TPU run
+    silently loses the kernel (the caller warns).
+
+    The fused Pallas kernel covers the random-effect solve
     configurations: TPU backend, L-BFGS (L2, box constraints via
     projected trials) or OWL-QN (L1/elastic-net) or TRON
     (twice-differentiable losses, L2-only, box constraints via
@@ -1321,34 +1375,34 @@ def _use_pallas_entity_solver(objective, config, x,
         entity_solver_vmem_bytes,
     )
 
+    def refuse(reason: str, loud: bool = False):
+        return reason, loud
+
     if os.environ.get("PHOTON_ML_TPU_NO_PALLAS") == "1":
-        return False
+        return refuse("PHOTON_ML_TPU_NO_PALLAS=1")
     on_tpu = jax.default_backend() == "tpu" or _pallas_interpret()
     if not on_tpu:  # interpret: kernel on any backend
-        return False
+        return refuse(f"backend {jax.default_backend()}")
     if sharded:
-        _warn_fallback("entity-sharded blocks with no mesh in scope")
-        return False
+        return refuse("entity-sharded blocks with no mesh in scope", True)
     rc = config.regularization_context
     l1 = rc.l1_weight(config.regularization_weight) if rc else 0.0
     if config.optimizer_type not in (OptimizerType.LBFGS,
                                      OptimizerType.TRON):
-        _warn_fallback(f"optimizer {config.optimizer_type}")
-        return False
+        return refuse(f"optimizer {config.optimizer_type}", True)
     if config.optimizer_type == OptimizerType.TRON:
         # solve_glm raises for TRON + L1 or a once-differentiable loss;
         # the vmapped fallback preserves those error contracts.
         if l1 > 0 or not objective.loss.twice_differentiable:
-            return False
+            return refuse("TRON with L1 or a once-differentiable loss")
     if bounds is not None and l1 > 0:
         # solve_glm raises for L1 + bounds; preserve the error contract.
-        return False
+        return refuse("L1 with bounds")
     if objective.normalization is not None:
         # Objective-level (global-context) normalization is the fixed
         # effect's path; per-entity normalization reaches the kernel via
         # the gathered ``norm`` arrays instead.
-        _warn_fallback("objective-level normalization context")
-        return False
+        return refuse("objective-level normalization context", True)
     # VMEM working set per 128-entity grid step, from the same constants
     # the kernel dispatch uses (ops/pallas_entity_solver.py); oversize
     # buckets keep the vmapped path.
@@ -1358,11 +1412,10 @@ def _use_pallas_entity_solver(objective, config, x,
         r, d, itemsize, normalized=norm is not None,
         bounded=bounds is not None)
     if vmem >= VMEM_GUARD_BYTES:
-        _warn_fallback(
+        return refuse(
             f"bucket working set ~{vmem >> 20} MiB exceeds the VMEM "
-            f"budget (r={r}, d={d})")
-        return False
-    return True
+            f"budget (r={r}, d={d})", True)
+    return None
 
 
 @functools.partial(
@@ -1395,7 +1448,8 @@ def _solve_block(
     offsets = block.offsets
     extra = _gather_residual(residual_scores, block)
     if extra is not None:
-        offsets = offsets + extra.astype(offsets.dtype)
+        with jax.named_scope(scopes.RE_GATHER):
+            offsets = offsets + extra.astype(offsets.dtype)
 
     # With a mesh the kernel is still eligible — it runs per device via
     # shard_map below — so the "sharded" rejection only applies when no
@@ -1404,40 +1458,42 @@ def _solve_block(
         objective, config, block.x, sharded=sharded and mesh is None,
         norm=norm, bounds=bounds)
 
-    if use_kernel and sharded and mesh is not None:
-        return _shard_mapped_pallas_solver(
-            objective, config, mesh, block.x, block.labels, offsets,
-            block.weights, coefs0, norm=norm, bounds=bounds)
+    with _re_solve_scope(block):
+        if use_kernel and sharded and mesh is not None:
+            return _shard_mapped_pallas_solver(
+                objective, config, mesh, block.x, block.labels, offsets,
+                block.weights, coefs0, norm=norm, bounds=bounds)
 
-    if use_kernel:
-        return _dispatch_pallas_solver(objective, config, block.x,
-                                       block.labels, offsets,
-                                       block.weights, coefs0, norm=norm,
-                                       bounds=bounds)
+        if use_kernel:
+            return _dispatch_pallas_solver(objective, config, block.x,
+                                           block.labels, offsets,
+                                           block.weights, coefs0, norm=norm,
+                                           bounds=bounds)
 
-    def fit_one(coef0, x, y, off, w, norm_e, bounds_e):
-        from photon_ml_tpu.ops.features import DenseFeatures
+        def fit_one(coef0, x, y, off, w, norm_e, bounds_e):
+            from photon_ml_tpu.ops.features import DenseFeatures
 
-        if norm_e is not None:
-            fac, shf, _ = norm_e
-            # Normalize by rewriting the entity's dense rows inside the
-            # jitted solve (a fusion, not a persistent HBM copy) — the
-            # solve then runs in the normalized space directly, exactly
-            # like the kernel's in-VMEM x' transform.
-            if shf is not None:
-                x = x - shf[None, :]
-            if fac is not None:
-                x = x * fac[None, :]
-        lb, ub = bounds_e if bounds_e is not None else (None, None)
-        batch = GLMBatch(DenseFeatures(x), y, off, w)
-        return solve_glm(objective, batch, config, coef0, lb, ub)
+            if norm_e is not None:
+                fac, shf, _ = norm_e
+                # Normalize by rewriting the entity's dense rows inside
+                # the jitted solve (a fusion, not a persistent HBM copy)
+                # — the solve then runs in the normalized space directly,
+                # exactly like the kernel's in-VMEM x' transform.
+                if shf is not None:
+                    x = x - shf[None, :]
+                if fac is not None:
+                    x = x * fac[None, :]
+            lb, ub = bounds_e if bounds_e is not None else (None, None)
+            batch = GLMBatch(DenseFeatures(x), y, off, w)
+            return solve_glm(objective, batch, config, coef0, lb, ub)
 
-    return jax.vmap(fit_one)(coefs0, block.x, block.labels, offsets,
-                             block.weights, norm, bounds)
+        return jax.vmap(fit_one)(coefs0, block.x, block.labels, offsets,
+                                 block.weights, norm, bounds)
 
 
 @functools.partial(
     jax.jit, static_argnames=("objective", "config", "is_classification"))
+@jax.named_scope(scopes.FE_SOLVE)
 def _solve_fixed(
     objective: GLMObjective, config: GLMOptimizationConfiguration,
     is_classification: bool, batch: GLMBatch, residual_scores, rng_key,
@@ -1470,13 +1526,20 @@ def _solve_fixed(
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows",))
+@jax.named_scope(scopes.FE_SCORE)
 def _fe_score_impl(coef, feats, n_rows: int):
     return feats.matvec(coef)[:n_rows]
 
 
+@jax.named_scope(scopes.RE_SCATTER)
 def _scatter_margins(scores, block, margins, n_rows):
     m = jnp.where(block.row_ids < n_rows, margins, 0.0)
     return scores.at[block.row_ids.reshape(-1)].add(m.reshape(-1))
+
+
+@jax.named_scope(scopes.RE_MARGINS)
+def _local_margins(block, coefs):
+    return block.local_margins(coefs)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows",))
@@ -1484,11 +1547,11 @@ def _re_score_impl(blocks, pblocks, coefs, n_rows: int):
     scores = jnp.zeros((n_rows + 1,),
                        coefs[0].dtype if coefs else jnp.float32)
     for block, c in zip(blocks, coefs):
-        scores = _scatter_margins(scores, block, block.local_margins(c),
+        scores = _scatter_margins(scores, block, _local_margins(block, c),
                                   n_rows)
     for block, c in zip(pblocks, coefs):
         if block is not None:
-            scores = _scatter_margins(scores, block, block.local_margins(c),
+            scores = _scatter_margins(scores, block, _local_margins(block, c),
                                       n_rows)
     return scores[:-1]
 

@@ -372,7 +372,8 @@ class TelemetryInTraceRule:
     # photon_ml_tpu.telemetry entry points that open spans / create
     # metrics; resolved through the import table so local helpers named
     # `span` in unrelated modules do not trip the rule.
-    _FACTORIES = ("span", "timed_span", "counter", "gauge", "histogram")
+    _FACTORIES = ("span", "timed_span", "phase", "counter", "gauge",
+                  "histogram")
     # Metric mutation methods — distinctive enough to flag on name alone
     # (nothing else in the tree defines .inc/.observe).
     _MUTATORS = ("inc", "observe")

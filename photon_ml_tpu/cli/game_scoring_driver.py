@@ -48,7 +48,10 @@ from photon_ml_tpu.io import schemas
 from photon_ml_tpu.io.avro_codec import write_container
 from photon_ml_tpu.io.model_io import load_game_model
 from photon_ml_tpu.telemetry import span
-from photon_ml_tpu.utils.compile_cache import enable_compile_cache
+from photon_ml_tpu.utils.compile_cache import (
+    compile_ledger,
+    enable_compile_cache,
+)
 from photon_ml_tpu.utils.date_range import resolve_input_dirs
 from photon_ml_tpu.utils.logging_utils import setup_photon_logger
 
@@ -233,6 +236,7 @@ def run(argv=None) -> dict:
         wall = time.perf_counter() - t0
         summary["total_seconds"] = wall
         summary["device"] = device_summary()
+        summary["compile"] = compile_ledger(top=20)
         _apply_legacy_aliases(summary)
         obs.finish(summary)
         summary["telemetry"] = telemetry.attribution_summary(wall)

@@ -39,7 +39,10 @@ from photon_ml_tpu.optimization.config import (
 )
 from photon_ml_tpu.telemetry import span
 from photon_ml_tpu.types import TaskType
-from photon_ml_tpu.utils.compile_cache import enable_compile_cache
+from photon_ml_tpu.utils.compile_cache import (
+    compile_ledger,
+    enable_compile_cache,
+)
 from photon_ml_tpu.utils.date_range import resolve_input_dirs
 from photon_ml_tpu.utils.events import (
     EventEmitter,
@@ -676,10 +679,15 @@ def _write_summary(args, out_dir, logger, task, sequence, t0, results,
         "bestConfigs": {k: v.to_string() for k, v in best_configs.items()},
         "objectiveHistory": best_result.objective_history,
         "validationHistory": best_result.validation_history,
+        # HOST DISPATCH seconds per coordinate (an enqueue, not device
+        # time: docs/OBSERVABILITY.md "The training fit").
         "coordinateSeconds": best_result.timings,
         "totalSeconds": wall,
         "total_seconds": wall,
         "device": device_summary(),
+        # What tracing, lowering and compiling (or loading from the
+        # persistent cache) cost, by jitted function: the 20 costliest.
+        "compile": compile_ledger(top=20),
     }
     if stream_info is not None:
         # ``stream_train`` is the canonical snake_case schema; the

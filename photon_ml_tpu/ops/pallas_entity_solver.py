@@ -59,6 +59,7 @@ from photon_ml_tpu.optimization.convergence import (
     OptimizerResult,
 )
 from photon_ml_tpu.optimization.owlqn import pseudo_gradient
+from photon_ml_tpu.telemetry import scopes
 
 Array = jax.Array
 
@@ -988,6 +989,11 @@ def pallas_entity_lbfgs(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        # The device trace's event for this kernel: the benchmark sums
+        # the operations whose name starts with scopes.KERNEL (a mode
+        # suffix may follow it, another prefix may not).
+        name=(scopes.KERNEL if mode == "lbfgs"
+              else f"{scopes.KERNEL}_{mode}"),
     )(jnp.asarray(l2_weight, dtype).reshape(1),
       jnp.asarray(l1_weight, dtype).reshape(1),
       x_l, y_l, off_l, w_l, c0_l, *extra_inputs)

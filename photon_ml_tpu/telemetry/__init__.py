@@ -37,6 +37,7 @@ from photon_ml_tpu.telemetry.spans import (
     Tracer,
     attribution_summary,
     export_chrome_trace,
+    phase,
     span,
     stage_attribution,
     timed_span,
@@ -173,6 +174,7 @@ __all__ = [
     "install_sigterm_dump",
     "mint",
     "parse_slo",
+    "phase",
     "prometheus_name",
     "read_obs_descriptor",
     "registry",

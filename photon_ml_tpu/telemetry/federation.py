@@ -114,6 +114,10 @@ GAUGE_MERGE_POLICIES: Dict[str, str] = {
     # than summing axis extents into a meaningless total. (The
     # training.mesh.*_transfer_bytes series are counters and sum.)
     "training.mesh.": "last",
+    # Random-effect work of the fit a process was built with
+    # (algorithm/coordinate_descent.py): slots, true rows and entities by
+    # solve path are per-process holdings, so the fleet has the sum.
+    "training.re.": "sum",
     # Network front door (serving/netserver.py): connections held open
     # are per-process holdings — the fleet has the sum. (Everything
     # else under serving.net.* is a counter; lint rule counter-family.)

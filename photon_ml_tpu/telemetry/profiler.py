@@ -25,11 +25,12 @@ r4096 cost on the device". This profiler records, per cache key:
   number is an upper bound on device time — the same caveat as the
   ``device_wait`` span, documented in docs/OBSERVABILITY.md.
 
-``table()`` renders the roofline-style per-bucket view served on
-``/statusz`` and written into metrics.json: per key, compile economics
-(lower/first-call seconds, FLOPs, bytes) next to steady-state dispatch
-statistics (count, mean/min/max seconds, est. FLOP/s from the static
-FLOP count over the mean dispatch wall).
+``table()`` renders the per-bucket view served on ``/statusz`` and
+written into metrics.json: per key, compile economics (lower/first-call
+seconds, static FLOPs, bytes) next to steady-state dispatch statistics
+(count, mean/min/max seconds). No rate is derived from the two: static
+FLOPs over a dispatch-to-settle host wall is the wrong source for a
+utilization (PERF.md §6); that number comes from a device trace.
 """
 
 from __future__ import annotations
@@ -85,8 +86,8 @@ class ExecutableProfiler:
         first real call still compiles exactly once), so the per-key cost
         is one extra trace — small against the compile it annotates.
         ``rows_bucket`` is the key's rows component, passed structurally
-        by the caller (who holds the real key tuple) so ``table()`` can
-        join builds onto dispatch rows without parsing key reprs."""
+        by the caller (who holds the real key tuple) so a reader can join
+        builds onto dispatch rows without parsing key reprs."""
         entry = {"lower_s": None, "first_call_s": None,
                  "rows_bucket": (int(rows_bucket)
                                  if rows_bucket is not None else None)}
@@ -140,39 +141,22 @@ class ExecutableProfiler:
 
     def table(self) -> dict:
         """The /statusz + metrics.json per-bucket compile/device-time
-        table: ``builds`` (per cache key) and ``dispatch`` (per rows
-        bucket, with est_flops_per_sec where a build on that key
-        reported FLOPs — roofline-style: static FLOPs over mean
-        dispatch-to-settle wall, an UPPER-bound denominator and so a
-        LOWER-bound rate)."""
+        table: ``builds`` (per cache key, with the static FLOPs and bytes
+        of its lowering) and ``dispatch`` (per rows bucket:
+        dispatch-to-settle wall statistics)."""
         with self._lock:
             builds = {k: dict(v) for k, v in self._builds.items()}
             dispatch = {k: dict(v) for k, v in self._dispatch.items()}
-        # FLOPs per rows-bucket (recorded structurally at build time);
-        # several nnz buckets share a rows bucket — take the max (the
-        # widest executable bounds the rate).
-        flops_by_rb: Dict[int, float] = {}
-        for b in builds.values():
-            fl = b.get("flops")
-            rb = b.get("rows_bucket")
-            if fl is None or rb is None:
-                continue
-            flops_by_rb[rb] = max(flops_by_rb.get(rb, 0.0), fl)
         out_dispatch = {}
         for rb, d in sorted(dispatch.items()):
-            mean_s = d["sum_s"] / d["count"] if d["count"] else None
-            row = {
+            out_dispatch[f"r{rb}"] = {
                 "rows_bucket": rb,
                 "dispatches": d["count"],
                 "rows": d["rows"],
-                "mean_s": mean_s,
+                "mean_s": d["sum_s"] / d["count"] if d["count"] else None,
                 "min_s": d["min_s"],
                 "max_s": d["max_s"],
             }
-            fl = flops_by_rb.get(rb)
-            if fl is not None and mean_s:
-                row["est_flops_per_sec"] = fl / mean_s
-            out_dispatch[f"r{rb}"] = row
         return {"builds": builds, "dispatch": out_dispatch}
 
     def reset(self) -> None:

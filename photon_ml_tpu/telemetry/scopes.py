@@ -1,0 +1,75 @@
+"""The names of the training fit's phases: one table, imported by the code
+that opens each name, by the tests and by ``dev_scripts/trace_scopes.py``
+(docs/OBSERVABILITY.md §The training fit lists each with its metric).
+
+Device phases are ``jax.named_scope`` inside the jitted block and step
+(HLO metadata only: the ``op_name`` path of every operation traced under
+the scope carries the name, and the profiler's device events carry that
+path; nothing runs for it). Host phases are ``telemetry.spans.phase``
+spans of ``CoordinateDescent.run``. Both land in the profiler's one trace,
+on one clock.
+"""
+
+from __future__ import annotations
+
+PREFIX = "photon."
+
+# -- device scopes (jax.named_scope inside traced code) -----------------------
+FE_SOLVE = "photon.fe.solve"      # body of _solve_fixed
+FE_SCORE = "photon.fe.score"      # _fe_score_impl
+RE_GATHER = "photon.re.gather"    # residual gather into the blocks' slots
+RE_SOLVE = "photon.re.solve"      # kernel or vmapped solve, one child a class
+RE_MARGINS = "photon.re.margins"  # block.local_margins
+RE_SCATTER = "photon.re.scatter"  # margins scattered back into row order
+CD_OBJECTIVE = "photon.cd.objective"  # the loss sum and the penalties
+
+#: The leaf scopes: an operation counts under the innermost of these on
+#: its path.
+DEVICE_SCOPES = (FE_SOLVE, FE_SCORE, RE_GATHER, RE_SOLVE, RE_MARGINS,
+                 RE_SCATTER, CD_OBJECTIVE)
+#: gather + margins + scatter: the score exchange.
+EXCHANGE_SCOPES = (RE_GATHER, RE_MARGINS, RE_SCATTER)
+
+_CD = "photon.cd."
+
+
+def cd_coordinate(name: str) -> str:
+    """The scope around one coordinate's whole update inside the block:
+    the place to read a coordinate's device time."""
+    return _CD + name
+
+
+def re_size_class(rows: int) -> str:
+    """Child of ``RE_SOLVE``: one per bucket size class (padded rows)."""
+    return f"r{int(rows)}"
+
+
+# -- the kernel and the jitted programs ---------------------------------------
+#: ``name=`` of the entity solver's ``pallas_call`` (a mode suffix follows):
+#: the prefix of its device events.
+KERNEL = "pallas_entity_lbfgs"
+#: Function names of the jitted block and step: JAX's compile events and
+#: the trace's ``XLA Modules`` line carry them as ``jit_<name>``.
+CD_BLOCK = "cd_block"
+CD_STEP = "cd_step"
+
+# -- host phases of CoordinateDescent.run (telemetry.spans.phase) ------------
+CD_RUN = "photon.cd.run"                        # parent of all
+CD_PREPARE = "photon.cd.run.prepare"            # models, step_data, params
+CD_INITIAL_SCORES = "photon.cd.run.initial_scores"
+CD_DISPATCH = "photon.cd.run.dispatch"  # enqueue; trace/lower/load on the 1st
+CD_WAIT = "photon.cd.run.wait"          # device_get: host blocked on device
+CD_FINISH = "photon.cd.run.finish"      # _sync_models, tracing guard, result
+CD_VALIDATE = "photon.cd.run.validate"
+CD_CHECKPOINT = "photon.cd.run.checkpoint"
+
+#: Phases every run opens; validate and checkpoint only where they run.
+HOST_PHASES = (CD_PREPARE, CD_INITIAL_SCORES, CD_DISPATCH, CD_WAIT,
+               CD_FINISH)
+OPTIONAL_HOST_PHASES = (CD_VALIDATE, CD_CHECKPOINT)
+
+# -- gauges set when a random-effect coordinate is built ----------------------
+GAUGE_RE_SLOTS = "training.re.slots"
+GAUGE_RE_ROWS = "training.re.rows"
+GAUGE_RE_KERNEL_ENTITIES = "training.re.kernel_entities"
+GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"

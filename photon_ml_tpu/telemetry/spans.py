@@ -25,6 +25,13 @@ RULES (enforced by the jaxlint ``telemetry-in-trace`` rule):
 
 Disabled mode (the default) returns one shared no-op context manager —
 no allocation, one branch (asserted in tests/test_telemetry.py).
+
+Every recorded span also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so the drivers' stages sit in the profiler's trace
+(``--profile-output-dir``) on the device operations' clock. ``phase()`` is
+the helper for the few host phases that must be visible to ANY profiler
+session, telemetry enabled or not (``CoordinateDescent.run``'s, named in
+telemetry/scopes.py): an annotation always, a span besides when enabled.
 """
 
 from __future__ import annotations
@@ -60,6 +67,20 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (a no-op costing one atomic
+    read while no profiler session is active). JAX is imported on first
+    use, so this module stays importable without it."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
 
 
 class Tracer:
@@ -186,19 +207,22 @@ class _Span:
     recorded (and its duration charged to the parent's child time) at
     exit."""
 
-    __slots__ = ("name", "t0", "child_s")
+    __slots__ = ("name", "t0", "child_s", "_ann")
 
     def __init__(self, name: str):
         self.name = name
         self.child_s = 0.0
+        self._ann = _annotation(name)
 
     def __enter__(self):
         _TRACER._stack().append(self)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         stack = _TRACER._stack()
         # Tolerate out-of-order exits (generator spans closed by GC):
         # unwind to this span rather than corrupting the stack.
@@ -219,6 +243,19 @@ def span(name: str):
     inside jit-traced code (jaxlint: telemetry-in-trace)."""
     if not _reg._enabled:
         return _NOOP
+    return _Span(name)
+
+
+def phase(name: str):
+    """Open a named host phase that any profiler session sees: always a
+    ``TraceAnnotation`` (the benchmark's ``--trace 1`` and
+    ``--profile-output-dir`` read it without ``telemetry.enable()``), and
+    an ordinary ``span`` besides when telemetry is enabled, so
+    ``stage_attribution()`` and metrics.json carry the same name with
+    self time. For the handful of phases of a fit, not per-item work.
+    NEVER call inside jit-traced code."""
+    if not _reg._enabled:
+        return _annotation(name)
     return _Span(name)
 
 
