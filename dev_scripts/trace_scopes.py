@@ -17,6 +17,8 @@ drops), and prints per job:
   listed under ``photon.re.solve`` with the path each took;
 - the same rolled up by ``photon.cd.<coordinate>``;
 - the exchange (gather + margins + scatter);
+- what of each scope ran before ``cd_block``'s first operation (the eager
+  initial-scores pass of a warm start; nothing on a cold one);
 - the remainder under no ``photon.*`` scope, with its largest operations;
 - every idle gap over ``--gap-ms`` with the innermost ``photon.cd.*`` host
   span that covers it (``bench.*`` where none does).
@@ -316,6 +318,10 @@ def place(path: str) -> dict:
             "scoped": leaf is not None or coordinate is not None}
 
 
+def in_block(path: str) -> bool:
+    return path.startswith(f"jit({scopes.CD_BLOCK})/")
+
+
 def job_spans(trace: dict) -> List[Tuple[int, int, str]]:
     return host_spans(trace, lambda n: n == JOB_SPAN)
 
@@ -389,9 +395,17 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
             if edges[i + 1] - edges[i] > gap_ms * 1e6]
     scope_ms = {s: covered_ms(by_leaf.get(s, []), lo, hi)
                 for s in scopes.DEVICE_SCOPES}
+    # What ran before the block's first operation: a cold start's initial
+    # scores are built, so no scoring scope may show here (a warm start's,
+    # and a coordinate's that declares no zero start, do).
+    block_starts = [s for _, s, d, path in events
+                    if lo <= s < hi and d > 0 and in_block(path)]
+    first = min(block_starts, default=hi)
     return {
         "window_ms": (hi - lo) / 1e6, "busy_ms": busy_ms,
         "scope_ms": scope_ms,
+        "before_block_ms": {s: covered_ms(by_leaf.get(s, []), lo, first)
+                            for s in scopes.DEVICE_SCOPES},
         "exchange_ms": sum(scope_ms[s] for s in scopes.EXCHANGE_SCOPES),
         "size_class_ms": {
             c: {"ms": covered_ms(v["ivs"], lo, hi),
@@ -448,7 +462,7 @@ def _mean(per_job: List[dict]) -> dict:
     out = {k: sum(j[k] for j in per_job) / n
            for k in ("window_ms", "busy_ms", "exchange_ms",
                      "unattributed_ms", "unattributed_share")}
-    for key in ("scope_ms", "coordinate_ms"):
+    for key in ("scope_ms", "before_block_ms", "coordinate_ms"):
         names = sorted({s for j in per_job for s in j[key]})
         out[key] = {s: sum(j[key].get(s, 0.0) for j in per_job) / n
                     for s in names}
@@ -489,6 +503,10 @@ def print_report(result: dict, out=sys.stdout) -> None:
         print(f"| under no `photon.*` scope | "
               f"{block['unattributed_ms']:.3f} | "
               f"{100 * block['unattributed_share']:.2f}% |", file=out)
+        early = {s: ms for s, ms in block["before_block_ms"].items() if ms}
+        print("before the block's first operation: " + (", ".join(
+            f"`{s}` {ms:.3f} ms" for s, ms in early.items())
+            or "no scoped operation"), file=out)
 
     for k, job in enumerate(result["jobs"]):
         table(job, f"job {k}")

@@ -16,15 +16,26 @@ per-coordinate "addScoresToOffsets" shuffle is a gather.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
+from typing import (
+    Dict,
+    List,
+    Mapping,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+    Union,
+)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from photon_ml_tpu import telemetry
 from photon_ml_tpu.algorithm.coordinates import Coordinate
 from photon_ml_tpu.data.game_data import GameDataset
 from photon_ml_tpu.evaluation.evaluators import Evaluator
@@ -38,6 +49,11 @@ from photon_ml_tpu.utils.tracing_guard import TracingGuard
 logger = logging.getLogger(__name__)
 
 Array = jax.Array
+
+# Module-level handles (get-or-create by name; inc is a no-op while
+# telemetry is off).
+_M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
+_M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
 
 
 def _unstack_tracker_block(trs: Dict[str, object], names: Sequence[str],
@@ -136,6 +152,7 @@ class CoordinateDescent:
         self._fused_fns = None
         self._block_fns: Dict[int, object] = {}
         self._val_scorer = None
+        self._cold_cache = None
         # Shared retrace infrastructure (utils/tracing_guard.py): every
         # fused executable registers here, and run() asserts the hot
         # loop's compile-count invariant — each executable traces exactly
@@ -150,8 +167,6 @@ class CoordinateDescent:
         vmapped fallback solve (``coordinate.routing()`` has each bucket's
         reason). Set once, here, and only while telemetry is enabled: the
         true-row count is one small device reduction a bucket."""
-        from photon_ml_tpu import telemetry
-
         if not telemetry.enabled():
             return
         routed = [c for c in self.coordinates.values()
@@ -333,12 +348,7 @@ class CoordinateDescent:
                         timings=timings, trackers=trackers, meta=meta))
 
             with phase(scopes.CD_PREPARE):
-                if initial_model is None:
-                    models = {n: c.initialize_model()
-                              for n, c in self.coordinates.items()}
-                else:
-                    models = {n: initial_model.get_model(n) for n in names}
-
+                models = None
                 if checkpoint_dir is not None:
                     latest = ckpt.latest_checkpoint(checkpoint_dir)
                     if latest is not None:
@@ -370,6 +380,11 @@ class CoordinateDescent:
                                                    self.task_type)
                         logger.info("resumed from %s (step %d)", latest,
                                     done_steps)
+                # A start is COLD when nothing was handed in and nothing
+                # restored: the state the block starts from is then the
+                # coordinates' own initial models, known from shapes alone.
+                cold = initial_model is None and models is None
+                _M_RUNS.inc()
 
                 # The fused path: params/scores dicts are the authoritative
                 # training state on device; model objects are materialized
@@ -378,16 +393,24 @@ class CoordinateDescent:
                 data_args = {n: self.coordinates[n].step_data() for n in names}
                 pdata_args = {n: self.coordinates[n].penalty_data()
                               for n in names}
-                params = {n: self.coordinates[n].params_of(models[n])
-                          for n in names}
-                # Canonicalize param leaves to device arrays: checkpoint-
-                # loaded models carry host np.ndarray leaves, and np inputs
-                # key a SEPARATE pjit executable from the device arrays of
-                # steady-state calls — one silent recompile per coordinate
-                # on every resume (surfaced by tracing_guard's per_fn=1
-                # invariant below).
-                params = {n: jax.tree.map(jnp.asarray, p)
-                          for n, p in params.items()}
+                if cold:
+                    _M_COLD_STARTS.inc()
+                    start = self._cold_start(data_args)
+                    models, params = dict(start.models), dict(start.params)
+                else:
+                    if models is None:
+                        models = {n: initial_model.get_model(n)
+                                  for n in names}
+                    params = {n: self.coordinates[n].params_of(models[n])
+                              for n in names}
+                    # Canonicalize param leaves to device arrays:
+                    # checkpoint-loaded models carry host np.ndarray leaves,
+                    # and np inputs key a SEPARATE pjit executable from the
+                    # device arrays of steady-state calls — one silent
+                    # recompile per coordinate on every resume (surfaced by
+                    # tracing_guard's per_fn=1 invariant below).
+                    params = {n: jax.tree.map(jnp.asarray, p)
+                              for n, p in params.items()}
                 fused = self._fused_update_fns()
 
             def _sync_models():
@@ -396,22 +419,30 @@ class CoordinateDescent:
                         params[m], models[m])
 
             with phase(scopes.CD_INITIAL_SCORES):
+                # Cold: the coordinates whose initial model scores zero by
+                # construction (``Coordinate.zero_start``) get their zero
+                # vectors from ONE program and no pass over the data; every
+                # other start is scored.
+                built = _zero_vectors(start.score_specs) if cold else {}
                 scores: Dict[str, Array] = {
-                    n: self.coordinates[n].pure_score(data_args[n], params[n])
+                    n: (built[n] if n in built else
+                        self.coordinates[n].pure_score(data_args[n],
+                                                       params[n]))
                     for n in names}
                 rows = self._training_rows(next(iter(scores.values())).dtype)
 
-            # Objective history lives in a FIXED-CAPACITY device vector updated
-            # by a tiny jitted set (enqueue-only); materialization is ONE
-            # device->host transfer. Per-entry float() syncs each wait for the
-            # device and would dominate whole runs. Capacity is padded to a
-            # power of two so the updater executable is shared across runs of
-            # different lengths.
+            # The per-step path's objective history lives in a FIXED-CAPACITY
+            # device vector updated by a tiny jitted set (enqueue-only);
+            # materialization is ONE device->host transfer. Per-entry float()
+            # syncs each wait for the device and would dominate whole runs.
+            # Capacity is padded to a power of two so the updater executable
+            # is shared across runs of different lengths. Made at the first
+            # per-step update: a run of whole blocks never dispatches it.
             total_steps = max(num_iterations * len(names),
                               len(objective_history))
             cap = max(64, 1 << max(0, total_steps - 1).bit_length())
             hist_dtype = np.dtype(next(iter(scores.values())).dtype)
-            hist_dev = jnp.zeros(cap, hist_dtype)
+            hist_dev = None
             hist_len = len(objective_history)  # absolute step count written
             mat_hist_len = hist_len  # prefix already materialized (resumed)
 
@@ -577,6 +608,8 @@ class CoordinateDescent:
                     # Device-side history write — NOT synced here (a float()
                     # per update waits for the device); materialized in one
                     # transfer at checkpoint/return.
+                    if hist_dev is None:
+                        hist_dev = jnp.zeros(cap, hist_dtype)
                     hist_dev = _hist_set(hist_dev, np.uint32(step - 1), obj)
                     hist_len = max(hist_len, step)
                     logger.info("iter %d coordinate %s enqueued (host "
@@ -629,6 +662,29 @@ class CoordinateDescent:
                     timings=timings,
                 )
 
+    def _cold_start(self, data_args) -> "_ColdStart":
+        """What a cold ``run()`` starts from, derived ONCE per object (like
+        ``_rows_cache``): every job after the first finds it here, because
+        whatever the host does before the block's enqueue is device idle.
+
+        The coordinates' initial models and their parameters are immutable
+        device arrays and the block donates nothing, so every cold run
+        starts from the same ones. The scores are the block's carry, which
+        it replaces: each run needs vectors of its own, of exactly the
+        shape and dtype ``pure_score`` returns (``jax.eval_shape``: a
+        trace, nothing runs) and, where the coordinate's data spans
+        devices, with the sharding its compiled output carries."""
+        if self._cold_cache is not None:
+            return self._cold_cache
+        models = {n: c.initialize_model()
+                  for n, c in self.coordinates.items()}
+        params = {n: jax.tree.map(jnp.asarray, c.params_of(models[n]))
+                  for n, c in self.coordinates.items()}
+        self._cold_cache = _ColdStart(models, params, tuple(
+            (n, _score_spec(c, data_args[n], params[n]))
+            for n, c in self.coordinates.items() if c.zero_start))
+        return self._cold_cache
+
     def _training_rows(self, dtype) -> Tuple[Array, Array, Array]:
         """(labels, offsets, weights) aligned with the global row order,
         taken from the first coordinate's data. Cached — built once per run,
@@ -652,6 +708,41 @@ class CoordinateDescent:
             rows = tuple(r.astype(dtype) for r in rows)
         self._rows_cache = rows
         return rows
+
+
+class _ColdStart(NamedTuple):
+    """``CoordinateDescent._cold_start``: by coordinate name, the initial
+    models, their parameters, and the spec of the initial score vector of
+    each coordinate whose ``zero_start`` says it may be built."""
+    models: Dict[str, object]
+    params: Dict[str, object]
+    score_specs: Tuple[Tuple[str, jax.ShapeDtypeStruct], ...]
+
+
+def _score_spec(coord: Coordinate, data, params) -> jax.ShapeDtypeStruct:
+    """Shape, dtype and sharding of ``coord.pure_score(data, params)``
+    without running it. Data on one device: a trace. Data over a mesh: the
+    compiler chooses the output's sharding, so the scoring program is
+    compiled (as the scored branch would compile it) and asked."""
+    out = jax.eval_shape(coord.pure_score, data, params)
+    if all(len(leaf.sharding.device_set) == 1
+           for leaf in jax.tree.leaves((data, params))
+           if isinstance(leaf, jax.Array)):
+        return jax.ShapeDtypeStruct(out.shape, out.dtype)
+    compiled = jax.jit(coord.pure_score).lower(data, params).compile()
+    return jax.ShapeDtypeStruct(out.shape, out.dtype,
+                                sharding=compiled.output_shardings)
+
+
+@functools.partial(jax.jit, static_argnames=("specs",))
+def _zero_vectors(specs: Tuple[Tuple[str, jax.ShapeDtypeStruct], ...]):
+    """One program, keyed by the specs, for all the zero vectors a cold
+    start needs: one dispatch a run whatever the number of coordinates."""
+    return {
+        n: (jnp.zeros(s.shape, s.dtype) if s.sharding is None else
+            lax.with_sharding_constraint(jnp.zeros(s.shape, s.dtype),
+                                         s.sharding))
+        for n, s in specs}
 
 
 @jax.jit

@@ -47,6 +47,14 @@ from photon_ml_tpu.types import TaskType
 Array = jax.Array
 
 
+def scores_zero_by_construction(initialize_model):
+    """Marks an ``initialize_model`` whose model scores zero on every row
+    whatever the data: all that multiplies a feature is zero
+    (``Coordinate.zero_start`` reads the mark; no frame is added)."""
+    initialize_model.scores_zero = True
+    return initialize_model
+
+
 class Coordinate:
     """Interface: update_model(model, residual_scores) and score(model).
 
@@ -68,6 +76,16 @@ class Coordinate:
     """
 
     name: str
+
+    @property
+    def zero_start(self) -> bool:
+        """Whether ``initialize_model()`` is KNOWN to score zero on every
+        row (``scores_zero_by_construction``): ``CoordinateDescent.run``
+        then builds a cold start's initial scores, and computes them
+        (``pure_score``) otherwise. A property of the function that builds
+        the model and of nothing else: a class that brings its own
+        ``initialize_model`` starts without it."""
+        return getattr(type(self).initialize_model, "scores_zero", False)
 
     def update_model(self, model, residual_scores: Optional[Array], rng_key):
         raise NotImplementedError
@@ -193,6 +211,7 @@ class FixedEffectCoordinate(Coordinate):
         return jnp.pad(jnp.asarray(arr), (0, self._d_pad - self._d),
                        constant_values=fill)
 
+    @scores_zero_by_construction
     def initialize_model(self) -> FixedEffectModel:
         d = self.data.feature_shards[self.feature_shard_id].shape[1]
         glm_cls = model_for_task(self.task_type)
@@ -616,6 +635,7 @@ class RandomEffectCoordinate(Coordinate):
         return sum(int(jnp.sum(b.row_ids < self.dataset.n_rows))
                    for b in self.dataset.blocks)
 
+    @scores_zero_by_construction
     def initialize_model(self) -> RandomEffectModel:
         dt = (self.dataset.blocks[0].x.dtype if self.dataset.blocks
               else jnp.float32)
@@ -1054,6 +1074,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def _dtype(self):
         return self.dataset.blocks[0].x.dtype
 
+    @scores_zero_by_construction  # B is Gaussian, every latent factor zero
     def initialize_model(self):
         from photon_ml_tpu.models.factored_random_effect import (
             FactoredRandomEffectModel,

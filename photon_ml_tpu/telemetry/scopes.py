@@ -55,7 +55,14 @@ CD_STEP = "cd_step"
 
 # -- host phases of CoordinateDescent.run (telemetry.spans.phase) ------------
 CD_RUN = "photon.cd.run"                        # parent of all
-CD_PREPARE = "photon.cd.run.prepare"            # models, step_data, params
+# prepare: checkpoint restore, step_data, models and params. Cold start (no
+# initial_model, nothing restored): both come from the object's cache, no
+# dispatch after its first run. Warm or resumed: params_of the models given.
+CD_PREPARE = "photon.cd.run.prepare"
+# initial_scores: cold, the enqueue of ONE program that makes the zero
+# vectors (about nothing); warm or resumed, or a coordinate without a
+# declared zero start: the enqueue of one eager pure_score a coordinate
+# (device: a pass over X, the margins and a scatter of every slot).
 CD_INITIAL_SCORES = "photon.cd.run.initial_scores"
 CD_DISPATCH = "photon.cd.run.dispatch"  # enqueue; trace/lower/load on the 1st
 CD_WAIT = "photon.cd.run.wait"          # device_get: host blocked on device
@@ -73,3 +80,9 @@ GAUGE_RE_SLOTS = "training.re.slots"
 GAUGE_RE_ROWS = "training.re.rows"
 GAUGE_RE_KERNEL_ENTITIES = "training.re.kernel_entities"
 GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"
+
+# -- counters of CoordinateDescent.run (inc is a no-op while telemetry is off) -
+COUNTER_CD_RUNS = "training.cd.runs"
+#: Runs that started cold (no ``initial_model``, no checkpoint restored):
+#: the runs whose initial scores were built and not computed.
+COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"

@@ -475,6 +475,31 @@ def test_reduce_scopes_on_the_recorded_chip_trace():
         scopes.CD_RUN, scopes.CD_PREPARE, scopes.CD_WAIT}
 
 
+RECORDED_COLD = RECORDED.with_name("trace_glmix_fit_cold.json")
+
+
+@pytest.mark.parametrize("recorded, before", [
+    # PR 29's tree: every start paid the eager initial-scores pass
+    (RECORDED, {scopes.FE_SCORE: 6.352, scopes.RE_MARGINS: 1.501,
+                scopes.RE_SCATTER: 84.229}),
+    # PR 30's: a cold start builds its zero scores
+    (RECORDED_COLD, {}),
+])
+def test_reduction_says_what_ran_before_the_block(recorded, before):
+    """One job of ``glmix.fit`` as the chip ran it, before and after cold
+    starts stopped scoring zero models: the scoring scopes' time ahead of
+    ``cd_block``'s first operation, and nothing else, is what went."""
+    (job,) = trace_scopes.reduce_scopes(
+        json.loads(recorded.read_text()))["jobs"]
+    early = {s: ms for s, ms in job["before_block_ms"].items() if ms}
+    assert early == pytest.approx(before, abs=1e-3)
+    in_block = {s: job["scope_ms"][s] - job["before_block_ms"][s]
+                for s in scopes.DEVICE_SCOPES}
+    assert in_block[scopes.RE_SCATTER] == pytest.approx(84.6, abs=0.3)
+    assert in_block[scopes.FE_SCORE] == pytest.approx(6.35, abs=0.05)
+    assert in_block[scopes.FE_SOLVE] == pytest.approx(146.54, abs=0.1)
+
+
 # A hand-encoded XSpace: protobuf wire format, as the profiler writes it.
 
 def _varint(n: int) -> bytes:
