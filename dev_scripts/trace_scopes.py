@@ -21,7 +21,12 @@ drops), and prints per job:
   initial-scores pass of a warm start; nothing on a cold one);
 - the remainder under no ``photon.*`` scope, with its largest operations;
 - every idle gap over ``--gap-ms`` with the innermost ``photon.cd.*`` host
-  span that covers it (``bench.*`` where none does).
+  span that covers it (``bench.*`` where none does);
+- of every scope's time, the part that is collectives (operations named
+  ``all-reduce*``, ``all-gather*``, ``reduce-scatter*``, ``all-to-all*``,
+  ``collective-permute*``), and the device-busy time chip by chip. Over a
+  trace of several chips every number is the mean over the chips' planes;
+  the gaps and the unscoped operations listed are the first chip's.
 
 Where the path lives (looked at by hand on the v5e, JAX 0.9.0, PR 29): in
 the stat ``tf_op`` of the event's METADATA (one per HLO instruction of a
@@ -75,6 +80,13 @@ from benchmark.trace_reduce import (  # noqa: E402
 from photon_ml_tpu.telemetry import scopes  # noqa: E402
 
 HOST_SPAN_PREFIXES = (scopes.PREFIX, "bench.")
+# Operations that are collectives, by the start of their HLO name (``%``
+# stripped). What the v5e prints (looked at by hand, PR 31, JAX 0.9.0):
+# see ``PERF.md`` section 5. An asynchronous one is two events,
+# ``<name>-start`` and ``<name>-done``: both carry the prefix, both count.
+COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
+                       "all-to-all", "collective-permute")
+NO_SCOPE = "(no scope)"
 # The stat that carries the HLO metadata's op_name (``tf_op`` on the v5e,
 # PERF.md §5); any other stat whose value holds a ``photon.`` scope is
 # taken where a later profiler renames it.
@@ -358,12 +370,15 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
     by_coord: Dict[str, list] = {}
     by_class: Dict[str, dict] = {}
     scoped, everything, loose = [], [], {}
+    collectives: Dict[str, list] = {}
     for name, s, d, path in events:
         if s + d <= lo or s >= hi or d <= 0:
             continue
         iv = (s, s + d)
         everything.append(iv)
         where = place(path)
+        if short_name(name).lstrip("%").startswith(COLLECTIVE_PREFIXES):
+            collectives.setdefault(where["leaf"] or NO_SCOPE, []).append(iv)
         if not where["scoped"]:
             n = short_name(name)
             loose.setdefault(n, []).append(iv)
@@ -407,6 +422,11 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
         "before_block_ms": {s: covered_ms(by_leaf.get(s, []), lo, first)
                             for s in scopes.DEVICE_SCOPES},
         "exchange_ms": sum(scope_ms[s] for s in scopes.EXCHANGE_SCOPES),
+        # of each scope's time, what is collectives (union of intervals)
+        "collective_ms": {s: covered_ms(ivs, lo, hi)
+                          for s, ivs in sorted(collectives.items())},
+        "collectives_ms": covered_ms(
+            [iv for ivs in collectives.values() for iv in ivs], lo, hi),
         "size_class_ms": {
             c: {"ms": covered_ms(v["ivs"], lo, hi),
                 "path": "kernel" if v["kernel"] else "vmapped"}
@@ -445,24 +465,37 @@ def reduce_scopes(trace: dict, gap_ms: float = 0.2) -> dict:
               if p["name"].startswith(DEVICE_PLANE_PREFIXES)]
     if not planes:
         raise ValueError("the trace holds no device plane")
-    events = [e for ln in planes[0]["lines"] if ln["name"] == OPS_LINE
-              for e in ln["events"]]
+    chips = [[e for ln in plane["lines"] if ln["name"] == OPS_LINE
+              for e in ln["events"]] for plane in planes]
     jobs = job_spans(trace)
     if not jobs:
         raise ValueError(f"the trace holds no {JOB_SPAN} span")
     spans = host_spans(trace, lambda n: n != JOB_SPAN
                        and n.startswith(HOST_SPAN_PREFIXES))
-    per_job = [reduce_job(events, spans, lo, hi, gap_ms)
-               for lo, hi, _ in jobs]
-    return {"jobs": per_job, "mean": _mean(per_job)}
+    per_job = []
+    for lo, hi, _ in jobs:
+        per_chip = [reduce_job(events, spans, lo, hi, gap_ms)
+                    for events in chips]
+        # One chip: its numbers. Several: the mean over chips; the gaps and
+        # the unscoped operations listed are the first chip's.
+        job = per_chip[0] if len(per_chip) == 1 else {
+            **per_chip[0], **_mean(per_chip)}
+        job["chip_busy_ms"] = [c["busy_ms"] for c in per_chip]
+        per_job.append(job)
+    mean = _mean(per_job)
+    mean["chip_busy_ms"] = [
+        sum(j["chip_busy_ms"][k] for j in per_job) / len(per_job)
+        for k in range(len(chips))]
+    return {"jobs": per_job, "mean": mean}
 
 
 def _mean(per_job: List[dict]) -> dict:
     n = len(per_job)
     out = {k: sum(j[k] for j in per_job) / n
-           for k in ("window_ms", "busy_ms", "exchange_ms",
+           for k in ("window_ms", "busy_ms", "exchange_ms", "collectives_ms",
                      "unattributed_ms", "unattributed_share")}
-    for key in ("scope_ms", "before_block_ms", "coordinate_ms"):
+    for key in ("scope_ms", "before_block_ms", "coordinate_ms",
+                "collective_ms"):
         names = sorted({s for j in per_job for s in j[key]})
         out[key] = {s: sum(j[key].get(s, 0.0) for j in per_job) / n
                     for s in names}
@@ -484,11 +517,14 @@ def print_report(result: dict, out=sys.stdout) -> None:
         busy = block["busy_ms"]
         print(f"\n{title}: window {block['window_ms']:.3f} ms, device busy "
               f"{busy:.3f} ms", file=out)
-        print("| scope | ms | share of busy |", file=out)
-        print("| --- | --- | --- |", file=out)
+        coll = block["collective_ms"]
+        print("| scope | ms | share of busy | of it collectives, ms |",
+              file=out)
+        print("| --- | --- | --- | --- |", file=out)
         for s in scopes.DEVICE_SCOPES:
             ms = block["scope_ms"].get(s, 0.0)
-            print(f"| `{s}` | {ms:.3f} | {100 * ms / busy:.2f}% |", file=out)
+            print(f"| `{s}` | {ms:.3f} | {100 * ms / busy:.2f}% | "
+                  f"{coll.get(s, 0.0):.3f} |", file=out)
             if s == scopes.RE_SOLVE:
                 for c, v in block["size_class_ms"].items():
                     print(f"| &nbsp;&nbsp;`{c}` ({v['path']}) | "
@@ -502,7 +538,12 @@ def print_report(result: dict, out=sys.stdout) -> None:
                   f"{100 * ms / busy:.2f}% |", file=out)
         print(f"| under no `photon.*` scope | "
               f"{block['unattributed_ms']:.3f} | "
-              f"{100 * block['unattributed_share']:.2f}% |", file=out)
+              f"{100 * block['unattributed_share']:.2f}% | "
+              f"{coll.get(NO_SCOPE, 0.0):.3f} |", file=out)
+        print(f"collectives (union over scopes, mean over chips): "
+              f"{block['collectives_ms']:.3f} ms; device busy by chip, ms: "
+              + ", ".join(f"{ms:.3f}" for ms in block["chip_busy_ms"]),
+              file=out)
         early = {s: ms for s, ms in block["before_block_ms"].items() if ms}
         print("before the block's first operation: " + (", ".join(
             f"`{s}` {ms:.3f} ms" for s, ms in early.items())

@@ -181,6 +181,41 @@ class CoordinateDescent:
         telemetry.gauge(scopes.GAUGE_RE_KERNEL_ENTITIES).set(on_kernel)
         telemetry.gauge(scopes.GAUGE_RE_FALLBACK_ENTITIES).set(
             sum(b["entities"] for b in buckets) - on_kernel)
+        mesh = self._mesh()
+        if mesh is None:
+            return
+        # What each device of the mesh holds, read off the arrays' own
+        # shards (padding rows and padding entities included).
+        rows: Dict[object, int] = {}
+        slots: Dict[object, int] = {}
+        for c in self.coordinates.values():
+            batch = getattr(c, "_batch", None)
+            if batch is not None:
+                for shard in batch.labels.addressable_shards:
+                    rows[shard.device] = (rows.get(shard.device, 0)
+                                          + shard.data.shape[0])
+            for block in getattr(getattr(c, "dataset", None), "blocks", ()):
+                for shard in block.row_ids.addressable_shards:
+                    slots[shard.device] = (slots.get(shard.device, 0)
+                                           + shard.data.size)
+        telemetry.gauge(scopes.GAUGE_MESH_DEVICES).set(mesh.devices.size)
+        if rows:
+            telemetry.gauge(scopes.GAUGE_MESH_ROWS_PER_DEVICE).set(
+                max(rows.values()))
+        if slots:
+            telemetry.gauge(scopes.GAUGE_RE_SLOTS_PER_DEVICE_MAX).set(
+                max(slots.values()))
+            telemetry.gauge(scopes.GAUGE_RE_SLOTS_PER_DEVICE_MEAN).set(
+                sum(slots.values()) / mesh.devices.size)
+
+    def _mesh(self):
+        """The device mesh the coordinates were built over (``mesh=``),
+        or None: every coordinate of one fit shares it."""
+        for c in self.coordinates.values():
+            mesh = getattr(c, "mesh", None)
+            if mesh is not None:
+                return mesh
+        return None
 
     def _fused_update_fns(self):
         """One jitted function per coordinate performing the ENTIRE update —
@@ -285,6 +320,12 @@ class CoordinateDescent:
             return params, scores, objs, trs
 
         fn = jax.jit(cd_block)
+        mesh = self._mesh()
+        if mesh is not None:
+            # the compile ledger's row says for how many devices
+            from photon_ml_tpu.utils.compile_cache import note_partitions
+
+            note_partitions(scopes.CD_BLOCK, mesh.devices.size)
         self._block_fns[n_iters] = fn
         self.tracing_guard.track(f"block:{n_iters}", fn)
         return fn

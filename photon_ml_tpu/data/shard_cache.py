@@ -359,11 +359,11 @@ class StreamedFixedEffectData:
             raise KeyError(
                 f"streamed ingest assembled shard {self._shard_id!r}, "
                 f"coordinate asked for {shard_id!r}")
-        if dtype is not None and np.dtype(dtype) != np.dtype(
-                np.asarray(self._batch.labels).dtype):
+        # the array's own dtype: no fetch of the vector to the host
+        have = np.dtype(self._batch.labels.dtype)
+        if dtype is not None and np.dtype(dtype) != have:
             raise ValueError(
-                f"streamed batch was assembled as "
-                f"{np.asarray(self._batch.labels).dtype}, asked for {dtype}")
+                f"streamed batch was assembled as {have}, asked for {dtype}")
         if extra_offsets is None:
             return self._batch
         return GLMBatch(self._batch.features, self._batch.labels,

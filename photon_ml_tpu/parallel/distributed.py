@@ -161,36 +161,60 @@ def replicate(x, mesh: Mesh):
     return jax.device_put(x, NamedSharding(mesh, P()))
 
 
+def _lay_over(a, sharding: NamedSharding, fill) -> Array:
+    """``a`` padded along axis 0 to a multiple of the sharded axis'
+    extent and laid out under ``sharding``, without ever holding the whole
+    (or the whole padded) array on one device. The function reads the
+    array's own placement: one that already lies over the mesh with this
+    partitioning, with nothing to pad, is handed back as it is, the same
+    buffers; of any other (on the host, on one device, over other
+    devices) every device's shard is cut from the source, padded alone
+    and put where it belongs.
+    """
+    k = sharding.mesh.shape[sharding.spec[0]]
+    n = a.shape[0]
+    pad = (-n) % k
+    if (pad == 0 and isinstance(a, jax.Array)
+            and a.sharding.is_equivalent_to(sharding, a.ndim)):
+        return a
+    shape = (n + pad,) + tuple(a.shape[1:])
+
+    def shard_of(index):
+        lo, hi, _ = index[0].indices(shape[0])
+        piece = a[lo:min(hi, n)]
+        short = (hi - lo) - piece.shape[0]
+        if short:
+            lib = jnp if isinstance(piece, jax.Array) else np
+            piece = lib.pad(piece, [(0, short)] + [(0, 0)] * (a.ndim - 1),
+                            constant_values=fill)
+        return piece
+
+    return jax.make_array_from_callback(shape, sharding, shard_of)
+
+
 def shard_batch(batch: GLMBatch, mesh: Mesh, axis: str = DATA_AXIS
                 ) -> GLMBatch:
     """Shard a GLMBatch's row (or nnz) dimension over the mesh.
 
     Rows are padded to a multiple of the mesh size with weight-0 rows
     (inert in the objective). For CSR the nnz stream is padded with zero
-    values pointing at row/col 0.
+    values pointing at row/col 0. Each device's shard is padded and placed
+    alone, and a batch that already lies over the mesh row by row comes
+    back with the buffers it came with (``_lay_over``).
     """
-    k = mesh.shape[axis]
     row_sh = NamedSharding(mesh, P(axis))
 
-    labels = _pad_to_multiple(batch.labels, k, 0, 0.0)
-    offsets = _pad_to_multiple(batch.offsets, k, 0, 0.0)
-    weights = _pad_to_multiple(batch.weights, k, 0, 0.0)
-
+    labels = _lay_over(batch.labels, row_sh, 0.0)
     feats = batch.features
     if isinstance(feats, DenseFeatures):
-        x = _pad_to_multiple(feats.x, k, 0, 0.0)
-        new_feats = DenseFeatures(
-            jax.device_put(x, NamedSharding(mesh, P(axis, None))))
+        new_feats = DenseFeatures(_lay_over(
+            feats.x, NamedSharding(mesh, P(axis, None)), 0.0))
     elif isinstance(feats, CSRFeatures):
-        values = _pad_to_multiple(feats.values, k, 0, 0.0)
-        col_ids = _pad_to_multiple(feats.col_ids, k, 0, 0)
-        row_ids = _pad_to_multiple(feats.row_ids, k, 0, 0)
-        n_rows_padded = int(labels.shape[0])
         new_feats = CSRFeatures(
-            values=jax.device_put(values, row_sh),
-            col_ids=jax.device_put(col_ids, row_sh),
-            row_ids=jax.device_put(row_ids, row_sh),
-            n_rows=n_rows_padded,
+            values=_lay_over(feats.values, row_sh, 0.0),
+            col_ids=_lay_over(feats.col_ids, row_sh, 0),
+            row_ids=_lay_over(feats.row_ids, row_sh, 0),
+            n_rows=int(labels.shape[0]),
             n_features=feats.n_features,
         )
     else:
@@ -198,9 +222,9 @@ def shard_batch(batch: GLMBatch, mesh: Mesh, axis: str = DATA_AXIS
 
     return GLMBatch(
         features=new_feats,
-        labels=jax.device_put(labels, row_sh),
-        offsets=jax.device_put(offsets, row_sh),
-        weights=jax.device_put(weights, row_sh),
+        labels=labels,
+        offsets=_lay_over(batch.offsets, row_sh, 0.0),
+        weights=_lay_over(batch.weights, row_sh, 0.0),
     )
 
 
@@ -356,20 +380,16 @@ def shard_block(block: EntityBlock, mesh: Mesh, sentinel_row: int,
     Entities are padded to a multiple of the mesh size with all-padding
     entities (weight 0 everywhere, row_ids == sentinel, feat_idx == -1);
     their solves converge instantly and their scatter contributions land in
-    the sentinel slot.
+    the sentinel slot. Shard by shard, as ``shard_batch`` does it: a block
+    already laid over the mesh by entity comes back with its own buffers.
     """
-    k = mesh.shape[axis]
     sh2 = NamedSharding(mesh, P(axis, None))
     sh3 = NamedSharding(mesh, P(axis, None, None))
     return EntityBlock(
-        x=jax.device_put(_pad_to_multiple(block.x, k, 0, 0.0), sh3),
-        labels=jax.device_put(_pad_to_multiple(block.labels, k, 0, 0.0), sh2),
-        offsets=jax.device_put(
-            _pad_to_multiple(block.offsets, k, 0, 0.0), sh2),
-        weights=jax.device_put(
-            _pad_to_multiple(block.weights, k, 0, 0.0), sh2),
-        row_ids=jax.device_put(
-            _pad_to_multiple(block.row_ids, k, 0, sentinel_row), sh2),
-        feat_idx=jax.device_put(
-            _pad_to_multiple(block.feat_idx, k, 0, -1), sh2),
+        x=_lay_over(block.x, sh3, 0.0),
+        labels=_lay_over(block.labels, sh2, 0.0),
+        offsets=_lay_over(block.offsets, sh2, 0.0),
+        weights=_lay_over(block.weights, sh2, 0.0),
+        row_ids=_lay_over(block.row_ids, sh2, sentinel_row),
+        feat_idx=_lay_over(block.feat_idx, sh2, -1),
     )

@@ -118,6 +118,9 @@ GAUGE_MERGE_POLICIES: Dict[str, str] = {
     # (algorithm/coordinate_descent.py): slots, true rows and entities by
     # solve path are per-process holdings, so the fleet has the sum.
     "training.re.": "sum",
+    # ... but what ONE device of a process's mesh holds is no holding to
+    # add up: the fleet keeps the newest writer, as for training.mesh.*.
+    "training.re.slots_per_device.": "last",
     # Network front door (serving/netserver.py): connections held open
     # are per-process holdings — the fleet has the sum. (Everything
     # else under serving.net.* is a counter; lint rule counter-family.)

@@ -81,6 +81,16 @@ GAUGE_RE_ROWS = "training.re.rows"
 GAUGE_RE_KERNEL_ENTITIES = "training.re.kernel_entities"
 GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"
 
+# -- gauges of a fit whose coordinates lie over a device mesh (mesh=) ----------
+GAUGE_MESH_DEVICES = "training.mesh.devices"
+#: Rows of the fixed effect's batch on the fullest device (padding included).
+GAUGE_MESH_ROWS_PER_DEVICE = "training.mesh.rows_per_device"
+#: Random-effect slots (entities x padded rows, every size class, the empty
+#: entities that fill a class to a multiple of the mesh included) on the
+#: fullest device, and the mean over devices.
+GAUGE_RE_SLOTS_PER_DEVICE_MAX = "training.re.slots_per_device.max"
+GAUGE_RE_SLOTS_PER_DEVICE_MEAN = "training.re.slots_per_device.mean"
+
 # -- counters of CoordinateDescent.run (inc is a no-op while telemetry is off) -
 COUNTER_CD_RUNS = "training.cd.runs"
 #: Runs that started cold (no ``initial_model``, no checkpoint restored):

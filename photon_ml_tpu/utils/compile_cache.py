@@ -52,6 +52,9 @@ _totals = _new_totals()
 # reports them without a function name, inside that function's
 # backend-compile span, which ends (and names the function) after them.
 _unclaimed = {"retrieval_s": 0.0, "cache_hits": 0}
+# Devices a function's program is partitioned over, where its owner said so
+# (``note_partitions``): JAX's events carry a function's name and no more.
+_partitions: Dict[str, int] = {}
 
 
 def _function_name(fun_name) -> str:
@@ -96,6 +99,14 @@ def _on_event(event: str, **_kw) -> None:
             _unclaimed["cache_hits"] += 1
 
 
+def note_partitions(fun_name: str, partitions: int) -> None:
+    """The owner of a jitted function whose arguments lie over a device
+    mesh says over how many devices: the ledger's row of that name reads
+    ``partitions`` (1 for every function nobody spoke for)."""
+    with _lock:
+        _partitions[str(fun_name)] = int(partitions)
+
+
 def compile_ledger(top: Optional[int] = None) -> dict:
     """``{"functions": {name: row}, "totals": {...}}`` since the process
     began listening (``enable_compile_cache``), or since ``reset``; with
@@ -105,11 +116,14 @@ def compile_ledger(top: Optional[int] = None) -> dict:
     is in both rows), ``lower_s`` (jaxpr to MLIR module), ``backend_s``
     (XLA compile, or the persistent cache's lookup and executable load
     where it hit: ``retrieval_s`` and ``cache_hits`` are that part), and
-    how often each happened (``traces``, ``lowerings``, ``compiles``).
+    how often each happened (``traces``, ``lowerings``, ``compiles``),
+    and ``partitions``, the devices the program was lowered for
+    (``note_partitions``; 1 where nobody said).
     Names are the jitted functions' (``cd_block``), as JAX reports them.
     Totals add ``cache_requests``; requests minus hits were compiled."""
     with _lock:
-        rows = {k: dict(v) for k, v in _functions.items()}
+        rows = {k: dict(v, partitions=_partitions.get(k, 1))
+                for k, v in _functions.items()}
         totals = dict(_totals)
     if top is not None:
         cost = lambda r: r["trace_s"] + r["lower_s"] + r["backend_s"]
@@ -121,6 +135,7 @@ def compile_ledger(top: Optional[int] = None) -> dict:
 def reset_compile_ledger() -> None:
     with _lock:
         _functions.clear()
+        _partitions.clear()
         _totals.update(_new_totals())
         _unclaimed.update(retrieval_s=0.0, cache_hits=0)
 

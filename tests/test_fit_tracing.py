@@ -421,6 +421,60 @@ def test_reduce_scopes_by_hand():
     assert result["mean"]["scope_ms"] == job["scope_ms"]
 
 
+def test_reduce_scopes_over_several_chips_and_their_collectives():
+    """A second chip's plane, with collectives inside the scopes: every
+    number is the mean over the chips, a scope's collective time is the
+    union of its collective operations (``-start`` and ``-done`` both),
+    and the busy time is given chip by chip."""
+    us = 1000
+    trace = _hand_trace()
+    one = trace["planes"][0]
+    fe = f"{_BLOCK}/photon.cd.fixed/jit(_solve_fixed)/photon.fe.solve"
+    sc = f"{_BLOCK}/photon.cd.perUser/jit(_re_score_impl)/photon.re.scatter"
+    two_ops = [
+        ["%while.1", 10 * us, 30 * us, fe + "/while"],
+        ["%all-reduce.3 = f32[200] all-reduce(...)", 12 * us, 4 * us,
+         fe + "/while/body/dot_general"],
+        ["%all-reduce.3 = f32[200] all-reduce(...)", 14 * us, 4 * us,
+         fe + "/while/body/dot_general"],       # overlaps: a union
+        ["%all-gather-start.5", 40 * us, 1 * us, sc + "/scatter-add"],
+        ["%all-gather-done.5", 44 * us, 2 * us, sc + "/scatter-add"],
+        ["%fusion.7", 46 * us, 4 * us, sc + "/scatter-add"],
+        ["%collective-permute.2", 60 * us, 5 * us, ""],
+        ["%gather_all-reduce.fusion", 70 * us, 5 * us, ""],  # no collective
+    ]
+    trace["planes"].insert(1, {"name": "/device:TPU:1", "lines": [
+        {"name": "XLA Ops", "events": two_ops}]})
+    result = trace_scopes.reduce_scopes(trace, gap_ms=0.005)
+    (job,) = result["jobs"]
+    ms = lambda us: pytest.approx(us / 1000)
+    alone = trace_scopes.reduce_scopes(
+        {"planes": [one, trace["planes"][2]]}, gap_ms=0.005)["jobs"][0]
+    assert alone["collective_ms"] == {} and alone["collectives_ms"] == 0
+    assert alone["chip_busy_ms"] == [ms(80)]
+    # chip two: fe.solve 10-40, scatter 40-41 + 44-50, 10 more unscoped
+    assert job["chip_busy_ms"] == [ms(80), ms(47)]
+    assert job["busy_ms"] == ms((80 + 47) / 2)
+    assert job["scope_ms"][scopes.FE_SOLVE] == ms((20 + 30) / 2)
+    assert job["scope_ms"][scopes.RE_SCATTER] == ms((5 + 7) / 2)
+    assert job["collective_ms"] == {
+        scopes.FE_SOLVE: ms(6 / 2), scopes.RE_SCATTER: ms(3 / 2),
+        trace_scopes.NO_SCOPE: ms(5 / 2)}
+    assert job["collectives_ms"] == ms(14 / 2)
+    assert result["mean"]["chip_busy_ms"] == job["chip_busy_ms"]
+    # the gaps listed are the first chip's
+    assert [g["phase"] for g in job["idle_gaps"]] == [
+        g["phase"] for g in alone["idle_gaps"]]
+    import io
+
+    buf = io.StringIO()
+    trace_scopes.print_report(result, out=buf)
+    out = buf.getvalue()
+    assert "of it collectives, ms" in out
+    assert f"| `{scopes.FE_SOLVE}` | 25.000" not in out  # ms, not us
+    assert "device busy by chip, ms: 0.080, 0.047" in out
+
+
 def test_pack_round_trips_and_cut_keeps_one_job():
     trace = _hand_trace()
     host = trace["planes"][1]["lines"][0]["events"]
