@@ -24,7 +24,8 @@ drops), and prints per job:
   span that covers it (``bench.*`` where none does);
 - of every scope's time, the part that is collectives (operations named
   ``all-reduce*``, ``all-gather*``, ``reduce-scatter*``, ``all-to-all*``,
-  ``collective-permute*``), and the device-busy time chip by chip. Over a
+  ``collective-permute*``, or with such an opcode under a name JAX gave:
+  ``is_collective``), and the device-busy time chip by chip. Over a
   trace of several chips every number is the mean over the chips' planes;
   the gaps and the unscoped operations listed are the first chip's.
 
@@ -80,12 +81,27 @@ from benchmark.trace_reduce import (  # noqa: E402
 from photon_ml_tpu.telemetry import scopes  # noqa: E402
 
 HOST_SPAN_PREFIXES = (scopes.PREFIX, "bench.")
-# Operations that are collectives, by the start of their HLO name (``%``
-# stripped). What the v5e prints (looked at by hand, PR 31, JAX 0.9.0):
-# see ``PERF.md`` section 5. An asynchronous one is two events,
-# ``<name>-start`` and ``<name>-done``: both carry the prefix, both count.
+# Operations that are collectives. One the partitioner (or a compiler pass)
+# made is named after its opcode (``%all-reduce.12``: what the v5e prints,
+# looked at by hand, PR 31, JAX 0.9.0, ``PERF.md`` section 5); one the
+# program wrote is named after JAX's primitive and shows its opcode only
+# behind the `` = `` (``%psum_invariant.16 = f32[20000265]{...}
+# all-reduce(...)``: the divided exchange's two, PR 32). Either counts. An
+# asynchronous one is two events, ``<name>-start`` and ``<name>-done``:
+# both carry the prefix, both count.
 COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
                        "all-to-all", "collective-permute")
+_COLLECTIVE_OPCODE = re.compile(
+    r" = (?:\([^=]*?\)|\S+) (?:%s)(?:-start|-done)?\("
+    % "|".join(COLLECTIVE_PREFIXES))
+
+
+def is_collective(name: str) -> bool:
+    """Whether a device event (its full HLO text) is a collective."""
+    return (short_name(name).lstrip("%").startswith(COLLECTIVE_PREFIXES)
+            or _COLLECTIVE_OPCODE.search(name) is not None)
+
+
 NO_SCOPE = "(no scope)"
 # The stat that carries the HLO metadata's op_name (``tf_op`` on the v5e,
 # PERF.md §5); any other stat whose value holds a ``photon.`` scope is
@@ -377,7 +393,7 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
         iv = (s, s + d)
         everything.append(iv)
         where = place(path)
-        if short_name(name).lstrip("%").startswith(COLLECTIVE_PREFIXES):
+        if is_collective(name):
             collectives.setdefault(where["leaf"] or NO_SCOPE, []).append(iv)
         if not where["scoped"]:
             n = short_name(name)

@@ -54,6 +54,9 @@ Array = jax.Array
 # telemetry is off).
 _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
+_M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
+_M_EXCHANGE_REPLICATED = telemetry.counter(
+    scopes.COUNTER_RE_EXCHANGE_REPLICATED)
 
 
 def _unstack_tracker_block(trs: Dict[str, object], names: Sequence[str],
@@ -686,6 +689,15 @@ class CoordinateDescent:
                 # shapes/dtypes/statics drifted call-to-call and every "one
                 # dispatch" above silently paid a recompile.
                 self.tracing_guard.assert_max_retraces(per_fn=1)
+                # How this run's mesh exchanges were placed: every
+                # coordinate with blocks over a mesh was traced by now.
+                for c in self.coordinates.values():
+                    if (getattr(c, "mesh", None) is None
+                            or not hasattr(c, "dataset")):
+                        continue
+                    (_M_EXCHANGE_DIVIDED
+                     if getattr(c, "exchange_divided", False)
+                     else _M_EXCHANGE_REPLICATED).inc()
                 if logger.isEnabledFor(logging.INFO) and objective_history:
                     logger.info("objective history: %s",
                                 ["%.6f" % v for v in objective_history])

@@ -76,6 +76,11 @@ class Coordinate:
     """
 
     name: str
+    #: True once ``pure_score`` has been traced handing a mesh to the score
+    #: exchange (each device gathers and scatters its own slots); False
+    #: before, without a mesh, and for a coordinate that has no exchange.
+    #: ``CoordinateDescent.run`` counts it.
+    exchange_divided: bool = False
 
     @property
     def zero_start(self) -> bool:
@@ -574,7 +579,18 @@ class RandomEffectCoordinate(Coordinate):
     as device data, and fold into the fused Pallas kernel (or the
     vmapped fallback) — no silent perf cliff for normalized/bounded
     configs. Models stay in the ORIGINAL space; solves happen in the
-    normalized space with per-entity transforms on the way in/out."""
+    normalized space with per-entity transforms on the way in/out.
+
+    ``mesh`` shards every block's entity axis over the mesh's ``data``
+    axis, and the coordinate then runs per device over its own entities:
+    the solves (the kernel under ``shard_map``, the vmapped solver
+    partitioned by entity) and the score exchange with the row-sharded
+    n-vectors. The residual is made whole once per update
+    (``_whole_residual``) and each device gathers its own slots; each
+    device scatters its own margins into a private vector and one
+    all-reduce per scoring hands every device its row range
+    (``_scatter_over_mesh``). Nothing of a block's ``[E, r]`` shape crosses
+    devices, and the scores are bitwise the one-device ones."""
 
     name: str
     dataset: RandomEffectDataset
@@ -659,7 +675,8 @@ class RandomEffectCoordinate(Coordinate):
         dispatches per call)."""
         return _re_score_impl(
             tuple(self.dataset.blocks), tuple(self.dataset.passive_blocks),
-            tuple(model.local_coefs), n_rows=self.dataset.n_rows)
+            tuple(model.local_coefs), n_rows=self.dataset.n_rows,
+            mesh=self.mesh)
 
     def penalties(self, model: RandomEffectModel):
         return self.pure_penalties(tuple(model.local_coefs),
@@ -690,6 +707,8 @@ class RandomEffectCoordinate(Coordinate):
         )
 
         blocks, _, norm_blocks, bounds_blocks = data
+        if self.mesh is not None:
+            residual = _whole_residual(residual, self.mesh)
         new_coefs, results = [], []
         for block, c0, norm, bounds in zip(blocks, params, norm_blocks,
                                            bounds_blocks):
@@ -710,8 +729,10 @@ class RandomEffectCoordinate(Coordinate):
 
     def pure_score(self, data, params) -> Array:
         blocks, pblocks = data[0], data[1]
+        if self.mesh is not None:
+            self.exchange_divided = True
         return _re_score_impl(blocks, pblocks, tuple(params),
-                              n_rows=self.dataset.n_rows)
+                              n_rows=self.dataset.n_rows, mesh=self.mesh)
 
     def penalty_data(self):
         return self._norm_blocks
@@ -1131,7 +1152,10 @@ class FactoredRandomEffectCoordinate(Coordinate):
         blocks, _ = data
         gammas, B = list(params[0]), params[1]
         d = self.dataset.num_global_features
-        residuals = [_gather_residual(residual, b) for b in blocks]
+        if self.mesh is not None:
+            residual = _whole_residual(residual, self.mesh)
+        residuals = [_gather_residual(residual, b, self.mesh)
+                     for b in blocks]
         # Row-major view of x/labels/offsets/weights is iteration-invariant;
         # only the per-row gammas change across alternations.
         x_flat, y_flat, off_flat, w_flat = _flatten_factored_static(
@@ -1155,9 +1179,12 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def pure_score(self, data, params) -> Array:
         blocks, pblocks = data
         gammas, B = params
+        if self.mesh is not None:
+            self.exchange_divided = True
         return _fre_score_impl(
             blocks, pblocks, tuple(gammas), B,
-            n_rows=self.dataset.n_rows, d=self.dataset.num_global_features)
+            n_rows=self.dataset.n_rows, d=self.dataset.num_global_features,
+            mesh=self.mesh)
 
     def pure_penalties(self, params, pdata=None):
         gammas, B = params
@@ -1243,15 +1270,63 @@ def _solve_latent_matrix(
 
 @jax.named_scope(scopes.RE_GATHER)
 def _gather_residual(residual_scores: Optional[Array],
-                     block: EntityBlock) -> Optional[Array]:
+                     block: EntityBlock, mesh=None) -> Optional[Array]:
     """Per-row residual for a block: a zero sentinel slot is appended so
-    padding rows (row_ids == n_rows) gather 0."""
+    padding rows (row_ids == n_rows) gather 0.
+
+    With a mesh ``residual_scores`` is what ``_whole_residual`` made (every
+    device holds all of it, sentinel slot included) and each device reads
+    the slots of its own shard of the block's entities: the result is
+    entity-sharded like the block, and no ``[E, r]`` array crosses devices."""
     if residual_scores is None:
         return None
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        slots = P("data", None)
+        return jax.shard_map(
+            lambda ext, row_ids: ext[row_ids], mesh=mesh,
+            in_specs=(P(), slots), out_specs=slots,
+        )(residual_scores, block.row_ids)
     ext = jnp.concatenate(
         [residual_scores,
          jnp.zeros((1,), residual_scores.dtype)])
     return ext[block.row_ids]
+
+
+@jax.named_scope(scopes.RE_GATHER)
+def _whole_residual(residual_scores: Optional[Array], mesh
+                    ) -> Optional[Array]:
+    """What a mesh fit's blocks gather from: the row-sharded residual made
+    whole on every device, ONE collective on the n-vector per coordinate
+    update whatever the number of size classes, with the rows filled up to
+    a multiple of the mesh's ``data`` size and the zero sentinel slot
+    behind them. An all-gather, spelt as the v5e's compiler lowers one of
+    this size (every device writes its rows into a zero vector, an
+    all-reduce sums them), because that lowering drops the scope's name.
+    Spelt out at all, and not a sharding constraint, because a constraint
+    leaves the partitioner free to make the residual's producer whole
+    instead: it gathered X for it."""
+    if residual_scores is None:
+        return None
+    from jax.sharding import PartitionSpec as P
+
+    k = mesh.shape["data"]
+    n_rows = residual_scores.shape[0]
+    own_rows = -(-n_rows // k)
+    if own_rows * k != n_rows:
+        residual_scores = jnp.pad(residual_scores,
+                                  (0, own_rows * k - n_rows))
+
+    def all_rows(own):
+        whole = jax.lax.dynamic_update_slice(
+            jnp.zeros((own_rows * k + 1,), own.dtype), own,
+            (jax.lax.axis_index("data") * own_rows,))
+        return jax.lax.psum(whole, "data")
+
+    return jax.shard_map(
+        all_rows, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    )(residual_scores)
 
 
 @contextlib.contextmanager
@@ -1467,7 +1542,7 @@ def _solve_block(
     instantly). Remaining fallbacks (oversize VMEM, CPU) use the
     portable vmapped solver."""
     offsets = block.offsets
-    extra = _gather_residual(residual_scores, block)
+    extra = _gather_residual(residual_scores, block, mesh)
     if extra is not None:
         with jax.named_scope(scopes.RE_GATHER):
             offsets = offsets + extra.astype(offsets.dtype)
@@ -1553,9 +1628,67 @@ def _fe_score_impl(coef, feats, n_rows: int):
 
 
 @jax.named_scope(scopes.RE_SCATTER)
-def _scatter_margins(scores, block, margins, n_rows):
-    m = jnp.where(block.row_ids < n_rows, margins, 0.0)
-    return scores.at[block.row_ids.reshape(-1)].add(m.reshape(-1))
+def _scatter_margins(scores, row_ids, margins, n_rows):
+    """One block's margins added into the sentinel-extended score vector:
+    padding slots (row_ids == n_rows) add 0 to the sentinel slot. Under a
+    mesh the vector is a device's private one and the block that device's
+    shard of it (``_scatter_over_mesh``)."""
+    m = jnp.where(row_ids < n_rows, margins, 0.0)
+    return scores.at[row_ids.reshape(-1)].add(m.reshape(-1))
+
+
+def _scatter_in_turn(slots, length: int, n_rows: int, dtype):
+    """Every block's ``(row_ids, margins)`` added in turn into one zero
+    vector of ``length`` > n_rows entries (the sentinel slot among them)."""
+    scores = jnp.zeros((length,), dtype)
+    for row_ids, margins in slots:
+        scores = _scatter_margins(scores, row_ids, margins, n_rows)
+    return scores
+
+
+@jax.named_scope(scopes.RE_SCATTER)
+def _scatter_over_mesh(mesh, slots, n_rows: int, dtype):
+    """A mesh fit's scatter, over every block's entity-sharded ``(row_ids,
+    margins)``. Each device adds the margins of its OWN entities, all
+    blocks in turn, into one private zero vector as long as the row range
+    (filled up to a multiple of the mesh's ``data`` size, plus the sentinel
+    slot); ONE all-reduce of that n-vector per scoring sums them and each
+    device keeps its row range (a reduce-scatter, spelt as the v5e's
+    compiler lowers one, which drops the scope's name on the way): the
+    scores come back row-sharded like the fixed effect's batch, and no
+    ``[E, r]`` array crosses devices. A real row sits in one slot of one
+    block, so every entry is ``0 + m`` on one device and 0 on the others:
+    the sum is bitwise the one-device scatter's."""
+    from jax.sharding import PartitionSpec as P
+
+    k = mesh.shape["data"]
+    own_rows = -(-n_rows // k)
+
+    def own_slots(slots_l):
+        scores = jax.lax.psum(
+            _scatter_in_turn(slots_l, own_rows * k + 1, n_rows, dtype),
+            "data")
+        return jax.lax.dynamic_slice(
+            scores, (jax.lax.axis_index("data") * own_rows,), (own_rows,))
+
+    per_entity = P("data", None)
+    scores = jax.shard_map(
+        own_slots, mesh=mesh,
+        in_specs=([(per_entity, per_entity)] * len(slots),),
+        out_specs=P("data"),
+    )(slots)
+    return scores if own_rows * k == n_rows else scores[:n_rows]
+
+
+def _scatter_slots(slots, n_rows: int, dtype, mesh):
+    """Scores in row order, ``f[n_rows]``, from ``slots``: an ITERATOR over
+    every block's ``(row_ids, margins)``, so that on one device a block's
+    margins are made when its turn to be scattered comes. With a mesh
+    (static: the coordinate's, whose blocks are entity-sharded over it)
+    the scatter is ``_scatter_over_mesh``'s."""
+    if mesh is not None:
+        return _scatter_over_mesh(mesh, list(slots), n_rows, dtype)
+    return _scatter_in_turn(slots, n_rows + 1, n_rows, dtype)[:-1]
 
 
 @jax.named_scope(scopes.RE_MARGINS)
@@ -1563,22 +1696,28 @@ def _local_margins(block, coefs):
     return block.local_margins(coefs)
 
 
-@functools.partial(jax.jit, static_argnames=("n_rows",))
-def _re_score_impl(blocks, pblocks, coefs, n_rows: int):
-    scores = jnp.zeros((n_rows + 1,),
-                       coefs[0].dtype if coefs else jnp.float32)
-    for block, c in zip(blocks, coefs):
-        scores = _scatter_margins(scores, block, _local_margins(block, c),
-                                  n_rows)
-    for block, c in zip(pblocks, coefs):
-        if block is not None:
-            scores = _scatter_margins(scores, block, _local_margins(block, c),
-                                      n_rows)
-    return scores[:-1]
+def _scored_blocks(blocks, pblocks, params):
+    """(block, its entities' parameters) in scoring order: the active
+    blocks, then the passive blocks there are."""
+    return list(zip(blocks, params)) + [
+        (b, p) for b, p in zip(pblocks, params) if b is not None]
 
 
-@functools.partial(jax.jit, static_argnames=("n_rows", "d"))
-def _fre_score_impl(blocks, pblocks, gammas, B, n_rows: int, d: int):
+@functools.partial(jax.jit, static_argnames=("n_rows", "mesh"))
+def _re_score_impl(blocks, pblocks, coefs, n_rows: int, mesh=None):
+    """A random effect's scores: every block's margins scattered back into
+    row order (``_scatter_slots`` says what a mesh does to it)."""
+    return _scatter_slots(
+        ((block.row_ids, _local_margins(block, c))
+         for block, c in _scored_blocks(blocks, pblocks, coefs)),
+        n_rows, coefs[0].dtype if coefs else jnp.float32, mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "d", "mesh"))
+def _fre_score_impl(blocks, pblocks, gammas, B, n_rows: int, d: int,
+                    mesh=None):
+    """``_re_score_impl`` where entity e's coefficients are
+    ``gamma_e @ B``."""
     def block_margins(block, gamma):
         coefs = gamma @ B  # [E, d]
         pad = block.d_pad - d
@@ -1586,12 +1725,7 @@ def _fre_score_impl(blocks, pblocks, gammas, B, n_rows: int, d: int):
             coefs = jnp.pad(coefs, ((0, 0), (0, pad)))
         return block.local_margins(coefs)
 
-    scores = jnp.zeros((n_rows + 1,), B.dtype)
-    for block, g in zip(blocks, gammas):
-        scores = _scatter_margins(scores, block, block_margins(block, g),
-                                  n_rows)
-    for block, g in zip(pblocks, gammas):
-        if block is not None:
-            scores = _scatter_margins(scores, block, block_margins(block, g),
-                                      n_rows)
-    return scores[:-1]
+    return _scatter_slots(
+        ((block.row_ids, block_margins(block, g))
+         for block, g in _scored_blocks(blocks, pblocks, gammas)),
+        n_rows, B.dtype, mesh)

@@ -15,7 +15,10 @@ primitives, expressed as XLA collectives over ICI:
   their leading entity axis; the vmapped solver is elementwise over entities,
   so XLA partitions it with zero communication — the analog of the
   co-partitioned mapValues solve (RandomEffectCoordinate.scala:104-113).
-  Score scatter-adds reduce over the mesh automatically.
+  The score exchange between rows and entity slots is divided by the
+  program (``algorithm/coordinates.py``: each device gathers and scatters
+  its own slots, one n-vector all-reduce each way); left to the partitioner
+  it is replicated on every device.
 
 Everything uses plain ``jax.sharding.NamedSharding`` + jit: XLA's SPMD
 partitioner inserts psum/all-gather where the math requires, which is the

@@ -17,10 +17,14 @@ PREFIX = "photon."
 # -- device scopes (jax.named_scope inside traced code) -----------------------
 FE_SOLVE = "photon.fe.solve"      # body of _solve_fixed
 FE_SCORE = "photon.fe.score"      # _fe_score_impl
-RE_GATHER = "photon.re.gather"    # residual gather into the blocks' slots
+# residual gather into the blocks' slots; under a mesh each device gathers
+# its own, after ONE all-reduce that makes the residual whole (under the scope)
+RE_GATHER = "photon.re.gather"
 RE_SOLVE = "photon.re.solve"      # kernel or vmapped solve, one child a class
 RE_MARGINS = "photon.re.margins"  # block.local_margins
-RE_SCATTER = "photon.re.scatter"  # margins scattered back into row order
+# margins scattered back into row order; under a mesh each device scatters
+# its own, then ONE all-reduce sums the private vectors (under the scope)
+RE_SCATTER = "photon.re.scatter"
 CD_OBJECTIVE = "photon.cd.objective"  # the loss sum and the penalties
 
 #: The leaf scopes: an operation counts under the innermost of these on
@@ -96,3 +100,12 @@ COUNTER_CD_RUNS = "training.cd.runs"
 #: Runs that started cold (no ``initial_model``, no checkpoint restored):
 #: the runs whose initial scores were built and not computed.
 COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
+#: Per run, the random-effect coordinates built over a mesh whose score
+#: exchange was traced DIVIDED over it (each device gathers and scatters the
+#: slots of its own entities, one n-vector collective each way, under
+#: ``photon.re.gather`` / ``photon.re.scatter``), and those whose exchange
+#: was left to the partitioner, which replicates the index work on every
+#: device. divided / (divided + replicated) is the share of mesh exchanges
+#: that are divided; both stay 0 without a mesh.
+COUNTER_RE_EXCHANGE_DIVIDED = "training.re.exchange.divided"
+COUNTER_RE_EXCHANGE_REPLICATED = "training.re.exchange.replicated"

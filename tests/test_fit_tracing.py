@@ -347,6 +347,29 @@ def test_place_of_an_operation(path, leaf, coordinate, size_class):
     assert where["scoped"] == (leaf is not None or coordinate is not None)
 
 
+@pytest.mark.parametrize("event, want", [
+    ("%all-reduce.12 = f32[200]{0:T(256)} all-reduce(f32[200]{0} %dot.3), "
+     "channel_id=3", True),
+    ("%all-gather-start.2", True),
+    ("%collective-permute-done.7 = f32[1]{0} collective-permute-done(...)",
+     True),
+    # written by the program: named after JAX's primitive (PR 32)
+    ("%psum_invariant.16 = f32[20000265]{0:T(1024)S(1)} "
+     "all-reduce(f32[20000265]{0:T(1024)} %dynamic_update_slice.4), "
+     "channel_id=1", True),
+    ("%all-reduce.147 = (f32[401]{0}, f32[], f32[]) all-reduce("
+     "f32[401]{0} %wrapped_scatter.5, f32[] %a, f32[] %b)", True),
+    ("%reduce_scatter.3 = f32[100]{0} reduce-scatter(f32[400]{0} %x)", True),
+    ("%fusion.21 = f32[5983,32]{1,0} fusion(f32[20000265]{0} "
+     "%psum_invariant.16), kind=kLoop", False),
+    ("%get-tuple-element.9 = f32[401]{0} get-tuple-element((f32[401]{0}, "
+     "f32[]) %all-reduce.147), index=0", False),
+    ("%multiply_reduce_fusion.318", False),
+])
+def test_a_collective_is_known_by_name_or_by_opcode(event, want):
+    assert trace_scopes.is_collective(event) is want
+
+
 def _hand_trace():
     """One job of 100 us by hand. The scan's ``while`` (no scope) holds
     everything from 10 to 90; scoped operations cover 10-30 (fixed effect,
