@@ -38,7 +38,7 @@ WORK = ROOT / ".chip_smoke"  # gitignored; removed again on success
 
 ROWS = 200_000
 SERVE_ROWS = 400
-D_FIXED = 200  # intercept included, as in bench.py's headline
+D_FIXED = 200  # intercept included; 200 and 25: the repo's headline widths, set by hand
 N_USERS = 5_000
 D_USER = 25  # intercept included
 TRAIN_PARTS = 8
@@ -66,8 +66,8 @@ def _emit(**line) -> None:
 
 
 def _truth(seed: int):
-    """The known coefficient set labels are drawn from (bench.py
-    build_problem's recipe): fixed w ~ N(0, 0.5), per-user w ~ N(0, 0.3);
+    """The known coefficient set labels are drawn from, widths and scales
+    set by hand: fixed w ~ N(0, 0.5), per-user w ~ N(0, 0.3);
     the last fixed column and the last per-user column are intercepts."""
     rng = np.random.default_rng([seed, 0])
     return (rng.normal(0, 0.5, D_FIXED),
@@ -196,8 +196,8 @@ def _capture_cd_blocks():
     calls = []
     original = CoordinateDescent._fused_block_fn
 
-    def spying(self, n_iters):
-        fn = original(self, n_iters)
+    def spying(self, *span):
+        fn = original(self, *span)
 
         def dispatch(*args):
             calls.append((fn, jax.tree.map(abstract, args)))

@@ -44,14 +44,13 @@ except ImportError:  # run as a script: dev_scripts/ itself is sys.path[0]
 # jaxlint's default scope: the package + tooling. tests/ is style-checked
 # (via --with-style) but exempt from jaxlint rules — tests legitimately
 # jit per call and host-sync eagerly.
-ANALYSIS_PATHS = ["photon_ml_tpu", "dev_scripts", "bench.py",
-                  "__graft_entry__.py"]
+ANALYSIS_PATHS = ["photon_ml_tpu", "dev_scripts", "__graft_entry__.py"]
 DEFAULT_BASELINE = REPO_ROOT / "dev_scripts" / "jaxlint_baseline.txt"
 
 
 def _resolve(paths, root: Path, strict: bool = False):
-    """Default paths that don't exist are skipped (not every tree has a
-    bench.py); EXPLICIT paths that don't exist are an error — a typo'd
+    """Default paths that don't exist are skipped (a test's tree holds
+    only the package); EXPLICIT paths that don't exist are an error — a typo'd
     path silently analyzing 0 files would pass the gate vacuously."""
     out = []
     for p in paths:

@@ -26,7 +26,7 @@ import sys
 from pathlib import Path
 
 MAX_LINE = 99
-DEFAULT_PATHS = ["photon_ml_tpu", "tests", "dev_scripts", "bench.py",
+DEFAULT_PATHS = ["photon_ml_tpu", "tests", "dev_scripts",
                  "__graft_entry__.py"]
 
 

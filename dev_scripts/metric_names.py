@@ -15,7 +15,7 @@ static, like jaxlint keeps the tracing invariants.
 
 Scope and mechanics:
 
-- AST walk of ``photon_ml_tpu/`` + ``bench.py`` (tests are EXEMPT: the
+- AST walk of ``photon_ml_tpu/`` (tests are EXEMPT: the
   exposition tests deliberately register schema-violating names to
   exercise escaping).
 - A call counts as a registration when it is ``<anything>.counter(...)``
@@ -79,7 +79,7 @@ import sys
 from pathlib import Path
 
 FACTORIES = ("counter", "gauge", "histogram")
-DEFAULT_PATHS = ["photon_ml_tpu", "bench.py"]
+DEFAULT_PATHS = ["photon_ml_tpu"]
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)*$")
 _FRAGMENT_BAD_RE = re.compile(r"[^a-z0-9_.]")

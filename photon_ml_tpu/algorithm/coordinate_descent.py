@@ -55,15 +55,16 @@ Array = jax.Array
 _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
 _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
-_M_EXCHANGE_REPLICATED = telemetry.counter(
-    scopes.COUNTER_RE_EXCHANGE_REPLICATED)
 
 
 def _unstack_tracker_block(trs: Dict[str, object], names: Sequence[str],
                            base: Dict[str, list]) -> None:
     """Append one block's host tracker pytrees (leading n_iters axis) into
     per-coordinate per-update lists — shared by eager (checkpoint-save) and
-    lazy materialization so both produce identical entry shapes."""
+    lazy materialization so both produce identical entry shapes. ``trs``
+    holds the coordinates the block's span covers; ``names`` gives their
+    order (a jitted function returns a dict's keys sorted)."""
+    names = [n for n in names if n in trs]
     n_iters = jax.tree.leaves(trs[names[0]])[0].shape[0]
     for i in range(n_iters):
         for n in names:
@@ -152,8 +153,9 @@ class CoordinateDescent:
         self.task_type = task_type
         self.validation_data = validation_data
         self.validation_evaluators = list(validation_evaluators)
-        self._fused_fns = None
-        self._block_fns: Dict[int, object] = {}
+        # cd_block programs: by iteration count for whole iterations, by
+        # (iterations, first, stop) for a span of fewer coordinates
+        self._block_fns: Dict[object, object] = {}
         self._val_scorer = None
         self._cold_cache = None
         # Shared retrace infrastructure (utils/tracing_guard.py): every
@@ -220,71 +222,41 @@ class CoordinateDescent:
                 return mesh
         return None
 
-    def _fused_update_fns(self):
-        """One jitted function per coordinate performing the ENTIRE update —
-        residual reduce, solve (all buckets), re-score, full objective — as a
-        single device dispatch. The eager sequence costs ~5-6 dispatches
-        per update; fused it costs one.
+    def _fused_block_fn(self, n_iters: int, first: int = 0,
+                        stop: Optional[int] = None):
+        """ONE jitted dispatch executing `n_iters` coordinate-descent
+        iterations via lax.scan, each over the coordinates `first` to
+        `stop` (exclusive) of the updating sequence: all of them by
+        default.
 
-        Data pytrees are passed as ARGUMENTS (not trace constants) so the
-        compiled executables reference buffers, and params of every
-        coordinate flow in so the objective's penalty terms evaluate
-        on-device with no model materialization."""
-        if self._fused_fns is not None:
-            return self._fused_fns
-        loss = loss_for_task(self.task_type)
-        names = list(self.coordinates)
+        Per-dispatch latency, not device time, dominates a run of one
+        dispatch per coordinate update. Scanning whole iterations on
+        device leaves one dispatch per sync point (validation/checkpoint/
+        run end). A span of fewer coordinates is the same program for a
+        resume that lands inside an iteration and for saves that fall
+        between iteration boundaries; `step0` is then the base step of the
+        iteration the span belongs to.
 
-        def make(n):
-            coord = self.coordinates[n]
+        Returns (params, scores, objs[n_iters, stop - first], trackers)
+        where trackers holds the span's coordinates and its leaves carry a
+        leading n_iters axis; everything stays on device until
+        `_materialize_pending` fetches it in a single transfer.
 
-            def cd_step(data, pdata_all, params_all, other_scores,
-                        base_key, step, rows):
-                with jax.named_scope(scopes.cd_coordinate(n)):
-                    residual = None
-                    for s in other_scores:
-                        residual = s if residual is None else residual + s
-                    key = jax.random.fold_in(base_key, step)
-                    new_p, tracker = coord.pure_update(
-                        data, params_all[n], residual, key)
-                    score = coord.pure_score(data, new_p)
-                    total = score if residual is None else residual + score
-                obj = _full_objective(loss, total, rows, [
-                    pen for m in names
-                    for pen in self.coordinates[m].pure_penalties(
-                        new_p if m == n else params_all[m], pdata_all[m])])
-                return new_p, score, obj, tracker
-
-            return jax.jit(cd_step)
-
-        self._fused_fns = {n: make(n) for n in names}
-        for n, fn in self._fused_fns.items():
-            self.tracing_guard.track(f"fused:{n}", fn)
-        return self._fused_fns
-
-    def _fused_block_fn(self, n_iters: int):
-        """ONE jitted dispatch executing `n_iters` FULL coordinate-descent
-        iterations (every coordinate, in sequence) via lax.scan.
-
-        At the bench shapes per-dispatch latency, not device time,
-        dominates the per-step path (one dispatch per coordinate update).
-        Scanning whole iterations on device leaves one dispatch per sync
-        point (validation/checkpoint/run end).
-
-        Returns (params, scores, objs[n_iters, n_coords], trackers) where
-        tracker leaves carry a leading n_iters axis; everything stays on
-        device until `_materialize` fetches it in a single transfer.
-
-        Semantics are identical to the per-step path: same residual
-        recompute, same fold_in(base_key, step) key per update, same full
-        objective (reference: CoordinateDescent.scala:150-212).
+        Every update recomputes its residual afresh, draws its key as
+        fold_in(base_key, step) and evaluates the full objective
+        (reference: CoordinateDescent.scala:150-212), so a resumed run
+        repeats the uninterrupted one.
         """
-        fn = self._block_fns.get(n_iters)
+        names = list(self.coordinates)
+        n_coords = len(names)
+        if stop is None:
+            stop = n_coords
+        whole = (first, stop) == (0, n_coords)
+        cache_key = n_iters if whole else (n_iters, first, stop)
+        fn = self._block_fns.get(cache_key)
         if fn is not None:
             return fn
         loss = loss_for_task(self.task_type)
-        names = list(self.coordinates)
-        n_coords = len(names)
 
         def cd_block(data_args, pdata_args, params, scores, base_key, step0,
                      rows):
@@ -292,7 +264,8 @@ class CoordinateDescent:
                 params, scores = carry
                 objs = []
                 trs = {}
-                for ci, n in enumerate(names):
+                for ci in range(first, stop):
+                    n = names[ci]
                     coord = self.coordinates[n]
                     step = (step0 + it_idx * np.uint32(n_coords)
                             + np.uint32(ci + 1))
@@ -329,8 +302,10 @@ class CoordinateDescent:
             from photon_ml_tpu.utils.compile_cache import note_partitions
 
             note_partitions(scopes.CD_BLOCK, mesh.devices.size)
-        self._block_fns[n_iters] = fn
-        self.tracing_guard.track(f"block:{n_iters}", fn)
+        self._block_fns[cache_key] = fn
+        self.tracing_guard.track(
+            f"block:{n_iters}" if whole
+            else f"block:{n_iters}:{first}-{stop}", fn)
         return fn
 
     def run(
@@ -381,7 +356,7 @@ class CoordinateDescent:
             def _save(step):
                 with phase(scopes.CD_CHECKPOINT):
                     _sync_models()
-                    _materialize_all()
+                    _materialize_pending()
                     ckpt.save_checkpoint(checkpoint_dir, ckpt.CheckpointState(
                         step=step, models=models,
                         objective_history=list(objective_history),
@@ -433,7 +408,7 @@ class CoordinateDescent:
                 # The fused path: params/scores dicts are the authoritative
                 # training state on device; model objects are materialized
                 # lazily (checkpoint, validation, return) so the hot loop is
-                # exactly ONE dispatch per coordinate update.
+                # exactly ONE dispatch per span of updates.
                 data_args = {n: self.coordinates[n].step_data() for n in names}
                 pdata_args = {n: self.coordinates[n].penalty_data()
                               for n in names}
@@ -455,7 +430,6 @@ class CoordinateDescent:
                     # tracing_guard's per_fn=1 invariant below).
                     params = {n: jax.tree.map(jnp.asarray, p)
                               for n, p in params.items()}
-                fused = self._fused_update_fns()
 
             def _sync_models():
                 for m in names:
@@ -473,22 +447,8 @@ class CoordinateDescent:
                         self.coordinates[n].pure_score(data_args[n],
                                                        params[n]))
                     for n in names}
-                rows = self._training_rows(next(iter(scores.values())).dtype)
-
-            # The per-step path's objective history lives in a FIXED-CAPACITY
-            # device vector updated by a tiny jitted set (enqueue-only);
-            # materialization is ONE device->host transfer. Per-entry float()
-            # syncs each wait for the device and would dominate whole runs.
-            # Capacity is padded to a power of two so the updater executable
-            # is shared across runs of different lengths. Made at the first
-            # per-step update: a run of whole blocks never dispatches it.
-            total_steps = max(num_iterations * len(names),
-                              len(objective_history))
-            cap = max(64, 1 << max(0, total_steps - 1).bit_length())
-            hist_dtype = np.dtype(next(iter(scores.values())).dtype)
-            hist_dev = None
-            hist_len = len(objective_history)  # absolute step count written
-            mat_hist_len = hist_len  # prefix already materialized (resumed)
+                score_dtype = np.dtype(next(iter(scores.values())).dtype)
+                rows = self._training_rows(score_dtype)
 
             # Device-resident results of fused iteration BLOCKS, appended in
             # step order and fetched host-side in ONE transfer per sync point.
@@ -497,51 +457,34 @@ class CoordinateDescent:
             pending_tracker_blocks: List[dict] = []
             n_coords = len(names)
 
-            def _materialize_history():
-                nonlocal mat_hist_len
-                if hist_len > mat_hist_len:
-                    with phase(scopes.CD_WAIT):
-                        vals = np.asarray(hist_dev)[mat_hist_len:hist_len]
-                    objective_history.extend(float(v) for v in vals)
-                    mat_hist_len = hist_len
-
             def _materialize_pending(include_trackers: bool = True):
+                """The objective history has one source, the blocks'
+                ``objs`` ([iterations, coordinates of the span]), appended
+                in step order."""
                 if not pending_blocks:
                     return
-                if include_trackers:
-                    with phase(scopes.CD_WAIT):
-                        host_blocks = jax.device_get(pending_blocks)
-                    for objs, trs in host_blocks:
-                        for i in range(objs.shape[0]):
-                            for ci in range(n_coords):
-                                objective_history.append(float(objs[i, ci]))
-                        _unstack_tracker_block(trs, names, trackers)
-                else:
-                    # Objectives only (small); tracker blocks stay on device
-                    # for lazy fetch via LazyTrackers.
-                    with phase(scopes.CD_WAIT):
-                        objs_host = jax.device_get(
-                            [b[0] for b in pending_blocks])
-                    for objs in objs_host:
-                        for i in range(objs.shape[0]):
-                            for ci in range(n_coords):
-                                objective_history.append(float(objs[i, ci]))
-                    pending_tracker_blocks.extend(
-                        b[1] for b in pending_blocks)
+                objs_dev = [objs for objs, _ in pending_blocks]
+                trs_dev = [trs for _, trs in pending_blocks]
                 pending_blocks.clear()
-
-            def _materialize_all():
-                # Per-step entries always precede block entries (the per-step
-                # path only runs before blocks start or exclusively), so this
-                # order keeps objective_history in step order.
-                _materialize_history()
-                _materialize_pending()
+                with phase(scopes.CD_WAIT):
+                    if include_trackers:
+                        objs_host, trs_host = jax.device_get(
+                            (objs_dev, trs_dev))
+                    else:
+                        # Objectives only (small); tracker blocks stay on
+                        # device for lazy fetch via LazyTrackers.
+                        objs_host, trs_host = jax.device_get(objs_dev), ()
+                        pending_tracker_blocks.extend(trs_dev)
+                for objs in objs_host:
+                    objective_history.extend(float(v) for v in objs.ravel())
+                for trs in trs_host:
+                    _unstack_tracker_block(trs, names, trackers)
 
             validating = (self.validation_data is not None
                           and bool(self.validation_evaluators))
-            # Blocks cover whole iterations; they apply when checkpoint saves
-            # land on iteration boundaries (otherwise the per-step path below
-            # preserves the exact mid-iteration save behavior).
+            # Whole iterations go as one block when checkpoint saves land on
+            # iteration boundaries; otherwise spans of one coordinate (below)
+            # keep every save at the step it is due.
             blockable = (checkpoint_dir is None
                          or checkpoint_interval % n_coords == 0)
 
@@ -560,7 +503,7 @@ class CoordinateDescent:
                             DeviceGameScorer,
                         )
                         self._val_scorer = DeviceGameScorer(
-                            game_model, self.validation_data, dtype=hist_dtype)
+                            game_model, self.validation_data, dtype=score_dtype)
                     val_scores = np.asarray(self._val_scorer.score(game_model))
                     metrics = {
                         ev.name: ev.evaluate_dataset(val_scores,
@@ -623,39 +566,22 @@ class CoordinateDescent:
                         _save(step)
                     continue
 
-                # -------- per-step path: partial-iteration resume or ---------
-                # -------- non-iteration-aligned checkpoint intervals ---------
+                # ---- a resume that lands inside an iteration, or saves ----
+                # ---- between iteration boundaries: the same program over ---
+                # ---- a span of one coordinate ------------------------------
+                step0 = np.uint32(step)  # this iteration's base step
                 for ci, n in enumerate(names):
                     step += 1
                     if step <= done_steps:
                         continue  # resumed past this update
                     t0 = time.perf_counter()
-                    # One dispatch: residual reduce (the reference's
-                    # partial-score reduce, CoordinateDescent.scala:150-158,
-                    # recomputed FRESH each step so a resumed run matches an
-                    # uninterrupted one bit-for-bit), per-step fold_in key,
-                    # solve, re-score, full objective incl. every coordinate's
-                    # penalties. The step index is passed as a device scalar so
-                    # the compiled executable is reused across steps.
                     with phase(scopes.CD_DISPATCH):
-                        new_p, new_score, obj, tracker = fused[n](
-                            data_args[n], pdata_args, params,
-                            tuple(scores[m] for m in names if m != n),
-                            base_key, np.uint32(step), rows)
-                    params[n] = new_p
-                    scores[n] = new_score
-                    if isinstance(tracker, tuple):
-                        tracker = list(tracker)
-                    trackers[n].append(tracker)
+                        span_fn = self._fused_block_fn(1, ci, ci + 1)
+                        params, scores, objs, trs = span_fn(
+                            data_args, pdata_args, params, scores, base_key,
+                            step0, rows)
+                    pending_blocks.append((objs, trs))
                     timings[n] += time.perf_counter() - t0
-
-                    # Device-side history write — NOT synced here (a float()
-                    # per update waits for the device); materialized in one
-                    # transfer at checkpoint/return.
-                    if hist_dev is None:
-                        hist_dev = jnp.zeros(cap, hist_dtype)
-                    hist_dev = _hist_set(hist_dev, np.uint32(step - 1), obj)
-                    hist_len = max(hist_len, step)
                     logger.info("iter %d coordinate %s enqueued (host "
                                 "dispatch %.1f ms)", it, n,
                                 1e3 * (time.perf_counter() - t0))
@@ -678,26 +604,22 @@ class CoordinateDescent:
 
             # The host blocks here, on the objectives' transfer, until the
             # device has finished the last block (trackers stay on the device).
-            _materialize_history()
             _materialize_pending(include_trackers=False)
             with phase(scopes.CD_FINISH):
                 _sync_models()
-                # Hot-loop compile invariant: every fused executable (per-
-                # coordinate step fns, per-span block fns) traced exactly
-                # once this run — the runtime complement of jaxlint's
-                # retrace-hazard rule. A trip here means argument
-                # shapes/dtypes/statics drifted call-to-call and every "one
-                # dispatch" above silently paid a recompile.
+                # Hot-loop compile invariant: every fused executable (one
+                # block fn per span) traced exactly once this run — the
+                # runtime complement of jaxlint's retrace-hazard rule. A
+                # trip here means argument shapes/dtypes/statics drifted
+                # call-to-call and every "one dispatch" above silently paid
+                # a recompile.
                 self.tracing_guard.assert_max_retraces(per_fn=1)
-                # How this run's mesh exchanges were placed: every
-                # coordinate with blocks over a mesh was traced by now.
+                # The random-effect coordinates built over a mesh: each
+                # divides its score exchange over it.
                 for c in self.coordinates.values():
-                    if (getattr(c, "mesh", None) is None
-                            or not hasattr(c, "dataset")):
-                        continue
-                    (_M_EXCHANGE_DIVIDED
-                     if getattr(c, "exchange_divided", False)
-                     else _M_EXCHANGE_REPLICATED).inc()
+                    if (getattr(c, "mesh", None) is not None
+                            and hasattr(c, "dataset")):
+                        _M_EXCHANGE_DIVIDED.inc()
                 if logger.isEnabledFor(logging.INFO) and objective_history:
                     logger.info("objective history: %s",
                                 ["%.6f" % v for v in objective_history])
@@ -796,12 +718,6 @@ def _zero_vectors(specs: Tuple[Tuple[str, jax.ShapeDtypeStruct], ...]):
             lax.with_sharding_constraint(jnp.zeros(s.shape, s.dtype),
                                          s.sharding))
         for n, s in specs}
-
-
-@jax.jit
-def _hist_set(hist, idx, value):
-    """Write one objective value into the device-resident history vector."""
-    return hist.at[idx].set(value.astype(hist.dtype))
 
 
 def _rows_from_blocks(ds) -> Tuple[Array, Array, Array]:

@@ -76,11 +76,6 @@ class Coordinate:
     """
 
     name: str
-    #: True once ``pure_score`` has been traced handing a mesh to the score
-    #: exchange (each device gathers and scatters its own slots); False
-    #: before, without a mesh, and for a coordinate that has no exchange.
-    #: ``CoordinateDescent.run`` counts it.
-    exchange_divided: bool = False
 
     @property
     def zero_start(self) -> bool:
@@ -638,7 +633,7 @@ class RandomEffectCoordinate(Coordinate):
             e, r, _ = block.x.shape
             refusal = _kernel_refusal(
                 self._objective, self.config, block.x,
-                sharded=False, norm=norm, bounds=bounds)
+                norm=norm, bounds=bounds)
             out.append({"rows": int(r), "entities": int(e),
                         "slots": int(e) * int(r),
                         "path": "kernel" if refusal is None else "vmapped",
@@ -717,8 +712,7 @@ class RandomEffectCoordinate(Coordinate):
                     c0 = gathered_to_normalized_space(c0, *norm)
             result = _solve_block(
                 self._objective, self.config, block, residual, c0,
-                sharded=self.mesh is not None, mesh=self.mesh,
-                norm=norm, bounds=bounds)
+                mesh=self.mesh, norm=norm, bounds=bounds)
             coef = result.x
             if norm is not None:
                 with _re_solve_scope(block):
@@ -729,8 +723,6 @@ class RandomEffectCoordinate(Coordinate):
 
     def pure_score(self, data, params) -> Array:
         blocks, pblocks = data[0], data[1]
-        if self.mesh is not None:
-            self.exchange_divided = True
         return _re_score_impl(blocks, pblocks, tuple(params),
                               n_rows=self.dataset.n_rows, mesh=self.mesh)
 
@@ -1165,7 +1157,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
             gammas = [
                 _solve_factored_block(
                     self._objective, self.config, block, B, extra, g0, d,
-                    sharded=self.mesh is not None, mesh=self.mesh).x
+                    mesh=self.mesh).x
                 for block, extra, g0 in zip(blocks, residuals, gammas)]
             batch = GLMBatch(
                 KroneckerFeatures(x_flat, _flatten_gammas(blocks, gammas)),
@@ -1179,8 +1171,6 @@ class FactoredRandomEffectCoordinate(Coordinate):
     def pure_score(self, data, params) -> Array:
         blocks, pblocks = data
         gammas, B = params
-        if self.mesh is not None:
-            self.exchange_divided = True
         return _fre_score_impl(
             blocks, pblocks, tuple(gammas), B,
             n_rows=self.dataset.n_rows, d=self.dataset.num_global_features,
@@ -1195,11 +1185,10 @@ class FactoredRandomEffectCoordinate(Coordinate):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("objective", "config", "d", "sharded", "mesh"))
+    static_argnames=("objective", "config", "d", "mesh"))
 def _solve_factored_block(
     objective: GLMObjective, config: GLMOptimizationConfiguration,
-    block: EntityBlock, B, extra_offsets, gamma0, d: int,
-    sharded: bool = False, mesh=None,
+    block: EntityBlock, B, extra_offsets, gamma0, d: int, mesh=None,
 ):
     """Per-entity latent solves against the current B: one projection einsum
     for the whole bucket, then the batched solve (fused Pallas kernel on
@@ -1210,10 +1199,9 @@ def _solve_factored_block(
     offsets = block.offsets if extra_offsets is None else \
         block.offsets + extra_offsets.astype(block.offsets.dtype)
 
-    use_kernel = _use_pallas_entity_solver(
-        objective, config, lat, sharded=sharded and mesh is None)
+    use_kernel = _use_pallas_entity_solver(objective, config, lat)
 
-    if use_kernel and sharded and mesh is not None:
+    if use_kernel and mesh is not None:
         return _shard_mapped_pallas_solver(
             objective, config, mesh, lat, block.labels, offsets,
             block.weights, gamma0)
@@ -1425,19 +1413,18 @@ def _warn_fallback(reason: str):
             reason)
 
 
-def _use_pallas_entity_solver(objective, config, x,
-                              sharded: bool, norm=None,
+def _use_pallas_entity_solver(objective, config, x, norm=None,
                               bounds=None) -> bool:
     """Whether this bucket's solve goes to the fused Pallas kernel; warns
     once per distinct reason where a TPU run loses it (see
     ``_kernel_refusal`` for the rules)."""
-    refusal = _kernel_refusal(objective, config, x, sharded, norm, bounds)
+    refusal = _kernel_refusal(objective, config, x, norm, bounds)
     if refusal is not None and refusal[1]:
         _warn_fallback(refusal[0])
     return refusal is None
 
 
-def _kernel_refusal(objective, config, x, sharded: bool, norm=None,
+def _kernel_refusal(objective, config, x, norm=None,
                     bounds=None) -> Optional[Tuple[str, bool]]:
     """Why this bucket's solve does NOT go to the fused Pallas kernel, as
     ``(reason, loud)``, or None where it does; loud where a TPU run
@@ -1449,20 +1436,15 @@ def _kernel_refusal(objective, config, x, sharded: bool, norm=None,
     (twice-differentiable losses, L2-only, box constraints via
     projected trust-region trials), with or without per-entity
     normalization, dense blocks that fit the kernel's VMEM working
-    set. Mesh-sharded blocks are ALSO kernel-eligible —
-    _solve_block wraps the kernel in shard_map (one kernel per device
-    over its entity shard) and passes sharded=False here to express
-    that; sharded=True means "sharded with no mesh to scope a
-    per-device kernel" and falls back to the portable vmapped path.
+    set. Blocks entity-sharded over a mesh are kernel-eligible by the
+    same rules: _solve_block wraps the kernel in shard_map (one kernel
+    per device over its entity shard).
 
-    ``sharded`` must be decided by the caller at the Python level (the
-    coordinate knows whether a mesh shards its blocks) — inside a trace
-    ``x`` is a tracer and carries no sharding. All checks here use
-    only static information (config, shapes, backend), so the decision
-    is stable for a given jit cache entry. PHOTON_ML_TPU_NO_PALLAS=1
-    disables the kernel; the flag is read when a solve first TRACES, so
-    set it before building coordinates, not mid-run (jit-cached entries
-    keep the path they were traced with)."""
+    All checks here use only static information (config, shapes,
+    backend), so the decision is stable for a given jit cache entry.
+    PHOTON_ML_TPU_NO_PALLAS=1 disables the kernel; the flag is read when
+    a solve first TRACES, so set it before building coordinates, not
+    mid-run (jit-cached entries keep the path they were traced with)."""
     import os
 
     from photon_ml_tpu.optimization.config import OptimizerType
@@ -1479,8 +1461,6 @@ def _kernel_refusal(objective, config, x, sharded: bool, norm=None,
     on_tpu = jax.default_backend() == "tpu" or _pallas_interpret()
     if not on_tpu:  # interpret: kernel on any backend
         return refuse(f"backend {jax.default_backend()}")
-    if sharded:
-        return refuse("entity-sharded blocks with no mesh in scope", True)
     rc = config.regularization_context
     l1 = rc.l1_weight(config.regularization_weight) if rc else 0.0
     if config.optimizer_type not in (OptimizerType.LBFGS,
@@ -1515,11 +1495,11 @@ def _kernel_refusal(objective, config, x, sharded: bool, norm=None,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("objective", "config", "sharded", "mesh"))
+    jax.jit, static_argnames=("objective", "config", "mesh"))
 def _solve_block(
     objective: GLMObjective, config: GLMOptimizationConfiguration,
-    block: EntityBlock, residual_scores, coefs0, sharded: bool = False,
-    mesh=None, norm=None, bounds=None,
+    block: EntityBlock, residual_scores, coefs0, mesh=None, norm=None,
+    bounds=None,
 ):
     """One batched solve over the bucket's entity axis, jitted so the whole
     batched solve (trace included) is cached across coordinate-descent
@@ -1547,15 +1527,11 @@ def _solve_block(
         with jax.named_scope(scopes.RE_GATHER):
             offsets = offsets + extra.astype(offsets.dtype)
 
-    # With a mesh the kernel is still eligible — it runs per device via
-    # shard_map below — so the "sharded" rejection only applies when no
-    # mesh is available to scope it.
     use_kernel = _use_pallas_entity_solver(
-        objective, config, block.x, sharded=sharded and mesh is None,
-        norm=norm, bounds=bounds)
+        objective, config, block.x, norm=norm, bounds=bounds)
 
     with _re_solve_scope(block):
-        if use_kernel and sharded and mesh is not None:
+        if use_kernel and mesh is not None:
             return _shard_mapped_pallas_solver(
                 objective, config, mesh, block.x, block.labels, offsets,
                 block.weights, coefs0, norm=norm, bounds=bounds)

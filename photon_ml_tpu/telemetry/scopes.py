@@ -52,10 +52,10 @@ def re_size_class(rows: int) -> str:
 #: ``name=`` of the entity solver's ``pallas_call`` (a mode suffix follows):
 #: the prefix of its device events.
 KERNEL = "pallas_entity_lbfgs"
-#: Function names of the jitted block and step: JAX's compile events and
-#: the trace's ``XLA Modules`` line carry them as ``jit_<name>``.
+#: Function name of the jitted block, whatever span of coordinates it
+#: covers: JAX's compile events and the trace's ``XLA Modules`` line carry
+#: it as ``jit_<name>``.
 CD_BLOCK = "cd_block"
-CD_STEP = "cd_step"
 
 # -- host phases of CoordinateDescent.run (telemetry.spans.phase) ------------
 CD_RUN = "photon.cd.run"                        # parent of all
@@ -100,12 +100,8 @@ COUNTER_CD_RUNS = "training.cd.runs"
 #: Runs that started cold (no ``initial_model``, no checkpoint restored):
 #: the runs whose initial scores were built and not computed.
 COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
-#: Per run, the random-effect coordinates built over a mesh whose score
-#: exchange was traced DIVIDED over it (each device gathers and scatters the
-#: slots of its own entities, one n-vector collective each way, under
-#: ``photon.re.gather`` / ``photon.re.scatter``), and those whose exchange
-#: was left to the partitioner, which replicates the index work on every
-#: device. divided / (divided + replicated) is the share of mesh exchanges
-#: that are divided; both stay 0 without a mesh.
+#: Per run, the random-effect coordinates built over a mesh: each divides
+#: its score exchange over it (each device gathers and scatters the slots of
+#: its own entities, one n-vector collective each way, under
+#: ``photon.re.gather`` / ``photon.re.scatter``). 0 without a mesh.
 COUNTER_RE_EXCHANGE_DIVIDED = "training.re.exchange.divided"
-COUNTER_RE_EXCHANGE_REPLICATED = "training.re.exchange.replicated"

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives, and what compiling cost.
 
 One rule for every entry point (the three drivers, ``chip_smoke.py``,
-``bench.py``, ``benchmark/harness.py``): ``JAX_COMPILATION_CACHE_DIR``
+``benchmark/harness.py``): ``JAX_COMPILATION_CACHE_DIR``
 decides when it is set — JAX reads it itself and no directory is set in
 code — and otherwise the cache is ``<checkout>/.jax_cache``. The path is
 part of the cache key's lookup, so it is never derived from a temporary

@@ -3,10 +3,10 @@
 Device-count behavior (``--mesh-devices`` on an N-chip host) can only be
 exercised by a jax whose TOTAL device count is N, and
 ``--xla_force_host_platform_device_count`` must land in XLA_FLAGS before
-jax initializes — so both the ``multi_device`` pytest fixture
-(tests/conftest.py) and the bench ``stream_training.mesh`` children
-(bench.py) spawn subprocesses with this environment. One builder keeps
-the scrub-and-append rules from drifting between them.
+jax initializes — so the ``multi_device`` pytest fixture
+(tests/conftest.py) and tests/test_mesh_fold.py spawn subprocesses with
+this environment. One builder keeps the scrub-and-append rules from
+drifting between them.
 """
 
 from __future__ import annotations

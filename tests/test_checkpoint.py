@@ -63,13 +63,13 @@ def test_resume_matches_uninterrupted_run(rng, tmp_path):
     ref = cd_ref.run(num_iterations=3, seed=11)
 
     # Fault-injected run: crash during iteration 2 (step 4 of 6). The hot
-    # loop runs through fused jitted update fns, so the fault is injected
-    # at the dispatch layer (the jit cache means a fault inside pure_update
-    # would only fire while tracing).
+    # loop runs through the jitted block, here over spans of one
+    # coordinate, so the fault is injected at the dispatch layer (the jit
+    # cache means a fault inside pure_update would only fire while
+    # tracing).
     coords = build_coordinates(data)
     cd_crash = CoordinateDescent(coords, TaskType.LOGISTIC_REGRESSION)
-    fns = cd_crash._fused_update_fns()
-    original_update = fns["perUser"]
+    original_update = cd_crash._fused_block_fn(1, 1, 2)  # perUser alone
     calls = {"n": 0}
 
     def failing_update(*args):
@@ -78,7 +78,7 @@ def test_resume_matches_uninterrupted_run(rng, tmp_path):
             raise RuntimeError("injected fault")
         return original_update(*args)
 
-    fns["perUser"] = failing_update
+    cd_crash._block_fns[1, 1, 2] = failing_update
     with pytest.raises(RuntimeError, match="injected fault"):
         cd_crash.run(num_iterations=3, seed=11, checkpoint_dir=tmp_path)
     # Steps 1..3 completed and were checkpointed before the crash.
@@ -98,6 +98,57 @@ def test_resume_matches_uninterrupted_run(rng, tmp_path):
     # Trackers are checkpointed too: pre-crash updates are not lost.
     assert len(resumed.trackers["fixed"]) == len(ref.trackers["fixed"])
     assert len(resumed.trackers["perUser"]) == len(ref.trackers["perUser"])
+
+
+@pytest.mark.parametrize("case", [
+    "saves between iteration boundaries", "the default interval",
+    "a resume from inside an iteration"])
+def test_spans_of_one_coordinate_repeat_the_whole_blocks(rng, tmp_path, case):
+    """Saves that fall inside an iteration, and a resume that lands there,
+    run the block over spans of one coordinate: no other program, each
+    traced once, and the history of the uninterrupted run entry for entry,
+    in step order, with one tracker entry an update."""
+    data, *_ = make_glmix_data(rng, n=200)
+
+    def descent():
+        return CoordinateDescent(build_coordinates(data),
+                                 TaskType.LOGISTIC_REGRESSION)
+
+    cd_ref = descent()
+    ref = cd_ref.run(num_iterations=3, seed=7)
+    assert cd_ref.tracing_guard.counts() == {"block:3": 1}
+
+    cd = descent()
+    if case == "a resume from inside an iteration":
+        # Steps 1..4 with one save, at step 3: the first coordinate of
+        # the second iteration. Resumed with saves at iteration
+        # boundaries: one span finishes that iteration, a whole block
+        # runs the third.
+        descent().run(num_iterations=2, seed=7, checkpoint_dir=tmp_path,
+                      checkpoint_interval=3)
+        assert all_checkpoint_steps(tmp_path) == [3]
+        res = cd.run(num_iterations=3, seed=7, checkpoint_dir=tmp_path,
+                     checkpoint_interval=2)
+        programs = {"block:1:1-2", "block:1"}
+        saved = [3, 4, 6]
+    else:
+        interval = 3 if "between" in case else 1
+        res = cd.run(num_iterations=3, seed=7, checkpoint_dir=tmp_path,
+                     checkpoint_interval=interval)
+        programs = {"block:1:0-1", "block:1:1-2"}
+        saved = [s for s in range(1, 7) if s % interval == 0]
+    assert cd.tracing_guard.counts() == dict.fromkeys(programs, 1)
+    # the newest two saves are kept
+    assert sorted(all_checkpoint_steps(tmp_path)) == saved[-2:]
+
+    assert len(res.objective_history) == len(ref.objective_history) == 6
+    np.testing.assert_allclose(res.objective_history, ref.objective_history,
+                               rtol=1e-5)
+    np.testing.assert_allclose(_final_coefs(res), _final_coefs(ref),
+                               rtol=1e-6)
+    for name in ("fixed", "perUser"):
+        assert len(res.trackers[name]) == len(ref.trackers[name]) == 3
+    assert set(res.timings) == {"fixed", "perUser"}
 
 
 def test_resume_rejects_mismatched_configuration(rng, tmp_path):

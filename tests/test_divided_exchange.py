@@ -163,7 +163,6 @@ def test_divided_scores_are_bitwise_the_one_device_scores(exchanged):
     assert s_over.dtype == s_one.dtype
     assert np.asarray(s_one).any()
     np.testing.assert_array_equal(np.asarray(s_over), np.asarray(s_one))
-    assert over.exchange_divided and not one.exchange_divided
 
 
 def test_divided_gather_is_bitwise_the_one_device_gather(exchanged):
@@ -375,15 +374,6 @@ def test_without_a_mesh_the_block_lowers_to_the_parents_text():
 
 # -- (e) the counters -------------------------------------------------------------------
 
-class _LeavesItToThePartitioner(RandomEffectCoordinate):
-    """Blocks over a mesh, scored as on one device: what every mesh fit
-    did before PR 32."""
-
-    def pure_score(self, data, params):
-        return coordinates._re_score_impl(
-            data[0], data[1], tuple(params), n_rows=self.dataset.n_rows)
-
-
 def _exchange_counts(coords, runs=1):
     telemetry.reset()
     telemetry.enable()
@@ -395,19 +385,17 @@ def _exchange_counts(coords, runs=1):
     finally:
         telemetry.disable()
         telemetry.reset()
-    return (counters.get(scopes.COUNTER_RE_EXCHANGE_DIVIDED, 0),
-            counters.get(scopes.COUNTER_RE_EXCHANGE_REPLICATED, 0))
+    return counters.get(scopes.COUNTER_RE_EXCHANGE_DIVIDED, 0)
 
 
 @pytest.mark.parametrize("case, want", [
-    ("mesh", (1, 0)), ("mesh, two runs", (2, 0)), ("no mesh", (0, 0)),
-    ("left to the partitioner", (0, 1))])
+    ("mesh", 1), ("mesh, two runs", 2), ("no mesh", 0),
+    ("mesh, the fixed effect alone", 0)])
 def test_exchange_counters(case, want):
+    """One count a run for each random-effect coordinate built over a
+    mesh."""
     data = make_glmix_data(np.random.default_rng(11), n=200)[0]
     coords = _glmix(data, None if case == "no mesh" else make_mesh(K))
-    if case == "left to the partitioner":
-        per_user = coords["perUser"]
-        coords["perUser"] = _LeavesItToThePartitioner(
-            name="perUser", dataset=per_user.dataset, task_type=TASK,
-            config=per_user.config, mesh=per_user.mesh)
+    if "alone" in case:
+        del coords["perUser"]
     assert _exchange_counts(coords, runs=2 if "two" in case else 1) == want

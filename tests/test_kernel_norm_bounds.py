@@ -380,7 +380,7 @@ def test_norm_bounds_compose_with_entity_sharding(monkeypatch, rng):
     sbounds = (pad(bounds[0], -0.3), pad(bounds[1], 0.3))
     sharded = _solve_block(obj, cfg(1.001e-8), sblock, None,
                            jnp.zeros((ep, d), dtype),
-                           sharded=True, mesh=mesh, norm=snorm,
+                           mesh=mesh, norm=snorm,
                            bounds=sbounds)
     assert sharded.value_history is None
     np.testing.assert_allclose(np.asarray(sharded.x[:e]),

@@ -403,8 +403,7 @@ def test_solve_block_routes_tron_through_kernel(monkeypatch, rng):
     # Guard: TRON + once-differentiable loss never routes to the kernel.
     hinge_obj = GLMObjective(
         loss_for_task(TaskType.SMOOTHED_HINGE_LOSS_LINEAR_SVM))
-    assert not _use_pallas_entity_solver(hinge_obj, cfg(1e-7), block.x,
-                                         sharded=False)
+    assert not _use_pallas_entity_solver(hinge_obj, cfg(1e-7), block.x)
 
 
 @pytest.mark.parametrize("mode", ["tron", "owlqn"])
@@ -487,7 +486,7 @@ def test_kernel_composes_with_entity_sharding(monkeypatch, rng):
     assert ep == 24
     sharded = _solve_block(obj, cfg(1.001e-8), sblock, None,
                            jnp.zeros((ep, d), dtype),
-                           sharded=True, mesh=mesh)
+                           mesh=mesh)
     assert sharded.value_history is None  # kernel ran under shard_map
     np.testing.assert_allclose(np.asarray(sharded.x[:e]),
                                np.asarray(plain.x),
@@ -534,7 +533,7 @@ def test_factored_kernel_composes_with_entity_sharding(monkeypatch, rng):
     ep = sblock.num_entities
     sharded = _solve_factored_block(obj, cfg(1.001e-8), sblock, B, None,
                                     jnp.zeros((ep, k), dtype), d,
-                                    sharded=True, mesh=mesh)
+                                    mesh=mesh)
     assert sharded.value_history is None
     np.testing.assert_allclose(np.asarray(sharded.x[:e]),
                                np.asarray(plain.x),
@@ -555,5 +554,5 @@ def test_vmem_oversize_bucket_keeps_vmapped_path(monkeypatch, rng):
     monkeypatch.setenv("PHOTON_ML_TPU_PALLAS_INTERPRET", "1")
     small = jax.ShapeDtypeStruct((100, 8, 16), jnp.float32)
     big = jax.ShapeDtypeStruct((100, 400, 128), jnp.float32)  # ~26 MB tile
-    assert _use_pallas_entity_solver(obj, cfg, small, sharded=False)
-    assert not _use_pallas_entity_solver(obj, cfg, big, sharded=False)
+    assert _use_pallas_entity_solver(obj, cfg, small)
+    assert not _use_pallas_entity_solver(obj, cfg, big)

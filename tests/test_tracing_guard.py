@@ -123,8 +123,8 @@ def test_coordinate_descent_asserts_trace_invariant_through_guard(rng):
     result = cd.run(num_iterations=3, seed=0)
     assert result.model is not None
     # run() already asserted per_fn=1 internally; confirm the guard saw
-    # the executables (fused per-coordinate fns + the 3-iteration block
-    # dispatch, which traced once) and the invariant holds externally.
+    # the executable (the 3-iteration block dispatch, which traced once)
+    # and the invariant holds externally.
     counts = cd.tracing_guard.counts()
     assert counts and counts["block:3"] == 1
     assert all(v <= 1 for v in counts.values())
