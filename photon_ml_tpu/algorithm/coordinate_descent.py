@@ -168,10 +168,12 @@ class CoordinateDescent:
     def _publish_work(self) -> None:
         """Gauges of the work this fit was built with, summed over its
         random-effect coordinates: slots and true rows (padding waste =
-        1 - rows / slots), and the entities the fused kernel and the
+        1 - rows / slots), the entities the fused kernel and the
         vmapped fallback solve (``coordinate.routing()`` has each bucket's
-        reason). Set once, here, and only while telemetry is enabled: the
-        true-row count is one small device reduction a bucket."""
+        reason), and the rows one scoring gathers through ``slot_of_row``
+        with those of them in no slot. Set once, here, and only while
+        telemetry is enabled: the true-row count is one small device
+        reduction a bucket."""
         if not telemetry.enabled():
             return
         routed = [c for c in self.coordinates.values()
@@ -186,6 +188,12 @@ class CoordinateDescent:
         telemetry.gauge(scopes.GAUGE_RE_KERNEL_ENTITIES).set(on_kernel)
         telemetry.gauge(scopes.GAUGE_RE_FALLBACK_ENTITIES).set(
             sum(b["entities"] for b in buckets) - on_kernel)
+        indexed = [c for c in self.coordinates.values()
+                   if hasattr(c, "unslotted_rows")]
+        telemetry.gauge(scopes.GAUGE_RE_SCORE_ROWS).set(
+            sum(c.dataset.n_rows for c in indexed))
+        telemetry.gauge(scopes.GAUGE_RE_SCORE_UNSLOTTED_ROWS).set(
+            sum(c.unslotted_rows for c in indexed))
         mesh = self._mesh()
         if mesh is None:
             return

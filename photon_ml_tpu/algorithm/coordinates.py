@@ -11,7 +11,7 @@ coordinates' scores — but the execution is TPU-native:
   broadcast + treeAggregate per L-BFGS evaluation).
 - RandomEffectCoordinate: per-bucket `vmap`-batched solves over the entity
   axis (vs. the reference's per-entity Breeze solves inside mapValues tasks);
-  scores come back through a scatter-add instead of RDD joins.
+  scores come back through a gather by row instead of RDD joins.
 
 Scores here, as in the reference (GameEstimator score semantics), are raw
 margins x.coef — offsets are NOT included (they are added by evaluators /
@@ -580,12 +580,17 @@ class RandomEffectCoordinate(Coordinate):
     axis, and the coordinate then runs per device over its own entities:
     the solves (the kernel under ``shard_map``, the vmapped solver
     partitioned by entity) and the score exchange with the row-sharded
-    n-vectors. The residual is made whole once per update
-    (``_whole_residual``) and each device gathers its own slots; each
-    device scatters its own margins into a private vector and one
-    all-reduce per scoring hands every device its row range
-    (``_scatter_over_mesh``). Nothing of a block's ``[E, r]`` shape crosses
-    devices, and the scores are bitwise the one-device ones."""
+    n-vectors. What crosses the devices: one all-reduce of the residual
+    each update, which makes it whole (``_whole_residual``) for each device
+    to gather its own slots from, and one all-gather of the flat margins
+    each scoring, from which each device gathers its own row range
+    (``_scores_by_row``). Nothing of a block's ``[E, r]`` shape crosses
+    devices, and the scores are bitwise the one-device ones.
+
+    The scores come back to row order through ``slot_of_row``, an index
+    built here, once, from the blocks' own ``row_ids`` (``_slot_of_row``):
+    a row sits in one slot of one block, and a dataset in which one sits
+    in two is refused."""
 
     name: str
     dataset: RandomEffectDataset
@@ -620,6 +625,8 @@ class RandomEffectCoordinate(Coordinate):
         self._bounds_blocks = tuple(
             _gather_block_bounds(self.lower_bounds, self.upper_bounds, b)
             for b in self.dataset.blocks)
+        self._slot_of_row, self.unslotted_rows = _slot_of_row(
+            self.dataset, self.mesh)
 
     def routing(self) -> List[dict]:
         """Per bucket, what the solve will do with it: the size class
@@ -665,13 +672,13 @@ class RandomEffectCoordinate(Coordinate):
         return self.model_of(params, model), trackers
 
     def score(self, model: RandomEffectModel) -> Array:
-        """All bucket margins + the scatter assembly as ONE jitted dispatch
-        (the eager per-block einsum/where/scatter chain costs several
+        """All bucket margins + the gather by row as ONE jitted dispatch
+        (the eager per-block einsum/concatenate/gather chain costs several
         dispatches per call)."""
         return _re_score_impl(
             tuple(self.dataset.blocks), tuple(self.dataset.passive_blocks),
-            tuple(model.local_coefs), n_rows=self.dataset.n_rows,
-            mesh=self.mesh)
+            tuple(model.local_coefs), self._slot_of_row,
+            n_rows=self.dataset.n_rows, mesh=self.mesh)
 
     def penalties(self, model: RandomEffectModel):
         return self.pure_penalties(tuple(model.local_coefs),
@@ -682,7 +689,7 @@ class RandomEffectCoordinate(Coordinate):
     def step_data(self):
         return (tuple(self.dataset.blocks),
                 tuple(self.dataset.passive_blocks),
-                self._norm_blocks, self._bounds_blocks)
+                self._norm_blocks, self._bounds_blocks, self._slot_of_row)
 
     def params_of(self, model: RandomEffectModel):
         return tuple(model.local_coefs)
@@ -701,7 +708,7 @@ class RandomEffectCoordinate(Coordinate):
             gathered_to_original_space,
         )
 
-        blocks, _, norm_blocks, bounds_blocks = data
+        blocks, _, norm_blocks, bounds_blocks, _ = data
         if self.mesh is not None:
             residual = _whole_residual(residual, self.mesh)
         new_coefs, results = [], []
@@ -722,8 +729,8 @@ class RandomEffectCoordinate(Coordinate):
         return tuple(new_coefs), results
 
     def pure_score(self, data, params) -> Array:
-        blocks, pblocks = data[0], data[1]
-        return _re_score_impl(blocks, pblocks, tuple(params),
+        blocks, pblocks, slot_of_row = data[0], data[1], data[-1]
+        return _re_score_impl(blocks, pblocks, tuple(params), slot_of_row,
                               n_rows=self.dataset.n_rows, mesh=self.mesh)
 
     def penalty_data(self):
@@ -1082,6 +1089,8 @@ class FactoredRandomEffectCoordinate(Coordinate):
         self._objective = GLMObjective(loss_for_task(self.task_type))
         self._l1, self._l2 = _l1_l2(self.config)
         self._ll1, self._ll2 = _l1_l2(self.latent_config)
+        self._slot_of_row, self.unslotted_rows = _slot_of_row(
+            self.dataset, self.mesh)
 
     @property
     def _dtype(self):
@@ -1127,7 +1136,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
 
     def step_data(self):
         return (tuple(self.dataset.blocks),
-                tuple(self.dataset.passive_blocks))
+                tuple(self.dataset.passive_blocks), self._slot_of_row)
 
     def params_of(self, model):
         dt = self._dtype
@@ -1141,7 +1150,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
         return model.with_update(list(gammas), np.asarray(B))
 
     def pure_update(self, data, params, residual, rng_key):
-        blocks, _ = data
+        blocks = data[0]
         gammas, B = list(params[0]), params[1]
         d = self.dataset.num_global_features
         if self.mesh is not None:
@@ -1169,10 +1178,10 @@ class FactoredRandomEffectCoordinate(Coordinate):
         return (tuple(gammas), B), trackers
 
     def pure_score(self, data, params) -> Array:
-        blocks, pblocks = data
+        blocks, pblocks, slot_of_row = data
         gammas, B = params
         return _fre_score_impl(
-            blocks, pblocks, tuple(gammas), B,
+            blocks, pblocks, tuple(gammas), B, slot_of_row,
             n_rows=self.dataset.n_rows, d=self.dataset.num_global_features,
             mesh=self.mesh)
 
@@ -1289,12 +1298,9 @@ def _whole_residual(residual_scores: Optional[Array], mesh
     whole on every device, ONE collective on the n-vector per coordinate
     update whatever the number of size classes, with the rows filled up to
     a multiple of the mesh's ``data`` size and the zero sentinel slot
-    behind them. An all-gather, spelt as the v5e's compiler lowers one of
-    this size (every device writes its rows into a zero vector, an
-    all-reduce sums them), because that lowering drops the scope's name.
-    Spelt out at all, and not a sharding constraint, because a constraint
-    leaves the partitioner free to make the residual's producer whole
-    instead: it gathered X for it."""
+    behind them (``_end_to_end``). Spelt out, and not a sharding
+    constraint, because a constraint leaves the partitioner free to make
+    the residual's producer whole instead: it gathered X for it."""
     if residual_scores is None:
         return None
     from jax.sharding import PartitionSpec as P
@@ -1306,15 +1312,25 @@ def _whole_residual(residual_scores: Optional[Array], mesh
         residual_scores = jnp.pad(residual_scores,
                                   (0, own_rows * k - n_rows))
 
-    def all_rows(own):
-        whole = jax.lax.dynamic_update_slice(
-            jnp.zeros((own_rows * k + 1,), own.dtype), own,
-            (jax.lax.axis_index("data") * own_rows,))
-        return jax.lax.psum(whole, "data")
-
     return jax.shard_map(
-        all_rows, mesh=mesh, in_specs=P("data"), out_specs=P(),
+        functools.partial(_end_to_end, k=k, spare=1), mesh=mesh,
+        in_specs=P("data"), out_specs=P(),
     )(residual_scores)
+
+
+def _end_to_end(own, k: int, spare: int = 0):
+    """Under ``shard_map`` over the ``k`` devices of the ``data`` axis:
+    every device's vector ``own`` laid end to end in device order, with
+    ``spare`` zeros behind, whole on every device. An all-gather, spelt as
+    the v5e's compiler lowers one of these sizes (every device writes its
+    own into a zero vector, an all-reduce sums them), because that lowering
+    drops the scope's name from the collective: compiled for a described
+    v5e:2x2, ``jax.lax.all_gather`` of the flat margins is an ``all-reduce``
+    with no ``op_name``, and a trace counts it under no scope."""
+    whole = jax.lax.dynamic_update_slice(
+        jnp.zeros((own.shape[0] * k + spare,), own.dtype), own,
+        (jax.lax.axis_index("data") * own.shape[0],))
+    return jax.lax.psum(whole, "data")
 
 
 @contextlib.contextmanager
@@ -1604,67 +1620,114 @@ def _fe_score_impl(coef, feats, n_rows: int):
 
 
 @jax.named_scope(scopes.RE_SCATTER)
-def _scatter_margins(scores, row_ids, margins, n_rows):
-    """One block's margins added into the sentinel-extended score vector:
-    padding slots (row_ids == n_rows) add 0 to the sentinel slot. Under a
-    mesh the vector is a device's private one and the block that device's
-    shard of it (``_scatter_over_mesh``)."""
-    m = jnp.where(row_ids < n_rows, margins, 0.0)
-    return scores.at[row_ids.reshape(-1)].add(m.reshape(-1))
+def _scores_by_row(margins, slot_of_row, n_rows: int, dtype, mesh):
+    """Scores in row order, ``f[n_rows]``, from every scored block's
+    ``[E, r]`` margins (``_scored_blocks``' order): the margins' way back is
+    a gather by row. A real row sits in one slot of one block, so the
+    scatter of the slots into the rows is a permutation, read here from the
+    rows' side: the margins are flattened and laid end to end with one zero
+    behind them, and row i reads position ``slot_of_row[i]`` of that
+    (``_index_rows`` made it when the coordinate was built; a row in no
+    slot reads the zero). n indices and no padding among them, where a
+    scatter-add walks every slot.
 
+    With a mesh (static: the coordinate's, whose blocks are entity-sharded
+    over it) each device lays the margins of its OWN entities end to end,
+    ONE all-gather per scoring makes the flat vector whole on every device
+    (``_end_to_end``), and each device gathers its own row range through
+    its piece of the row-sharded ``slot_of_row``, whose entries are
+    positions in the all-gathered order: the scores come back row-sharded
+    like the fixed effect's batch, and no ``[E, r]`` array crosses
+    devices."""
+    def own_rows(margins_l, slot_l):
+        flat = jnp.concatenate(
+            [m.reshape(-1) for m in margins_l]
+            + [jnp.zeros((1,), dtype)]).astype(dtype)
+        if mesh is not None:
+            flat = _end_to_end(flat, mesh.shape["data"])
+        # in bounds by construction: no clamp, no fill
+        return flat.at[slot_l].get(mode="promise_in_bounds")
 
-def _scatter_in_turn(slots, length: int, n_rows: int, dtype):
-    """Every block's ``(row_ids, margins)`` added in turn into one zero
-    vector of ``length`` > n_rows entries (the sentinel slot among them)."""
-    scores = jnp.zeros((length,), dtype)
-    for row_ids, margins in slots:
-        scores = _scatter_margins(scores, row_ids, margins, n_rows)
-    return scores
-
-
-@jax.named_scope(scopes.RE_SCATTER)
-def _scatter_over_mesh(mesh, slots, n_rows: int, dtype):
-    """A mesh fit's scatter, over every block's entity-sharded ``(row_ids,
-    margins)``. Each device adds the margins of its OWN entities, all
-    blocks in turn, into one private zero vector as long as the row range
-    (filled up to a multiple of the mesh's ``data`` size, plus the sentinel
-    slot); ONE all-reduce of that n-vector per scoring sums them and each
-    device keeps its row range (a reduce-scatter, spelt as the v5e's
-    compiler lowers one, which drops the scope's name on the way): the
-    scores come back row-sharded like the fixed effect's batch, and no
-    ``[E, r]`` array crosses devices. A real row sits in one slot of one
-    block, so every entry is ``0 + m`` on one device and 0 on the others:
-    the sum is bitwise the one-device scatter's."""
+    if mesh is None:
+        return own_rows(margins, slot_of_row)
     from jax.sharding import PartitionSpec as P
 
-    k = mesh.shape["data"]
+    scores = jax.shard_map(
+        own_rows, mesh=mesh,
+        in_specs=([P("data", None)] * len(margins), P("data")),
+        out_specs=P("data"),
+    )(margins, slot_of_row)
+    return scores if scores.shape[0] == n_rows else scores[:n_rows]
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "mesh"))
+def _index_rows(row_ids, n_rows: int, mesh=None):
+    """``slot_of_row`` from every scored block's ``row_ids``: for each row
+    the position of its slot in the flat margins ``_scores_by_row`` gathers
+    from, and the position of the appended zero for a row that sits in no
+    slot. Also the most slots any row sits in, a row that does, and the
+    number of rows in no slot. One program at set-up, two scatter-adds over
+    the slots (the positions, and a count); padding slots (``row_ids ==
+    n_rows``) write nothing.
+
+    With a mesh each device writes the positions of the slots of its own
+    entity shards, as they will lie in the all-gathered vector (each
+    device's margins with a zero behind them: the first device's zero is the
+    one a row in no slot reads), into a private vector over the row range
+    filled up to a multiple of the mesh's ``data`` size; one all-reduce
+    sums the vectors and each device keeps its row range: ``slot_of_row``
+    comes back row-sharded, ``i32[own_rows * k]``."""
+    k = 1 if mesh is None else mesh.shape["data"]
     own_rows = -(-n_rows // k)
 
-    def own_slots(slots_l):
-        scores = jax.lax.psum(
-            _scatter_in_turn(slots_l, own_rows * k + 1, n_rows, dtype),
-            "data")
-        return jax.lax.dynamic_slice(
-            scores, (jax.lax.axis_index("data") * own_rows,), (own_rows,))
+    def own_slots(row_ids_l):
+        flat = jnp.concatenate([r.reshape(-1) for r in row_ids_l]
+                               + [jnp.zeros((0,), jnp.int32)])
+        slots = flat.shape[0]
+        chip = 0 if mesh is None else jax.lax.axis_index("data")
+        real = flat < n_rows
+        # a position less the first zero's: a row nobody writes reads it
+        at = jnp.arange(slots, dtype=jnp.int32) + (chip * (slots + 1) - slots)
+        rows = jnp.zeros((2, own_rows * k), jnp.int32)
+        rows = rows.at[0, flat].add(jnp.where(real, at, 0), mode="drop")
+        rows = rows.at[1, flat].add(real.astype(jnp.int32), mode="drop")
+        if mesh is not None:
+            rows = jax.lax.psum(rows, "data")
+        index, count = rows[0] + slots, rows[1]
+        found = (jnp.max(count), jnp.argmax(count),
+                 jnp.sum(count[:n_rows] == 0))
+        if mesh is not None:
+            index = jax.lax.dynamic_slice(
+                index, (chip * own_rows,), (own_rows,))
+        return (index,) + found
 
-    per_entity = P("data", None)
-    scores = jax.shard_map(
-        own_slots, mesh=mesh,
-        in_specs=([(per_entity, per_entity)] * len(slots),),
-        out_specs=P("data"),
-    )(slots)
-    return scores if own_rows * k == n_rows else scores[:n_rows]
+    if mesh is None:
+        return own_slots(row_ids)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        own_slots, mesh=mesh, in_specs=((P("data", None),) * len(row_ids),),
+        out_specs=(P("data"), P(), P(), P()),
+    )(row_ids)
 
 
-def _scatter_slots(slots, n_rows: int, dtype, mesh):
-    """Scores in row order, ``f[n_rows]``, from ``slots``: an ITERATOR over
-    every block's ``(row_ids, margins)``, so that on one device a block's
-    margins are made when its turn to be scattered comes. With a mesh
-    (static: the coordinate's, whose blocks are entity-sharded over it)
-    the scatter is ``_scatter_over_mesh``'s."""
-    if mesh is not None:
-        return _scatter_over_mesh(mesh, list(slots), n_rows, dtype)
-    return _scatter_in_turn(slots, n_rows + 1, n_rows, dtype)[:-1]
+def _slot_of_row(dataset: RandomEffectDataset, mesh) -> Tuple[Array, int]:
+    """(``slot_of_row``, rows in no slot) for a coordinate over ``dataset``
+    (already laid over ``mesh`` where there is one), derived from the
+    blocks' own ``row_ids`` on the device. Refuses a dataset in which a row
+    sits in two slots: its scores would not be a gather."""
+    index, most, row, unslotted = _index_rows(
+        tuple(b.row_ids for b, _ in _scored_blocks(
+            dataset.blocks, dataset.passive_blocks, dataset.blocks)),
+        n_rows=dataset.n_rows, mesh=mesh)
+    most, row, unslotted = (
+        int(v) for v in jax.device_get((most, row, unslotted)))
+    if most > 1:
+        raise ValueError(
+            f"row {row} sits in {most} slots of the random-effect "
+            "dataset's blocks: a row belongs to one entity, among its "
+            "active rows or its passive ones, once")
+    return index, unslotted
 
 
 @jax.named_scope(scopes.RE_MARGINS)
@@ -1680,18 +1743,19 @@ def _scored_blocks(blocks, pblocks, params):
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "mesh"))
-def _re_score_impl(blocks, pblocks, coefs, n_rows: int, mesh=None):
-    """A random effect's scores: every block's margins scattered back into
-    row order (``_scatter_slots`` says what a mesh does to it)."""
-    return _scatter_slots(
-        ((block.row_ids, _local_margins(block, c))
-         for block, c in _scored_blocks(blocks, pblocks, coefs)),
-        n_rows, coefs[0].dtype if coefs else jnp.float32, mesh)
+def _re_score_impl(blocks, pblocks, coefs, slot_of_row, n_rows: int,
+                   mesh=None):
+    """A random effect's scores: every block's margins, read back in row
+    order (``_scores_by_row`` says what a mesh does to it)."""
+    return _scores_by_row(
+        [_local_margins(block, c)
+         for block, c in _scored_blocks(blocks, pblocks, coefs)],
+        slot_of_row, n_rows, coefs[0].dtype if coefs else jnp.float32, mesh)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "d", "mesh"))
-def _fre_score_impl(blocks, pblocks, gammas, B, n_rows: int, d: int,
-                    mesh=None):
+def _fre_score_impl(blocks, pblocks, gammas, B, slot_of_row, n_rows: int,
+                    d: int, mesh=None):
     """``_re_score_impl`` where entity e's coefficients are
     ``gamma_e @ B``."""
     def block_margins(block, gamma):
@@ -1701,7 +1765,7 @@ def _fre_score_impl(blocks, pblocks, gammas, B, n_rows: int, d: int,
             coefs = jnp.pad(coefs, ((0, 0), (0, pad)))
         return block.local_margins(coefs)
 
-    return _scatter_slots(
-        ((block.row_ids, block_margins(block, g))
-         for block, g in _scored_blocks(blocks, pblocks, gammas)),
-        n_rows, B.dtype, mesh)
+    return _scores_by_row(
+        [block_margins(block, g)
+         for block, g in _scored_blocks(blocks, pblocks, gammas)],
+        slot_of_row, n_rows, B.dtype, mesh)

@@ -22,8 +22,10 @@ FE_SCORE = "photon.fe.score"      # _fe_score_impl
 RE_GATHER = "photon.re.gather"
 RE_SOLVE = "photon.re.solve"      # kernel or vmapped solve, one child a class
 RE_MARGINS = "photon.re.margins"  # block.local_margins
-# margins scattered back into row order; under a mesh each device scatters
-# its own, then ONE all-reduce sums the private vectors (under the scope)
+# the margins' way back into row order: one gather by row through
+# slot_of_row; under a mesh each device gathers its own row range, after ONE
+# all-gather of the flat margins (under the scope). The name is the
+# exchange's way back, whatever operation does it.
 RE_SCATTER = "photon.re.scatter"
 CD_OBJECTIVE = "photon.cd.objective"  # the loss sum and the penalties
 
@@ -84,6 +86,12 @@ GAUGE_RE_SLOTS = "training.re.slots"
 GAUGE_RE_ROWS = "training.re.rows"
 GAUGE_RE_KERNEL_ENTITIES = "training.re.kernel_entities"
 GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"
+#: The index work of one scoring, summed over the coordinates that score
+#: through ``slot_of_row``: rows gathered (n a coordinate, beside the slots
+#: above that a scatter-add would walk), and those of them that sit in no
+#: slot and read the appended zero.
+GAUGE_RE_SCORE_ROWS = "training.re.score.rows"
+GAUGE_RE_SCORE_UNSLOTTED_ROWS = "training.re.score.unslotted_rows"
 
 # -- gauges of a fit whose coordinates lie over a device mesh (mesh=) ----------
 GAUGE_MESH_DEVICES = "training.mesh.devices"
@@ -101,7 +109,8 @@ COUNTER_CD_RUNS = "training.cd.runs"
 #: the runs whose initial scores were built and not computed.
 COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
 #: Per run, the random-effect coordinates built over a mesh: each divides
-#: its score exchange over it (each device gathers and scatters the slots of
-#: its own entities, one n-vector collective each way, under
-#: ``photon.re.gather`` / ``photon.re.scatter``). 0 without a mesh.
+#: its score exchange over it (each device gathers the residual into the
+#: slots of its own entities and the margins into its own rows, one
+#: collective each way, under ``photon.re.gather`` / ``photon.re.scatter``).
+#: 0 without a mesh.
 COUNTER_RE_EXCHANGE_DIVIDED = "training.re.exchange.divided"

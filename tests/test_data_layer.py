@@ -136,6 +136,80 @@ def test_scatter_scores_roundtrip(rng):
     np.testing.assert_allclose(np.asarray(scores), np.ones(data.num_rows))
 
 
+def _per_user_coordinate(ds):
+    from photon_ml_tpu.algorithm import RandomEffectCoordinate
+    from photon_ml_tpu.optimization.config import (
+        GLMOptimizationConfiguration,
+    )
+
+    return RandomEffectCoordinate(
+        name="perUser", dataset=ds, task_type=TaskType.LOGISTIC_REGRESSION,
+        config=GLMOptimizationConfiguration.parse("5,1e-6,1.0,1.0,LBFGS,L2"))
+
+
+def _ones_model(coord):
+    zero = coord.initialize_model()
+    return zero.with_coefs([jnp.ones_like(c) for c in zero.local_coefs])
+
+
+@pytest.mark.parametrize("where", ["another user's slot", "a passive block"])
+def test_a_row_in_two_slots_is_refused_at_construction(rng, where):
+    """A coordinate's scores are a gather by row: a dataset in which a row
+    sits in two slots has no such reading, and construction names the
+    row."""
+    import dataclasses
+
+    data = _toy_game_data(rng)
+    ds = build_random_effect_dataset(
+        data, RandomEffectDataConfiguration("userId", "shard"),
+        intercept_col=9)
+    _per_user_coordinate(ds)  # as built: every row once
+    block = ds.blocks[0]
+    row = int(block.row_ids[0, 0])
+    if where == "another user's slot":
+        last = ds.blocks[-1]
+        assert last.row_ids[-1, 0] != row
+        twice = dataclasses.replace(
+            last, row_ids=last.row_ids.at[-1, 0].set(row))
+        ds = dataclasses.replace(ds, blocks=ds.blocks[:-1] + [twice])
+    else:
+        passive = dataclasses.replace(
+            block, row_ids=jnp.full_like(block.row_ids, ds.n_rows).at[
+                0, 0].set(row))
+        ds = dataclasses.replace(
+            ds, passive_blocks=[passive] + ds.passive_blocks[1:])
+    with pytest.raises(ValueError, match=rf"row {row} sits in 2 slots"):
+        _per_user_coordinate(ds)
+
+
+def test_a_row_in_no_slot_scores_zero(rng):
+    """A user filtered out of the dataset: its rows read the zero behind the
+    margins, every other row its own slot, and the coordinate counts
+    them."""
+    import dataclasses
+
+    data = _toy_game_data(rng)
+    ds = build_random_effect_dataset(
+        data, RandomEffectDataConfiguration("userId", "shard"),
+        intercept_col=9)
+    whole = _per_user_coordinate(ds)
+    assert whole.unslotted_rows == 0
+    want = np.array(whole.score(_ones_model(whole)))
+    assert (want >= 1.0).all()  # the intercept
+    gone = np.asarray(ds.blocks[0].row_ids)[0]
+    gone = gone[gone < ds.n_rows]
+    cut = dataclasses.replace(
+        ds, blocks=[jax.tree.map(lambda a: a[1:], ds.blocks[0])]
+        + ds.blocks[1:],
+        entity_codes=[ds.entity_codes[0][1:]] + ds.entity_codes[1:])
+    coord = _per_user_coordinate(cut)
+    assert coord.unslotted_rows == len(gone) > 0
+    got = np.asarray(coord.score(_ones_model(coord)))
+    assert not got[gone].any()
+    want[gone] = 0.0
+    np.testing.assert_array_equal(got, want)
+
+
 def test_reservoir_sample_properties(rng):
     idx, mult = reservoir_sample(rng, 100, 10)
     assert len(idx) == 10 and mult == 10.0

@@ -1,12 +1,17 @@
 """The score exchange of a ``mesh=`` fit is divided over the mesh (PR 32):
-each device gathers the residual into, and scatters the margins out of, the
-slots of its OWN entity shard, with one n-vector collective each way
+each device gathers the residual into the slots of its OWN entity shard and
+the margins into its OWN row range, with one collective each way
 (``algorithm/coordinates.py``: ``_whole_residual``, ``_gather_residual``,
-``_scatter_over_mesh``). On four of the eight virtual CPU devices: the
-divided exchange is bitwise the one-device exchange, a whole mesh fit agrees
-with the one-device fit, the compiled block carries no collective on a
-block's ``[E, r]`` shape, the one-device block is the program it was, and
-the two counters say which exchange a run traced."""
+``_scores_by_row``). The margins' way back is a gather by row through
+``slot_of_row`` (PR 34), on one device as over a mesh. On four of the eight
+virtual CPU devices: the divided exchange is bitwise the one-device
+exchange, both are bitwise the dataset's own scatter-add, a whole mesh fit
+agrees with the one-device fit, the compiled block carries no collective on
+a block's ``[E, r]`` shape and no scatter in the scoring, the one-device
+block is the program recorded here, and the counter and the gauges say
+what a run was built with."""
+
+import dataclasses
 
 import hashlib
 import re
@@ -61,11 +66,12 @@ def _clean_telemetry():
 
 # -- (a) the divided exchange is bitwise the one-device exchange -----------------
 
-def _dataset(n_rows: int, projector: str):
+def _dataset(n_rows: int, projector: str, drop_short_passive: bool = False):
     """13 users of very different activity (several size classes, none a
     multiple of 4 entities: the mesh fills each with empty entities), 16
     active rows a user at most, the rest in passive blocks; padding slots
-    in every block."""
+    in every block. ``drop_short_passive``: the half of the users past the
+    cap with the fewest rows past it lose them (rows in no slot)."""
     rng = np.random.default_rng(n_rows)
     users = rng.choice(13, n_rows, p=np.arange(1, 14) / 91.0)
     data = GameDataset.build(
@@ -73,9 +79,14 @@ def _dataset(n_rows: int, projector: str):
         feature_shards={"u": sp.csr_matrix(  # whole numbers: see _params
             rng.integers(1, 5, (n_rows, 5)).astype(float))},
         ids={"userId": users.astype(str)})
+    past_cap = np.sort(np.bincount(users) - 16)
+    past_cap = past_cap[past_cap > 0]
     return build_random_effect_dataset(
         data, RandomEffectDataConfiguration(
             "userId", "u", num_active_data_points=16,
+            num_passive_data_points_lower_bound=(
+                int(past_cap[len(past_cap) // 2]) if drop_short_passive
+                else None),
             projector_type=projector), seed=3)
 
 
@@ -209,6 +220,91 @@ def test_an_update_under_the_mesh_takes_the_residual(kind):
                for g, u in zip(leaves(got), leaves(unmoved)))
 
 
+# -- (a') the scores by ``slot_of_row`` are the dataset's own scatter-add ----------
+
+def _without_first_entity(block):
+    return None if block is None else jax.tree.map(lambda a: a[1:], block)
+
+
+def _slotted_dataset(n_rows: int, projector: str):
+    """``_dataset`` with what the index must survive besides: users past
+    the active cap whose passive rows were too few to keep (rows in no
+    slot), and one user taken out of its block whole (an entity with no
+    slot). Passive blocks and padding slots stay."""
+    dataset = _dataset(n_rows, projector, drop_short_passive=True)
+    return dataclasses.replace(
+        dataset,
+        blocks=[_without_first_entity(dataset.blocks[0])]
+        + dataset.blocks[1:],
+        passive_blocks=[_without_first_entity(dataset.passive_blocks[0])]
+        + dataset.passive_blocks[1:],
+        entity_codes=[dataset.entity_codes[0][1:]]
+        + dataset.entity_codes[1:])
+
+
+@pytest.fixture(scope="module", params=[
+    (kind, n_rows) for kind in ("random", "factored")
+    for n_rows in (400, 402)], ids=lambda p: "-".join(map(str, p)))
+def slotted(request):
+    """A coordinate on one device and over four, the same parameters, and
+    what ``RandomEffectDataset.scatter_scores`` (the scatter-add, kept as
+    the independent oracle) makes of the one-device margins."""
+    kind, n_rows = request.param
+    dataset = _slotted_dataset(
+        n_rows, "INDEX_MAP" if kind == "random" else "IDENTITY")
+    one = _coordinate(kind, dataset, None)
+    over = _coordinate(kind, dataset, make_mesh(K))
+    rng = np.random.default_rng(17)
+    p_one = _params(kind, one, rng)
+    if kind == "random":
+        coefs = p_one
+    else:
+        d = dataset.num_global_features
+        coefs = [jnp.pad(g @ p_one[1], ((0, 0), (0, b.d_pad - d)))
+                 for g, b in zip(p_one[0], dataset.blocks)]
+    want = dataset.scatter_scores(
+        [b.local_margins(c) for b, c in zip(dataset.blocks, coefs)],
+        [None if b is None else b.local_margins(c)
+         for b, c in zip(dataset.passive_blocks, coefs)])
+    slots = np.concatenate([
+        np.asarray(b.row_ids).reshape(-1)
+        for b in dataset.blocks + dataset.passive_blocks if b is not None])
+    return {"one": (one, p_one),
+            "four": (over, _params(kind, over, rng, like=p_one)),
+            "want": np.asarray(want), "n_rows": n_rows,
+            "in_no_slot": np.setdiff1d(np.arange(n_rows), slots)}
+
+
+def test_the_slotted_fixture_has_what_the_index_must_survive(slotted):
+    one, _ = slotted["one"]
+    n = slotted["n_rows"]
+    assert any(b is not None for b in one.dataset.passive_blocks)
+    assert any(b is None for b in one.dataset.passive_blocks)
+    assert any((np.asarray(b.row_ids) == n).any()
+               for b in one.dataset.blocks)
+    assert 16 < len(slotted["in_no_slot"]) < n // 2
+    assert slotted["want"].any()
+    assert not slotted["want"][slotted["in_no_slot"]].any()
+
+
+@pytest.mark.parametrize("devices", ["one", "four"])
+def test_scores_by_slot_of_row_are_bitwise_the_scatter_adds(slotted,
+                                                            devices):
+    coord, params = slotted[devices]
+    n = slotted["n_rows"]
+    got = coord.pure_score(coord.step_data(), params)
+    assert got.shape == (n,) and got.dtype == slotted["want"].dtype
+    np.testing.assert_array_equal(np.asarray(got), slotted["want"])
+    assert coord.unslotted_rows == len(slotted["in_no_slot"])
+    index = np.asarray(coord.step_data()[-1])
+    k = K if devices == "four" else 1
+    assert index.dtype == np.int32 and index.shape == (-(-n // k) * k,)
+    # the rows in no slot, and the rows that fill the range up to a multiple
+    # of the mesh, all read one position: the first device's appended zero
+    assert len(set(index[slotted["in_no_slot"]]) | set(index[n:])) == 1
+    assert len(np.unique(index)) == n - len(slotted["in_no_slot"]) + 1
+
+
 # -- (b) a whole mesh fit ----------------------------------------------------------
 
 def _glmix(data, mesh):
@@ -249,7 +345,7 @@ def fits():
             out[name, n_rows] = {
                 "coords": coords, "result": result, "scores": scores,
                 "compiled": (fn.lower(*seen["args"]).compile().as_text()
-                             if (name, n_rows) == ("four", 400) else None)}
+                             if n_rows == 400 else None)}
     return out
 
 
@@ -311,9 +407,9 @@ def _collectives(compiled_text: str):
 
 def test_the_compiled_mesh_block_divides_the_exchange(fits):
     """No collective carries an array of a block's ``[E, r]`` shape (whole
-    or a device's shard), and the n-vector crosses the devices once per
-    random-effect update and once per scoring (the scan's body is compiled
-    once; the fixed effect moves ``[d]`` and scalars)."""
+    or a device's shard); the residual crosses the devices once per
+    random-effect update and the flat margins once per scoring (the scan's
+    body is compiled once; the fixed effect moves ``[d]`` and scalars)."""
     fit = fits["four", 400]
     found = _collectives(fit["compiled"])
     assert found
@@ -330,10 +426,33 @@ def test_the_compiled_mesh_block_divides_the_exchange(fits):
     assert len(n_vectors) == 2, n_vectors
     gathers = [line for _, line in n_vectors if scopes.RE_GATHER in line]
     assert len(gathers) == 1
-    # the other: the scatter's (a combiner may have merged it with the
-    # penalties' scalars, under either's name)
-    assert all(op in ("all-reduce", "all-gather", "reduce-scatter")
-               for op, _ in n_vectors)
+    # the other: every device's margins and the zero behind them, laid end
+    slots = sum(b.x.shape[0] * b.x.shape[1]
+                for b in fit["coords"]["perUser"].dataset.blocks)
+    # to end (``_end_to_end``; a combiner may have merged the collective
+    # with the penalties' scalars, under either's name)
+    assert [op for op, shapes, _ in found if (slots + K,) in shapes] == [
+        "all-reduce"]
+
+
+def _instructions_under(compiled_text: str, scope: str):
+    return [line for line in compiled_text.splitlines()
+            if f"/{scope}/" in line and " = " in line]
+
+
+def test_the_compiled_blocks_hold_no_scatter_in_the_scoring(fits):
+    """The margins' way back is a gather: under ``photon.re.scatter`` the
+    mesh block and the one-device block hold a gather and no scatter, and
+    the one-device block holds no scatter-add anywhere (the solvers'
+    history updates are scatters that set)."""
+    for name in ("four", "one"):
+        compiled = fits[name, 400]["compiled"]
+        back = _instructions_under(compiled, scopes.RE_SCATTER)
+        assert any(re.search(r" gather\(", line) for line in back), name
+        assert not any(re.search(r" scatter\(", line) for line in back)
+        assert not any("scatter-add" in line for line in back)
+    assert "scatter-add" not in fits["one", 400]["compiled"]
+    assert "all-gather" not in fits["one", 400]["compiled"]
 
 
 def test_the_exchanges_collectives_sit_under_their_scopes(fits):
@@ -345,12 +464,16 @@ def test_the_exchanges_collectives_sit_under_their_scopes(fits):
 # -- (d) without a mesh the program is the one it was ---------------------------------
 
 # sha256 of ``cd_block``'s lowered text (``as_text()``: no locations) at
-# ``test_fit_tracing``'s tiny size, x64 on, as the PARENT of PR 32 lowered it
-# (``git archive`` of 592a391 and this tree: the same digest, and the same
-# ``op_name`` of every compiled instruction). A PR that changes the
-# one-device block on purpose records its own here and says so.
+# ``test_fit_tracing``'s tiny size, x64 on. Recorded anew by PR 34, which
+# changes the one-device block on purpose (the scoring's ten scatter-adds
+# become a concatenate and one gather, and ``slot_of_row`` is one more
+# argument), from that PR's own tree (its parent, 576cf65, lowers to
+# c7399799675318638c27c5fe662ff8ce4cf3d2d6eec2f2f996c8ab839ebec2de, the
+# digest PR 32 took from ITS parent 592a391 and PR 33 left standing). A PR
+# that changes the one-device block on purpose records its own here and says
+# so.
 ONE_DEVICE_BLOCK_SHA256 = (
-    "c7399799675318638c27c5fe662ff8ce4cf3d2d6eec2f2f996c8ab839ebec2de")
+    "aed299d949fcd16eff0f971d91d133b5cb30a8149b6970cbf9f505737430a4ac")
 
 
 @pytest.mark.skipif(F32_MODE, reason="the digest is of the x64 program")
@@ -372,7 +495,7 @@ def test_without_a_mesh_the_block_lowers_to_the_parents_text():
         ONE_DEVICE_BLOCK_SHA256
 
 
-# -- (e) the counters -------------------------------------------------------------------
+# -- (e) the counters and the gauges ----------------------------------------------------
 
 def _exchange_counts(coords, runs=1):
     telemetry.reset()
@@ -399,3 +522,42 @@ def test_exchange_counters(case, want):
     if "alone" in case:
         del coords["perUser"]
     assert _exchange_counts(coords, runs=2 if "two" in case else 1) == want
+
+
+@pytest.mark.parametrize("mesh", [None, "4"], ids=["one device", "mesh"])
+def test_score_index_gauges(mesh):
+    """What one scoring gathers, beside what a scatter-add would walk: n
+    rows a coordinate against every slot, and the rows that read the
+    appended zero."""
+    dataset = _slotted_dataset(402, "INDEX_MAP")
+    coord = _coordinate("random", dataset, mesh and MESHES[mesh]())
+    telemetry.enable()
+    CoordinateDescent({"perUser": coord}, TASK)
+    gauges = telemetry.snapshot()["gauges"]
+    slots = sum(b.num_entities * b.n_pad for b in coord.dataset.blocks)
+    assert gauges[scopes.GAUGE_RE_SLOTS] == slots  # the active blocks'
+    assert gauges[scopes.GAUGE_RE_SCORE_ROWS] == 402
+    assert gauges[scopes.GAUGE_RE_SCORE_UNSLOTTED_ROWS] == \
+        coord.unslotted_rows > 0
+
+
+def test_the_index_is_built_once_and_outside_the_block():
+    """``slot_of_row`` is made when the coordinate is constructed and rides
+    in ``step_data()``: runs build nothing, and the block takes it as an
+    argument."""
+    from photon_ml_tpu.utils import compile_cache
+
+    data = make_glmix_data(np.random.default_rng(12), n=230)[0]
+    compile_cache._listen()
+    compile_cache.reset_compile_ledger()
+    coords = _glmix(data, None)
+    built = compile_cache.compile_ledger()["functions"]["_index_rows"]
+    assert built["compiles"] == 1
+    index = coords["perUser"].step_data()[-1]
+    assert index is coords["perUser"].step_data()[-1]
+    cd = CoordinateDescent(coords, TASK)
+    cd.run(1)
+    cd.run(1)
+    rows = compile_cache.compile_ledger()["functions"]
+    assert rows["_index_rows"] == built
+    compile_cache.reset_compile_ledger()
