@@ -331,11 +331,15 @@ def covered_ms(intervals: Iterable[Interval], lo: int, hi: int) -> float:
 def place(path: str) -> dict:
     """``leaf``: the innermost table scope on the path; ``coordinate``: its
     ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
-    ``photon.re.solve``; ``scoped``: under any ``photon.*`` at all."""
-    leaf = coordinate = size_class = None
+    ``photon.re.solve``; ``product``: the sparse product
+    (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
+    ``<leaf>/<product>``; ``scoped``: under any ``photon.*`` at all."""
+    leaf = coordinate = size_class = product = None
     parts = path.split("/")
     for i, part in enumerate(parts):
-        if part in scopes.DEVICE_SCOPES:
+        if part in scopes.FE_PRODUCT_SCOPES and leaf:
+            product = f"{leaf}/{part}"
+        elif part in scopes.DEVICE_SCOPES:
             leaf = part
             if (part == scopes.RE_SOLVE and i + 1 < len(parts)
                     and _SIZE_CLASS.match(parts[i + 1])):
@@ -343,6 +347,7 @@ def place(path: str) -> dict:
         elif part.startswith(scopes.cd_coordinate("")):
             coordinate = part
     return {"leaf": leaf, "coordinate": coordinate, "size_class": size_class,
+            "product": product,
             "scoped": leaf is not None or coordinate is not None}
 
 
@@ -385,6 +390,7 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
     by_leaf: Dict[str, list] = {}
     by_coord: Dict[str, list] = {}
     by_class: Dict[str, dict] = {}
+    by_product: Dict[str, list] = {}
     scoped, everything, loose = [], [], {}
     collectives: Dict[str, list] = {}
     for name, s, d, path in events:
@@ -402,6 +408,8 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
         scoped.append(iv)
         if where["leaf"]:
             by_leaf.setdefault(where["leaf"], []).append(iv)
+        if where["product"]:
+            by_product.setdefault(where["product"], []).append(iv)
         if where["coordinate"]:
             by_coord.setdefault(where["coordinate"], []).append(iv)
         if where["size_class"]:
@@ -448,6 +456,9 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
                 "path": "kernel" if v["kernel"] else "vmapped"}
             for c, v in sorted(by_class.items(),
                                key=lambda kv: int(kv[0][1:]))},
+        # a sparse fixed effect's products, by the scope that ran them
+        "product_ms": {c: covered_ms(ivs, lo, hi)
+                       for c, ivs in sorted(by_product.items())},
         "coordinate_ms": {c: covered_ms(ivs, lo, hi)
                           for c, ivs in sorted(by_coord.items())},
         "unattributed_ms": busy_ms - scoped_ms,
@@ -511,7 +522,7 @@ def _mean(per_job: List[dict]) -> dict:
            for k in ("window_ms", "busy_ms", "exchange_ms", "collectives_ms",
                      "unattributed_ms", "unattributed_share")}
     for key in ("scope_ms", "before_block_ms", "coordinate_ms",
-                "collective_ms"):
+                "collective_ms", "product_ms"):
         names = sorted({s for j in per_job for s in j[key]})
         out[key] = {s: sum(j[key].get(s, 0.0) for j in per_job) / n
                     for s in names}
@@ -541,6 +552,16 @@ def print_report(result: dict, out=sys.stdout) -> None:
             ms = block["scope_ms"].get(s, 0.0)
             print(f"| `{s}` | {ms:.3f} | {100 * ms / busy:.2f}% | "
                   f"{coll.get(s, 0.0):.3f} |", file=out)
+            mine = {c: v for c, v in block.get("product_ms", {}).items()
+                    if c.startswith(s + "/")}
+            for c, v in mine.items():
+                print(f"| &nbsp;&nbsp;`{c[len(s) + 1:]}` | {v:.3f} | "
+                      f"{100 * v / busy:.2f}% |", file=out)
+            if mine:
+                rest = ms - sum(mine.values())
+                print(f"| &nbsp;&nbsp;the rest of `{s}` (d-space, "
+                      f"n-vectors) | {rest:.3f} | {100 * rest / busy:.2f}% |",
+                      file=out)
             if s == scopes.RE_SOLVE:
                 for c, v in block["size_class_ms"].items():
                     print(f"| &nbsp;&nbsp;`{c}` ({v['path']}) | "

@@ -55,6 +55,7 @@ Array = jax.Array
 _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
 _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
+_M_FE_PRODUCTS = telemetry.counter(scopes.COUNTER_FE_PRODUCTS)
 
 
 def _unstack_tracker_block(trs: Dict[str, object], names: Sequence[str],
@@ -173,9 +174,18 @@ class CoordinateDescent:
         reason), and the rows one scoring gathers through ``slot_of_row``
         with those of them in no slot. Set once, here, and only while
         telemetry is enabled: the true-row count is one small device
-        reduction a bucket."""
+        reduction a bucket. Where a coordinate's matrix came from the
+        sparse chooser: what it counted and stored (``sparse_work``)."""
         if not telemetry.enabled():
             return
+        sparse = self._sparse_counts()
+        if sparse:
+            telemetry.gauge(scopes.GAUGE_FE_NNZ).set(
+                sum(c.nnz for c in sparse))
+            telemetry.gauge(scopes.GAUGE_FE_SLOTS).set(
+                sum(c.slots for c in sparse))
+            telemetry.gauge(scopes.GAUGE_FE_MAX_COL_DEGREE).set(
+                max(c.max_col_degree for c in sparse))
         routed = [c for c in self.coordinates.values()
                   if hasattr(c, "routing")]
         buckets = [b for c in routed for b in c.routing()]
@@ -220,6 +230,12 @@ class CoordinateDescent:
                 max(slots.values()))
             telemetry.gauge(scopes.GAUGE_RE_SLOTS_PER_DEVICE_MEAN).set(
                 sum(slots.values()) / mesh.devices.size)
+
+    def _sparse_counts(self) -> list:
+        """The ``LayoutCounts`` of every coordinate whose matrix the sparse
+        chooser built (``Coordinate.sparse_work``)."""
+        counts = [c.sparse_work()[0] for c in self.coordinates.values()]
+        return [c for c in counts if c is not None]
 
     def _mesh(self):
         """The device mesh the coordinates were built over (``mesh=``),
@@ -310,6 +326,13 @@ class CoordinateDescent:
             from photon_ml_tpu.utils.compile_cache import note_partitions
 
             note_partitions(scopes.CD_BLOCK, mesh.devices.size)
+        sparse = self._sparse_counts()
+        if sparse:
+            # ... and in which layout its sparse fixed effect runs
+            from photon_ml_tpu.utils.compile_cache import note_layout
+
+            note_layout(scopes.CD_BLOCK, ",".join(sorted(
+                {c.layout for c in sparse})))
         self._block_fns[cache_key] = fn
         self.tracing_guard.track(
             f"block:{n_iters}" if whole
@@ -634,14 +657,20 @@ class CoordinateDescent:
                 final = GameModel(dict(models), self.task_type)
                 if best_model is None:
                     best_model = final
+                lazy = LazyTrackers(trackers, pending_tracker_blocks, names)
+                if telemetry.enabled() and self._sparse_counts():
+                    # the sparse products the solves ran: a fetch of the
+                    # trackers, so only where somebody is listening
+                    _M_FE_PRODUCTS.inc(sum(
+                        c.sparse_work(lazy[name])[1]
+                        for name, c in self.coordinates.items()))
                 return CoordinateDescentResult(
                     model=final,
                     objective_history=list(objective_history),
                     validation_history=validation_history,
                     best_model=best_model,
                     best_metric=best_metric,
-                    trackers=LazyTrackers(trackers, pending_tracker_blocks,
-                                          names),
+                    trackers=lazy,
                     timings=timings,
                 )
 

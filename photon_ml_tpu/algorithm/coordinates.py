@@ -35,7 +35,7 @@ from photon_ml_tpu.data.sampling import down_sample_weights
 from photon_ml_tpu.models.fixed_effect import FixedEffectModel
 from photon_ml_tpu.models.glm import model_for_task
 from photon_ml_tpu.models.random_effect import RandomEffectModel
-from photon_ml_tpu.ops.features import KroneckerFeatures
+from photon_ml_tpu.ops.features import KroneckerFeatures, layout_counts
 from photon_ml_tpu.ops.glm_objective import GLMBatch, GLMObjective
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
@@ -120,6 +120,14 @@ class Coordinate:
     def pure_score(self, data, params) -> Array:
         raise NotImplementedError
 
+    def sparse_work(self, trackers=()):
+        """``(counts, products)``: what the sparse chooser counted and chose
+        for this coordinate's matrix (``ops.features.LayoutCounts``; None
+        where no matrix of its came from the chooser), and the sparse
+        products the solves of ``trackers`` ran. ``CoordinateDescent`` sums
+        these into the ``training.fe.*`` gauges and counter."""
+        return None, 0
+
     def penalty_data(self):
         """Device data the penalty needs beyond the params (e.g. the
         normalization context's factor/shift arrays). Passed back into
@@ -202,6 +210,21 @@ class FixedEffectCoordinate(Coordinate):
         # jitted objective. (Never capture device arrays in hot jitted
         # closures: they are re-staged on every call.)
         self._l1, self._l2 = _l1_l2(self.config)
+
+    def sparse_work(self, trackers=()):
+        counts = layout_counts(self._batch.features)
+        if counts is None:
+            return None, 0
+        # A margin-cached L-BFGS solve of ``it`` iterations is ``it + 1``
+        # matvec and ``it + 1`` rmatvec whatever its line search does; of
+        # a TRON, OWL-QN or bounded solve the iterations are not products.
+        cached = (self.config.optimizer_type.name == "LBFGS"
+                  and not self._l1 and self.lower_bounds is None
+                  and self.upper_bounds is None)
+        if not cached:
+            return counts, 0
+        return counts, sum(2 * (int(np.asarray(tr.iterations)) + 1)
+                           for tr in trackers)
 
     def _pad_d(self, arr, fill=0.0):
         """Zero-pad a [d] vector to the feature-sharded width (no-op

@@ -115,12 +115,13 @@ class GameDataset:
         self, shard_id: str, dtype=jnp.float32,
         extra_offsets: Optional[np.ndarray] = None,
         dense_threshold: float = DENSE_DENSITY_THRESHOLD,
-        sparse_layout: str = "csr",
+        sparse_layout: Optional[str] = None,
     ) -> GLMBatch:
         """Materialize one feature shard as a device GLMBatch
         (the analog of FixedEffectDataSet, ml/data/FixedEffectDataSet.scala:29-103).
-        ``sparse_layout`` picks the below-threshold layout ("csr" |
-        "bucketed_ell" | "sort_permute_ell" — see features_to_device)."""
+        ``sparse_layout`` names the below-threshold layout ("csr" |
+        "bucketed_ell" | "sort_permute_ell"); None leaves it to the
+        program's chooser (see features_to_device)."""
         from photon_ml_tpu.data.device_feed import chunked_device_put
 
         mat = self.feature_shards[shard_id]

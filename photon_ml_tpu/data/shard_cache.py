@@ -118,6 +118,7 @@ from photon_ml_tpu.telemetry import span
 from photon_ml_tpu.ops.features import (
     CSRFeatures,
     DENSE_DENSITY_THRESHOLD,
+    lay_out_triplet,
     padded_csr_arrays,
 )
 from photon_ml_tpu.serving.buckets import BucketLadder, next_pow2
@@ -385,7 +386,9 @@ def assemble_fixed_effect_batch(
     device-side concatenation reconstructs the one-shot upload exactly —
     including the dense-vs-CSR layout decision, which is made from the
     GLOBAL density after the stream ends, exactly like
-    `features_to_device` on the full matrix."""
+    `features_to_device` on the full matrix. Below the density threshold
+    the streamed triplet ends where the one-shot upload ends, in the
+    program's chooser (`ops.features.lay_out_triplet`)."""
     import jax.numpy as jnp
 
     from photon_ml_tpu.ops.glm_objective import GLMBatch
@@ -424,6 +427,8 @@ def assemble_fixed_effect_batch(
         # pieces into zeros reproduces the same array (no duplicates, and
         # the f64->f32 value cast already happened elementwise at upload).
         feats = feats.to_dense()
+    else:
+        feats = lay_out_triplet(feats)
     batch = GLMBatch(features=feats, labels=cat(lab_p), offsets=cat(off_p),
                      weights=cat(wgt_p))
     stats = dict(stream.stats())

@@ -50,12 +50,12 @@ class TrainedGLM:
 def device_batch(features, labels, offsets=None, weights=None,
                  dtype=jnp.float32,
                  dense_threshold: float = DENSE_DENSITY_THRESHOLD,
-                 storage_dtype=None, sparse_layout: str = "csr"):
+                 storage_dtype=None, sparse_layout=None):
     """Host arrays -> device GLMBatch, choosing dense vs sparse layout.
     ``storage_dtype=jnp.bfloat16`` halves dense feature HBM traffic
-    (f32 accumulation — see DenseFeatures); ``sparse_layout`` picks the
-    below-threshold layout ("csr" | "bucketed_ell" |
-    "sort_permute_ell" — see features_to_device)."""
+    (f32 accumulation — see DenseFeatures); ``sparse_layout`` names the
+    below-threshold layout ("csr" | "bucketed_ell" | "sort_permute_ell");
+    None leaves it to the program's chooser (see features_to_device)."""
     feats = features_to_device(features, dtype, dense_threshold,
                                storage_dtype=storage_dtype,
                                sparse_layout=sparse_layout)

@@ -14,16 +14,26 @@ in HBM, in one of two layouts:
 
 Both are registered pytrees, so they flow through ``jit``/``vmap``/``pjit``
 and can be sharded with ``NamedSharding`` like any other array.
+
+A sparse matrix that is already ON the device (a row's non-zeros side by
+side, ``cols i32[n, k]`` / ``vals f[n, k]``) comes in through
+``sparse_rows_to_device``, which counts it there and chooses its layout
+(``choose_layout``); ``features_to_device``, the host path, ends in the same
+chooser.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple, Union
+import functools
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from photon_ml_tpu.telemetry import scopes
 
 Array = jax.Array
 
@@ -112,12 +122,13 @@ class CSRFeatures:
     n_rows / n_features are static Python ints (aux data) — they fix the
     output shapes for XLA.
 
-    Kernel note (revised after direct measurement, TPU v5e): XLA lowers
-    segment_sum to scatter-add at ~120M updates/s regardless of index
-    sortedness — fine for small/medium nnz, but ~100x off the roofline at
-    scale. For large sparse problems use BlockedEllFeatures below, whose
-    products are gather-only (measured 6.7x faster end-to-end on a
-    d=2M / 12M-nnz solve; see docs/SCALE.md).
+    Kernel note (TPU v5e, the cell ``sparse-lr.fit``, PERF.md section 5): a
+    gather and a scatter-add (which is what XLA makes of ``segment_sum``)
+    each cost ~6.7 ns an index or more, and each product here is one of
+    each: two index operations a non-zero (timed on that cell's data: 20.2
+    ns a non-zero ``matvec``, 14.2 ``rmatvec``), where
+    ``SlotMajorEllFeatures`` below pays one a stored slot (6.65 / 6.75).
+    For ragged rows; ``choose_layout`` decides.
     """
 
     values: Array  # f[nnz]
@@ -125,6 +136,8 @@ class CSRFeatures:
     row_ids: Array  # i32[nnz]
     n_rows: int
     n_features: int
+    # what the chooser counted, where it built this matrix (static, aux data)
+    counts: Optional["LayoutCounts"] = None
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -165,6 +178,7 @@ class CSRFeatures:
         return (self.values, self.col_ids, self.row_ids), (
             self.n_rows,
             self.n_features,
+            self.counts,
         )
 
     @classmethod
@@ -935,9 +949,211 @@ def sort_permute_ell_from_scipy(mat, max_groups: int = 8,
                                         max_groups=max_groups, dtype=dtype)
 
 
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class SlotMajorEllFeatures:
+    """Sparse rows of one stored width ``k``, slot by slot: slot ``s`` of
+    every row lies end to end, ``cols[s * n + i]`` / ``vals[s * n + i]``
+    being row ``i``'s ``s``-th stored entry (ELLPACK, transposed and flat).
+    A padded slot holds value 0 at column 0 and adds nothing to any product.
+
+    Why this shape (TPU v5e, compiled and timed at 9.2M rows x 40 slots,
+    PERF.md section 5): an index operation costs ~6.7 ns an index, so a
+    product should be ONE a stored slot: ``matvec`` is a loop over the k
+    slots of one gather of n values of ``v``, ``rmatvec`` a loop of one
+    scatter-add of n updates into ``f[d]``. The loops walk flat vectors, so
+    neither product has a temporary larger than an n-vector, where the same
+    products over ``[n, k]`` arrays first copy both into a layout padded
+    from k to 128 lanes (9.4 GB at that size). Columns that collide cost the
+    scatter-add 1% at that log's skew (30% if EVERY update lands on one
+    address), so no column is treated apart.
+    """
+
+    cols: Array  # i32[k * n], slot-major
+    vals: Array  # f[k * n]
+    n_rows: int
+    n_features: int
+    # what the chooser counted, where it built this matrix (static, aux data)
+    counts: Optional["LayoutCounts"] = None
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return (self.n_rows, self.n_features)
+
+    @property
+    def num_features(self) -> int:
+        return self.n_features
+
+    @property
+    def slots_per_row(self) -> int:
+        return self.vals.shape[-1] // max(self.n_rows, 1)
+
+    def _slot(self, s, square: bool, acc):
+        n = self.n_rows
+        c = lax.dynamic_slice(self.cols, (s * n,), (n,))
+        x = lax.dynamic_slice(self.vals, (s * n,), (n,)).astype(acc)
+        return c, (x * x if square else x)
+
+    def _by_row(self, v: Array, square: bool) -> Array:
+        acc = jnp.promote_types(v.dtype, jnp.float32)
+
+        def body(s, out):
+            c, x = self._slot(s, square, acc)
+            # in bounds by construction (checked where the matrix is built)
+            return out + x * v.at[c].get(mode="promise_in_bounds")
+
+        return lax.fori_loop(0, self.slots_per_row, body,
+                             jnp.zeros((self.n_rows,), acc))
+
+    def _by_column(self, u: Array, square: bool) -> Array:
+        acc = jnp.promote_types(u.dtype, jnp.float32)
+
+        def body(s, out):
+            c, x = self._slot(s, square, acc)
+            return out.at[c].add(x * u, mode="promise_in_bounds")
+
+        return lax.fori_loop(0, self.slots_per_row, body,
+                             jnp.zeros((self.n_features,), acc))
+
+    def matvec(self, v: Array) -> Array:
+        with jax.named_scope(scopes.FE_MATVEC):
+            return self._by_row(v, square=False)
+
+    def rmatvec(self, u: Array) -> Array:
+        with jax.named_scope(scopes.FE_RMATVEC):
+            return self._by_column(u, square=False)
+
+    def row_sq_matvec(self, v: Array) -> Array:
+        with jax.named_scope(scopes.FE_MATVEC):
+            return self._by_row(v, square=True)
+
+    def sq_rmatvec(self, u: Array) -> Array:
+        with jax.named_scope(scopes.FE_RMATVEC):
+            return self._by_column(u, square=True)
+
+    def to_csr(self) -> CSRFeatures:
+        """The same matrix as a flat triplet, in this layout's order (slot
+        by slot, so not sorted by row; a padded slot stays a stored value 0
+        at column 0): for what reads a matrix entry by entry, such as the
+        sharding of a batch over a mesh."""
+        rows = jnp.tile(jnp.arange(self.n_rows, dtype=jnp.int32),
+                        self.slots_per_row)
+        return CSRFeatures(self.vals, self.cols, rows, self.n_rows,
+                           self.n_features, self.counts)
+
+    def tree_flatten(self):
+        return (self.cols, self.vals), (self.n_rows, self.n_features,
+                                        self.counts)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
 FeatureMatrix = Union[DenseFeatures, CSRFeatures, BlockedCSRFeatures,
                       BlockedEllFeatures, BucketedEllFeatures,
-                      SortPermuteEllFeatures, KroneckerFeatures]
+                      SortPermuteEllFeatures, SlotMajorEllFeatures,
+                      KroneckerFeatures]
+
+
+@dataclasses.dataclass(frozen=True)
+class LayoutCounts:
+    """What the chooser knows of a sparse matrix given as rows of ``k``
+    stored slots, and what it chose. Counted on the device, for a matrix
+    born there (``sparse_rows_to_device``) and for one uploaded as a
+    triplet (``lay_out_triplet``). Rides on the matrix it describes as
+    static pytree data (``counts``)."""
+
+    n_rows: int
+    slots_per_row: int  # k: the fullest row's stored entries
+    n_features: int
+    nnz: int  # stored values that are not 0
+    max_col_degree: int  # non-zeros of the fullest column
+    layout: str = ""  # the layout chosen
+    slots: int = 0  # the slots that layout stores
+
+
+def choose_layout(counts: LayoutCounts) -> str:
+    """``"slot_major_ell"`` or ``"csr"`` for a sparse matrix of these
+    counts: the fewer index operations a product. An index operation, a
+    gather or a scatter-add alike, costs the v5e ~6.7 ns an index whatever
+    the table and the order (PERF.md section 5, the cell ``sparse-lr.fit``:
+    the row-wise ELL's products read 6.65 / 6.75 ns a slot, the flat
+    triplet's 20.2 / 14.2 a non-zero on the same data). The ELL pays one a
+    stored slot, padding included; the flat triplet pays two a non-zero (a
+    gather and a segment-sum) and stores no padding. The column degrees
+    are counted for the gauges and decide nothing: that log's skew costs
+    its scatter-add 0.8%."""
+    slots = counts.n_rows * counts.slots_per_row
+    return "slot_major_ell" if slots <= 2 * counts.nnz else "csr"
+
+
+@functools.partial(jax.jit, static_argnames=("n_features",))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _count_rows(cols, vals, n_features: int):
+    """nnz, the fullest column's non-zeros and the columns' range: one
+    scatter-add over every slot."""
+    stored = vals != 0
+    deg = jnp.zeros((n_features,), jnp.int32).at[cols].add(
+        stored.astype(jnp.int32), mode="drop")
+    return (jnp.sum(stored, dtype=jnp.int32), jnp.max(deg), jnp.min(cols),
+            jnp.max(cols))
+
+
+@jax.jit
+@jax.named_scope(scopes.FE_LAYOUT)
+def _slot_major(a):
+    return a.T.reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("nnz",))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _compact_rows(cols, vals, nnz: int):
+    """The stored non-zeros as a row-sorted triplet of exactly ``nnz``."""
+    k = cols.shape[1]
+    flat = vals.reshape(-1)
+    (at,) = jnp.nonzero(flat != 0, size=nnz, fill_value=0)
+    return flat[at], cols.reshape(-1)[at], (at // k).astype(jnp.int32)
+
+
+def sparse_rows_to_device(cols: Array, vals: Array,
+                          n_features: int) -> "FeatureMatrix":
+    """A sparse matrix that is already on the device, as rows of ``k``
+    stored slots (``cols i32[n, k]``, ``vals f[n, k]``: a row's non-zeros
+    side by side; a padded slot is value 0 at column 0), to the layout the
+    program runs it in. Everything happens on the device: the counts the
+    choice needs are one program there, of which four scalars come back,
+    and the layout is built there from the two arrays (which are left as
+    they are: the caller's).
+
+    There is no layout argument. ``choose_layout`` decides from n, k and
+    the non-zeros counted; the result's ``counts`` (``layout_counts``) say
+    what was counted and chosen."""
+    n, k = (int(v) for v in cols.shape)
+    n_features = int(n_features)
+    nnz, max_deg, lo, hi = (int(v) for v in jax.device_get(
+        _count_rows(cols, vals, n_features)))
+    if lo < 0 or hi >= n_features:
+        raise ValueError(f"column ids span [{lo}, {hi}]; the matrix has "
+                         f"{n_features} columns")
+    counts = LayoutCounts(n_rows=n, slots_per_row=k, n_features=n_features,
+                          nnz=nnz, max_col_degree=max_deg)
+    layout = choose_layout(counts)
+    if layout == "slot_major_ell":
+        return SlotMajorEllFeatures(
+            _slot_major(cols), _slot_major(vals), n, n_features,
+            dataclasses.replace(counts, layout=layout, slots=n * k))
+    return CSRFeatures(
+        *_compact_rows(cols, vals, nnz), n, n_features,
+        dataclasses.replace(counts, layout=layout, slots=nnz))
+
+
+def layout_counts(feats) -> Optional[LayoutCounts]:
+    """What the chooser counted and chose for ``feats``; None for a matrix
+    it did not build (and for the layouts it never builds)."""
+    return getattr(feats, "counts", None)
 
 
 def csr_from_scipy(mat, n_features: int | None = None, pad_to: int | None = None,
@@ -996,13 +1212,61 @@ def padded_csr_arrays(mat, n_rows_pad: int, nnz_pad: int,
     return values, col_ids, row_ids
 
 
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_features"))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _count_triplet(values, col_ids, row_ids, n_rows: int, n_features: int):
+    """nnz, the fullest column's non-zeros and the fullest row's stored
+    entries, of a flat triplet."""
+    stored = (values != 0).astype(jnp.int32)
+    deg = jnp.zeros((n_features,), jnp.int32).at[col_ids].add(stored)
+    per_row = jnp.zeros((n_rows,), jnp.int32).at[row_ids].add(1)
+    return (jnp.sum(stored), jnp.max(deg, initial=0),
+            jnp.max(per_row, initial=0))
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "k"))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _slot_major_of_triplet(values, col_ids, row_ids, n_rows: int, k: int):
+    """A row-sorted triplet laid slot-major: a row's ``j``-th entry becomes
+    its slot ``j``; the slots a row does not fill hold value 0 at column 0."""
+    per_row = jnp.zeros((n_rows,), jnp.int32).at[row_ids].add(1)
+    first = jnp.cumsum(per_row) - per_row
+    nth = jnp.arange(row_ids.shape[0], dtype=jnp.int32) - first[row_ids]
+    at = nth * n_rows + row_ids
+    return (jnp.zeros((k * n_rows,), col_ids.dtype).at[at].set(col_ids),
+            jnp.zeros((k * n_rows,), values.dtype).at[at].set(values))
+
+
+def lay_out_triplet(feats: CSRFeatures) -> "FeatureMatrix":
+    """A row-sorted triplet that is on the device, nothing padded, to the
+    layout ``choose_layout`` picks for it, counted and built there: what
+    the host paths end in (``features_to_device`` after its upload, the
+    streamed assembly of ``data/shard_cache.py`` after its last piece), so
+    that the two hold the same arrays and a matrix born on the device
+    (``sparse_rows_to_device``) meets the same chooser."""
+    n, d = feats.shape
+    nnz, max_deg, k = (int(v) for v in jax.device_get(_count_triplet(
+        feats.values, feats.col_ids, feats.row_ids, n_rows=n, n_features=d)))
+    counts = LayoutCounts(n_rows=n, slots_per_row=k, n_features=d, nnz=nnz,
+                          max_col_degree=max_deg)
+    layout = choose_layout(counts)
+    if layout == "slot_major_ell":
+        cols, vals = _slot_major_of_triplet(
+            feats.values, feats.col_ids, feats.row_ids, n_rows=n, k=k)
+        return SlotMajorEllFeatures(
+            cols, vals, n, d,
+            dataclasses.replace(counts, layout=layout, slots=n * k))
+    return dataclasses.replace(feats, counts=dataclasses.replace(
+        counts, layout=layout, slots=int(feats.values.shape[-1])))
+
+
 DENSE_DENSITY_THRESHOLD = 0.2
 
 
 def features_to_device(mat, dtype=jnp.float32,
                        dense_threshold: float = DENSE_DENSITY_THRESHOLD,
                        storage_dtype=None,
-                       sparse_layout: str = "csr") -> FeatureMatrix:
+                       sparse_layout: "str | None" = None) -> FeatureMatrix:
     """Host feature matrix -> device layout, choosing dense vs sparse by
     density. The single chooser shared by the GLM and GAME ingest paths.
 
@@ -1011,18 +1275,20 @@ def features_to_device(mat, dtype=jnp.float32,
     bandwidth-bound fixed-effect iteration — see DenseFeatures). Sparse
     layouts ignore it (their cost is lookup-count-, not byte-, bound).
 
-    ``sparse_layout`` picks the layout used below the density
-    threshold: ``"csr"`` (default — fine for small/medium nnz),
-    ``"bucketed_ell"`` (degree-bucketed dual-ELL: gather-only products,
-    near-nnz slot counts at ~2x the memory — the right choice past a
-    few million nnz on TPU, where CSR's transpose product is
-    scatter-bound), or ``"sort_permute_ell"`` (cross-order movement as
-    one key-sort per pass; chip-gated alternative, see docs/SCALE.md).
-    Use ``blocked_ell_from_scipy`` directly for the mesh-sharded
-    (column-blocked) variant."""
+    Below the density threshold the sparse layout is ``choose_layout``'s,
+    from the uploaded triplet's own counts (``lay_out_triplet``): the
+    chooser a matrix born on the device goes through
+    (``sparse_rows_to_device``).
+    ``sparse_layout`` overrides it by name: ``"csr"``, ``"bucketed_ell"``
+    (degree-bucketed dual-ELL: gather-only products at ~2x the memory) or
+    ``"sort_permute_ell"`` (cross-order movement as one key-sort per pass;
+    see docs/SCALE.md). No chip measurement ranks the named ones against
+    the chooser's yet (PERF.md section 7). Use ``blocked_ell_from_scipy``
+    directly for the mesh-sharded (column-blocked) variant."""
     import scipy.sparse as sp
 
-    if sparse_layout not in ("csr", "bucketed_ell", "sort_permute_ell"):
+    if sparse_layout not in (None, "csr", "bucketed_ell",
+                             "sort_permute_ell"):
         # validate up front: a typo'd name must fail loudly even when
         # the density branch would never consult it (dense input)
         raise ValueError(
@@ -1053,5 +1319,7 @@ def features_to_device(mat, dtype=jnp.float32,
             return bucketed_ell_from_scipy(mat, dtype=dtype)
         if sparse_layout == "sort_permute_ell":
             return sort_permute_ell_from_scipy(mat, dtype=dtype)
+        if sparse_layout is None:
+            return lay_out_triplet(csr_from_scipy(mat, dtype=dtype))
         return csr_from_scipy(mat, dtype=dtype)
     return DenseFeatures(chunked_device_put(np.asarray(mat), dense_dt))

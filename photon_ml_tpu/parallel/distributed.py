@@ -40,6 +40,7 @@ from photon_ml_tpu.ops.features import (
     BlockedEllFeatures,
     CSRFeatures,
     DenseFeatures,
+    SlotMajorEllFeatures,
 )
 from photon_ml_tpu.ops.glm_objective import GLMBatch
 
@@ -201,14 +202,18 @@ def shard_batch(batch: GLMBatch, mesh: Mesh, axis: str = DATA_AXIS
 
     Rows are padded to a multiple of the mesh size with weight-0 rows
     (inert in the objective). For CSR the nnz stream is padded with zero
-    values pointing at row/col 0. Each device's shard is padded and placed
-    alone, and a batch that already lies over the mesh row by row comes
-    back with the buffers it came with (``_lay_over``).
+    values pointing at row/col 0; a slot-major ELL is sharded as the flat
+    triplet it unrolls to (``to_csr``: its stored slots, entry by entry).
+    Each device's shard is padded and placed alone, and a batch that
+    already lies over the mesh row by row comes back with the buffers it
+    came with (``_lay_over``).
     """
     row_sh = NamedSharding(mesh, P(axis))
 
     labels = _lay_over(batch.labels, row_sh, 0.0)
     feats = batch.features
+    if isinstance(feats, SlotMajorEllFeatures):
+        feats = feats.to_csr()
     if isinstance(feats, DenseFeatures):
         new_feats = DenseFeatures(_lay_over(
             feats.x, NamedSharding(mesh, P(axis, None)), 0.0))
@@ -219,6 +224,7 @@ def shard_batch(batch: GLMBatch, mesh: Mesh, axis: str = DATA_AXIS
             row_ids=_lay_over(feats.row_ids, row_sh, 0),
             n_rows=int(labels.shape[0]),
             n_features=feats.n_features,
+            counts=feats.counts,
         )
     else:
         raise TypeError(f"unsupported feature type {type(feats)}")
@@ -256,6 +262,9 @@ def shard_batch_feature_dim(
     columns simultaneously; rows are padded with weight-0 rows.
     """
     feats = batch.features
+    if isinstance(feats, SlotMajorEllFeatures):
+        feats = feats.to_csr()
+        batch = GLMBatch(feats, batch.labels, batch.offsets, batch.weights)
     if isinstance(feats, (CSRFeatures, BlockedCSRFeatures,
                           BlockedEllFeatures)):
         # Sparse huge-d regime: route through the column-blocked sparse

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.models.random_effect import RandomEffectModel
-from photon_ml_tpu.ops.features import CSRFeatures
+from photon_ml_tpu.ops.features import CSRFeatures, SlotMajorEllFeatures
 
 Array = jax.Array
 
@@ -122,6 +122,8 @@ def score_random_with_matrix(feats, mapped: Array, M: Array) -> Array:
     matrix (see assemble_re_matrix). ``mapped`` holds per-row model codes,
     -1 = unknown -> the zero row M[n_codes]."""
     rows = jnp.where(mapped >= 0, mapped, M.shape[0] - 1)
+    if isinstance(feats, SlotMajorEllFeatures):
+        feats = feats.to_csr()
     if isinstance(feats, CSRFeatures):
         contrib = feats.values * M[rows[feats.row_ids], feats.col_ids]
         return jax.ops.segment_sum(contrib, feats.row_ids,

@@ -17,6 +17,15 @@ PREFIX = "photon."
 # -- device scopes (jax.named_scope inside traced code) -----------------------
 FE_SOLVE = "photon.fe.solve"      # body of _solve_fixed
 FE_SCORE = "photon.fe.score"      # _fe_score_impl
+# the two products of a fixed effect whose matrix is SPARSE (X.w by gathers
+# from w, X^T.u by a scatter-add into f[d]): children of FE_SOLVE and of
+# FE_SCORE, opened by the layout's own ``matvec`` / ``rmatvec`` (a dense
+# matrix opens neither: its products are the solve's fusions)
+FE_MATVEC = "photon.fe.matvec"
+FE_RMATVEC = "photon.fe.rmatvec"
+# the programs that count a sparse matrix on the device and lay it out
+# (``ops.features.sparse_rows_to_device``): at construction, never in a fit
+FE_LAYOUT = "photon.fe.layout"
 # residual gather into the blocks' slots; under a mesh each device gathers
 # its own, after ONE all-reduce that makes the residual whole (under the scope)
 RE_GATHER = "photon.re.gather"
@@ -33,6 +42,11 @@ CD_OBJECTIVE = "photon.cd.objective"  # the loss sum and the penalties
 #: its path.
 DEVICE_SCOPES = (FE_SOLVE, FE_SCORE, RE_GATHER, RE_SOLVE, RE_MARGINS,
                  RE_SCATTER, CD_OBJECTIVE)
+#: Children of ``FE_SOLVE`` / ``FE_SCORE``: an operation under one of them
+#: counts there, and what is left of the parent's row is the d-space work
+#: (the two-loop, the line search's n-vectors). Not in ``DEVICE_SCOPES``:
+#: a fit over a dense matrix has no such operation.
+FE_PRODUCT_SCOPES = (FE_MATVEC, FE_RMATVEC)
 #: gather + margins + scatter: the score exchange.
 EXCHANGE_SCOPES = (RE_GATHER, RE_MARGINS, RE_SCATTER)
 
@@ -93,6 +107,16 @@ GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"
 GAUGE_RE_SCORE_ROWS = "training.re.score.rows"
 GAUGE_RE_SCORE_UNSLOTTED_ROWS = "training.re.score.unslotted_rows"
 
+# -- gauges of a fit whose fixed effect's matrix is sparse ---------------------
+#: Summed over the fixed-effect coordinates whose matrix came from the
+#: chooser (``ops.features.layout_counts``): the stored values that are not
+#: 0, the slots the chosen layout stores (padding included: slots / nnz is
+#: what every product pays over the true work), and the non-zeros of the
+#: fullest column (how hard the scatter-add's collisions can be).
+GAUGE_FE_NNZ = "training.fe.nnz"
+GAUGE_FE_SLOTS = "training.fe.slots"
+GAUGE_FE_MAX_COL_DEGREE = "training.fe.max_col_degree"
+
 # -- gauges of a fit whose coordinates lie over a device mesh (mesh=) ----------
 GAUGE_MESH_DEVICES = "training.mesh.devices"
 #: Rows of the fixed effect's batch on the fullest device (padding included).
@@ -108,6 +132,13 @@ COUNTER_CD_RUNS = "training.cd.runs"
 #: Runs that started cold (no ``initial_model``, no checkpoint restored):
 #: the runs whose initial scores were built and not computed.
 COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
+#: Per run, the sparse products its fixed-effect solves ran, from the
+#: solvers' own counts: a margin-cached L-BFGS solve of ``it`` iterations is
+#: ``it + 1`` matvec and ``it + 1`` rmatvec (``OptimizerResult.iterations``);
+#: the block's scoring pass is one matvec more a sweep and is not counted
+#: here. 0 where no fixed effect is sparse; a TRON, OWL-QN or bounded solve
+#: (whose iterations are not products) adds nothing.
+COUNTER_FE_PRODUCTS = "training.fe.products"
 #: Per run, the random-effect coordinates built over a mesh: each divides
 #: its score exchange over it (each device gathers the residual into the
 #: slots of its own entities and the margins into its own rows, one

@@ -1,0 +1,468 @@
+"""A sparse fixed effect that is born on the device (PR 35):
+``sparse_rows_to_device`` counts it there, the chooser picks its layout,
+and ``CoordinateDescent.run`` fits it like any other fixed effect."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+from photon_ml_tpu import telemetry
+from photon_ml_tpu.algorithm.coordinate_descent import CoordinateDescent
+from photon_ml_tpu.algorithm.coordinates import FixedEffectCoordinate
+from photon_ml_tpu.data.shard_cache import StreamedFixedEffectData
+from photon_ml_tpu.ops import features as F
+from photon_ml_tpu.ops.glm_objective import GLMBatch
+from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
+from photon_ml_tpu.telemetry import scopes
+from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils.compile_cache import (
+    compile_ledger,
+    enable_compile_cache,
+)
+
+TASK = TaskType.LOGISTIC_REGRESSION
+OPTIMIZER = "2,1e-12,1.0,1.0,LBFGS,L2"
+
+
+@pytest.fixture(autouse=True)
+def _clean_telemetry():
+    telemetry.disable()
+    telemetry.reset()
+    yield
+    telemetry.disable()
+    telemetry.reset()
+
+
+def _prime_below(m: int) -> int:
+    return next(p for p in range(m, 1, -1)
+                if all(p % q for q in range(2, int(p ** 0.5) + 1)))
+
+
+def make_rows(rng, n, k, d, fill=1.0):
+    """``cols[n, k]`` / ``vals[n, k]`` (numpy): each row stores ``fill`` of
+    its slots (the rest padded: value 0 at column 0), its columns distinct
+    (so that scipy, which merges a row's repeated column, holds the same
+    entries); the last stored slot is the intercept's column d - 1, value
+    1; column d - 2 is in no row."""
+    p = _prime_below(d - 2)
+    start, step = rng.integers(0, p, n), rng.integers(1, p, n)
+    cols = (start[:, None] + np.arange(k)[None, :] * step[:, None]) % p
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    vals[vals == 0] = 1.0
+    stored = np.maximum(1, rng.binomial(k, fill, size=n))
+    keep = np.arange(k)[None, :] < stored[:, None]
+    cols[np.arange(n), stored - 1] = d - 1
+    vals[np.arange(n), stored - 1] = 1.0
+    cols = np.where(keep, cols, 0).astype(np.int32)
+    vals = np.where(keep, vals, 0.0).astype(np.float32)
+    return cols, vals
+
+
+def as_scipy(cols, vals, d):
+    n, k = cols.shape
+    rows = np.repeat(np.arange(n), k)
+    return sp.coo_matrix((vals.ravel(), (rows, cols.ravel())),
+                         shape=(n, d)).tocsr()
+
+
+# n, k, d, fill, and the layout the chooser must pick: one index operation
+# a stored slot against two a non-zero
+SHAPES = {
+    "uniform": (27000, 40, 5000, 1.0, "slot_major_ell"),
+    "three_quarters": (6000, 40, 5000, 0.75, "slot_major_ell"),
+    "ragged": (6000, 40, 5000, 0.2, "csr"),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(SHAPES))
+def built(request):
+    n, k, d, fill, layout = SHAPES[request.param]
+    cols, vals = make_rows(np.random.default_rng(7), n, k, d, fill)
+    feats = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
+    return {"layout": layout, "feats": feats, "cols": cols,
+            "vals": vals, "d": d, "mat": as_scipy(cols, vals, d)}
+
+
+def test_the_chooser_decides_at_three_shapes(built):
+    counts = F.layout_counts(built["feats"])
+    assert counts.layout == built["layout"]
+    kinds = {"csr": F.CSRFeatures,
+             "slot_major_ell": F.SlotMajorEllFeatures}
+    assert type(built["feats"]) is kinds[built["layout"]]
+    # what was counted on the device is what the host counts of the triples
+    host = F.layout_counts(F.features_to_device(built["mat"]))
+    assert (host.layout, host.nnz) == (counts.layout, counts.nnz)
+    n, k = built["cols"].shape
+    assert (counts.n_rows, counts.slots_per_row, counts.n_features) == (
+        n, k, built["d"])
+    assert counts.nnz == int((built["vals"] != 0).sum())
+    assert counts.max_col_degree == host.max_col_degree == n  # the intercept
+    assert counts.slots == {"csr": counts.nnz,
+                            "slot_major_ell": n * k}[built["layout"]]
+
+
+@pytest.mark.parametrize("product", ["matvec", "rmatvec", "row_sq_matvec",
+                                     "sq_rmatvec"])
+def test_device_built_products_match_csr_from_scipy(built, product):
+    feats, want = built["feats"], F.csr_from_scipy(built["mat"])
+    n, d = want.shape
+    rng = np.random.default_rng(11)
+    by_row = product in ("matvec", "row_sq_matvec")
+    vec = jnp.asarray(rng.normal(size=d if by_row else n))
+    got = getattr(feats, product)(vec)
+    ref = getattr(want, product)(vec)
+    # float32's: scipy sums a row's repeated column in the values' dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    if not by_row:
+        assert float(got[d - 2]) == 0.0  # the column of degree 0
+        # the intercept's column: every row's u (sq: times 1 squared)
+        assert float(got[d - 1]) == pytest.approx(float(jnp.sum(vec)),
+                                                  rel=1e-9)
+
+
+def test_no_nnz_sized_array_crosses_to_the_host():
+    n, k, d = SHAPES["uniform"][:3]
+    cols, vals = (jnp.asarray(a) for a in make_rows(
+        np.random.default_rng(3), n, k, d))
+    w = jnp.ones((d,), jnp.float32)
+    both = jax.jit(lambda feats, w: feats.rmatvec(feats.matvec(w)))
+    with jax.transfer_guard("disallow"):
+        feats = F.sparse_rows_to_device(cols, vals, d)
+        jax.block_until_ready(both(feats, w))
+    assert F.layout_counts(feats).layout == "slot_major_ell"
+    assert feats.cols.shape == (n * k,) and feats.vals.dtype == vals.dtype
+
+
+def test_a_column_out_of_range_is_refused():
+    cols = jnp.asarray([[0, 5], [1, 2]], jnp.int32)
+    vals = jnp.ones((2, 2), jnp.float32)
+    with pytest.raises(ValueError, match="column ids span"):
+        F.sparse_rows_to_device(cols, vals, 5)
+
+
+def counts(n, k, d, nnz, max_deg):
+    return F.LayoutCounts(n_rows=n, slots_per_row=k, n_features=d, nnz=nnz,
+                          max_col_degree=max_deg)
+
+
+@pytest.mark.parametrize("c, want", [
+    # the cell's own counts: uniform rows, one column in every row
+    (counts(9168123, 40, 1000001, 366724920, 9168123), "slot_major_ell"),
+    # ragged rows: a third of the slots stored
+    (counts(9168123, 40, 1000001, 122241640, 9168123), "csr"),
+    # three quarters of the slots stored: one index operation a slot is
+    # still cheaper than two a non-zero
+    (counts(4000000, 10, 100000, 30000000, 4000000), "slot_major_ell"),
+    # exactly half stored: the two cost the same, the ELL stays
+    (counts(1000, 40, 100000, 20000, 1000), "slot_major_ell"),
+    # one non-zero fewer than half: the flat triplet
+    (counts(1000, 40, 100000, 19999, 1000), "csr"),
+])
+def test_choose_layout(c, want):
+    assert F.choose_layout(c) == want
+
+
+def test_features_to_device_ends_in_the_same_chooser():
+    n, k, d = SHAPES["uniform"][:3]
+    cols, vals = make_rows(np.random.default_rng(5), n, k, d)
+    mat = as_scipy(cols, vals, d)
+    chosen = F.features_to_device(mat)
+    assert isinstance(chosen, F.SlotMajorEllFeatures)
+    assert F.layout_counts(chosen).layout == "slot_major_ell"
+    named = F.features_to_device(mat, sparse_layout="csr")
+    assert isinstance(named, F.CSRFeatures)
+    v = jnp.asarray(np.random.default_rng(6).normal(size=d))
+    np.testing.assert_allclose(np.asarray(chosen.matvec(v)),
+                               np.asarray(named.matvec(v)), rtol=1e-5,
+                               atol=1e-5)
+    ragged = F.features_to_device(as_scipy(*make_rows(
+        np.random.default_rng(5), 500, k, d, fill=0.2), d))
+    assert isinstance(ragged, F.CSRFeatures)
+    assert F.layout_counts(ragged).layout == "csr"
+    assert F.layout_counts(named) is None  # the chooser was not asked
+
+
+# -- the fit through CoordinateDescent.run -------------------------------------
+
+
+@dataclasses.dataclass
+class Problem:
+    """The plain arrays the in-repo reference reads."""
+
+    n_rows: int
+    n_features: int
+    cols: jax.Array
+    vals: jax.Array
+    labels: jax.Array
+    offsets: jax.Array
+    weights: jax.Array
+
+
+CONFIG = {"fixed": {"name": "fixed", "optimizer": OPTIMIZER},
+          "link": "logistic"}
+
+
+@pytest.fixture(scope="module")
+def problem():
+    n, k, d = SHAPES["uniform"][:3]
+    rng = np.random.default_rng(20261002)
+    cols, vals = make_rows(rng, n, k, d)
+    vals = (vals / np.sqrt(k)).astype(np.float32)
+    vals[cols == d - 1] = 1.0
+    w_true = rng.normal(size=d)
+    margin = (vals * w_true[cols]).sum(axis=1)
+    labels = (rng.random(n) < 1 / (1 + np.exp(-margin))).astype(np.float32)
+    return Problem(n, d, jnp.asarray(cols), jnp.asarray(vals),
+                   jnp.asarray(labels),
+                   jnp.asarray(0.1 * rng.normal(size=n), jnp.float32),
+                   jnp.asarray(rng.uniform(0.5, 1.5, size=n), jnp.float32))
+
+
+def descent(p: Problem, feats):
+    batch = GLMBatch(feats, p.labels, p.offsets, p.weights)
+    coord = FixedEffectCoordinate(
+        name="fixed",
+        data=StreamedFixedEffectData("global", batch, p.n_rows,
+                                     p.n_features, {}),
+        feature_shard_id="global", task_type=TASK,
+        config=GLMOptimizationConfiguration.parse(OPTIMIZER))
+    return CoordinateDescent({"fixed": coord}, TASK), coord
+
+
+@pytest.fixture(scope="module")
+def sparse_fit(problem):
+    feats = F.sparse_rows_to_device(problem.cols, problem.vals,
+                                    problem.n_features)
+    cd, coord = descent(problem, feats)
+    result = cd.run(1, seed=1)
+    model = result.model.get_model("fixed")
+    return {"cd": cd, "coord": coord, "result": result,
+            "w": np.asarray(model.glm.coefficients.means),
+            "scores": np.asarray(coord.score(model))}
+
+
+def test_fit_matches_the_in_repo_reference(problem, sparse_fit):
+    from benchmark.reference import sparse_glm
+
+    ref = sparse_glm.fit(problem, CONFIG)
+    assert ref["stopped"] is None and len(ref["values"]) == 3
+    w_ref = np.asarray(ref["coefs"]["fixed"])
+    w = sparse_fit["w"]
+    assert np.linalg.norm(w - w_ref) / np.linalg.norm(w_ref) < 1e-4
+    history = sparse_fit["result"].objective_history
+    assert len(history) == 1
+    assert history[-1] == pytest.approx(ref["values"][-1], rel=1e-5)
+    # the program's objective and scores at ITS coefficients
+    assert history[-1] == pytest.approx(
+        sparse_glm.value(problem, CONFIG, w), rel=1e-5)
+    own = np.asarray(sparse_glm.scores_of(problem, CONFIG, {"fixed": w}))
+    assert (np.sqrt(np.mean((sparse_fit["scores"] - own) ** 2)
+                    / np.mean(own ** 2)) < 1e-6)
+    tracker = sparse_fit["result"].trackers["fixed"][0]
+    assert int(np.asarray(tracker.iterations)) == 2  # the cap ends it
+
+
+def test_fit_matches_the_same_data_densified(problem, sparse_fit):
+    rows = jnp.arange(problem.n_rows)[:, None]
+    dense = F.DenseFeatures(jnp.zeros(
+        (problem.n_rows, problem.n_features), jnp.float32).at[
+            rows, problem.cols].add(problem.vals))
+    cd, coord = descent(problem, dense)
+    result = cd.run(1, seed=1)
+    model = result.model.get_model("fixed")
+    w = np.asarray(model.glm.coefficients.means)
+    assert (np.linalg.norm(sparse_fit["w"] - w) / np.linalg.norm(w)) < 1e-5
+    assert result.objective_history[-1] == pytest.approx(
+        sparse_fit["result"].objective_history[-1], rel=1e-6)
+    np.testing.assert_allclose(sparse_fit["scores"],
+                               np.asarray(coord.score(model)), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_the_product_scopes_are_in_the_lowered_block(sparse_fit):
+    cd = sparse_fit["cd"]
+    fn = cd._fused_block_fn(1)
+    seen = {}
+
+    def recorder(*args):
+        seen["args"] = args
+        return fn(*args)
+
+    cd._block_fns[1] = recorder
+    cd.run(1)
+    cd._block_fns[1] = fn
+    lowered = fn.lower(*seen["args"])
+    text = lowered.as_text(debug_info=True)
+    for scope in scopes.FE_PRODUCT_SCOPES:
+        assert f"{scope}/" in text or f'/{scope}"' in text, scope
+    # in the compiled operations' name paths each product sits under the
+    # scope that ran it: both under the solve, the matvec under the score
+    import re
+
+    paths = [p.split("/") for p in re.findall(
+        r'op_name="([^"]*)"', lowered.compile().as_text())]
+
+    def nested(parent, child):
+        return any(parent in p and child in p
+                   and p.index(parent) < p.index(child) for p in paths)
+
+    assert nested(scopes.FE_SOLVE, scopes.FE_MATVEC)
+    assert nested(scopes.FE_SOLVE, scopes.FE_RMATVEC)
+    assert nested(scopes.FE_SCORE, scopes.FE_MATVEC)
+    assert not nested(scopes.FE_SCORE, scopes.FE_RMATVEC)
+
+
+def test_a_dense_fit_opens_no_product_scope(problem):
+    dense = F.DenseFeatures(jnp.zeros((64, 8), jnp.float32))
+    small = Problem(64, 8, None, None, jnp.zeros((64,), jnp.float32),
+                    jnp.zeros((64,), jnp.float32),
+                    jnp.ones((64,), jnp.float32))
+    cd, _ = descent(small, dense)
+    fn = cd._fused_block_fn(1)
+    seen = {}
+    cd._block_fns[1] = lambda *a: (seen.update(args=a), fn(*a))[1]
+    cd.run(1)
+    text = fn.lower(*seen["args"]).as_text(debug_info=True)
+    assert scopes.FE_SOLVE in text
+    assert scopes.FE_MATVEC not in text and scopes.FE_RMATVEC not in text
+
+
+def test_layout_scope_is_in_the_construction_programs():
+    cols = jnp.zeros((4, 2), jnp.int32)
+    vals = jnp.ones((4, 2), jnp.float32)
+    for text in (
+            F._count_rows.lower(cols, vals, n_features=3).as_text(
+                debug_info=True),
+            F._slot_major.lower(cols).as_text(debug_info=True)):
+        assert scopes.FE_LAYOUT in text
+
+
+def test_gauges_counter_and_ledger_row(problem):
+    enable_compile_cache()  # the ledger listens from here
+    telemetry.enable()
+    feats = F.sparse_rows_to_device(problem.cols, problem.vals,
+                                    problem.n_features)
+    cd, _ = descent(problem, feats)
+    gauges = telemetry.snapshot()["gauges"]
+    n, k = problem.cols.shape
+    nnz = int((np.asarray(problem.vals) != 0).sum())
+    assert gauges[scopes.GAUGE_FE_NNZ] == nnz
+    assert gauges[scopes.GAUGE_FE_SLOTS] == n * k
+    assert gauges[scopes.GAUGE_FE_MAX_COL_DEGREE] == n
+    cd.run(1)
+    counters = telemetry.snapshot()["counters"]
+    # a solve of 2 iterations: 3 matvec and 3 rmatvec
+    assert counters[scopes.COUNTER_FE_PRODUCTS] == 6
+    row = compile_ledger()["functions"][scopes.CD_BLOCK]
+    assert row["fe_layout"] == "slot_major_ell"
+
+
+# -- the host path's consumers take the layout the chooser picks ---------------
+
+
+def uniform_shard(rng, n, k, d):
+    """A scipy shard whose rows all store ``k`` of ``d`` columns (density
+    under the dense threshold): the chooser lays it slot-major."""
+    cols, vals = make_rows(rng, n, k, d)
+    return as_scipy(cols, vals, d)
+
+
+def test_counts_ride_through_jit_tree_map_replace_and_shard_batch(built):
+    from photon_ml_tpu.parallel import make_mesh, shard_batch
+
+    feats = built["feats"]
+    counts = F.layout_counts(feats)
+    assert counts is not None
+    assert F.layout_counts(jax.jit(lambda f: f)(feats)) == counts
+    assert F.layout_counts(jax.tree_util.tree_map(lambda a: a, feats)
+                           ) == counts
+    kept = dataclasses.replace(feats, n_features=feats.n_features)
+    assert F.layout_counts(kept) == counts
+    n = feats.n_rows
+    batch = GLMBatch(feats, jnp.zeros((n,)), jnp.zeros((n,)), jnp.ones((n,)))
+    sharded = shard_batch(batch, make_mesh(4))
+    assert F.layout_counts(sharded.features) == counts
+    v = jnp.asarray(np.random.default_rng(2).normal(size=built["d"]))
+    np.testing.assert_allclose(
+        np.asarray(sharded.features.matvec(v))[:n],
+        np.asarray(feats.matvec(v)), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("feature_sharding", [False, True],
+                         ids=["rows", "columns"])
+def test_a_mesh_fit_takes_a_shard_the_chooser_lays_slot_major(
+        feature_sharding):
+    from photon_ml_tpu.data.game_data import GameDataset
+    from photon_ml_tpu.parallel import make_mesh
+
+    rng = np.random.default_rng(35)
+    n, k, d = 403, 6, 64  # 403: the mesh pads the rows
+    mat = uniform_shard(rng, n, k, d)
+    w_true = rng.normal(size=d)
+    labels = (rng.random(n) < 1 / (1 + np.exp(-(mat @ w_true)))).astype(
+        float)
+    data = GameDataset.build(responses=labels,
+                             feature_shards={"global": mat}, ids={})
+    assert isinstance(data.fixed_effect_batch("global").features,
+                      F.SlotMajorEllFeatures)
+
+    def fit(**kw):
+        coord = FixedEffectCoordinate(
+            name="fixed", data=data, feature_shard_id="global",
+            task_type=TASK, dtype=jnp.float64,
+            config=GLMOptimizationConfiguration.parse(
+                "20,1e-9,1.0,1.0,LBFGS,L2"), **kw)
+        result = CoordinateDescent({"fixed": coord}, TASK).run(1, seed=1)
+        model = result.model.get_model("fixed")
+        return (coord, np.asarray(model.glm.coefficients.means),
+                np.asarray(coord.score(model)))
+
+    one, w_one, s_one = fit()
+    assert one.sparse_work()[0].layout == "slot_major_ell"
+    over, w_mesh, s_mesh = fit(mesh=make_mesh(4),
+                               feature_sharding=feature_sharding)
+    np.testing.assert_allclose(w_mesh, w_one, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(s_mesh, s_one, rtol=1e-5, atol=1e-6)
+
+
+def test_the_device_scorer_takes_shards_the_chooser_lays_slot_major():
+    from photon_ml_tpu.data.game_data import GameDataset
+    from photon_ml_tpu.data.random_effect import (
+        RandomEffectDataConfiguration,
+        build_random_effect_dataset,
+    )
+    from photon_ml_tpu.models import (
+        Coefficients,
+        FixedEffectModel,
+        GameModel,
+        LogisticRegressionModel,
+        RandomEffectModel,
+    )
+    from photon_ml_tpu.models.device_scoring import DeviceGameScorer
+
+    rng = np.random.default_rng(36)
+    n, d, d_user = 120, 64, 32
+    data = GameDataset.build(
+        responses=(rng.random(n) < 0.5).astype(float),
+        feature_shards={"global": uniform_shard(rng, n, 6, d),
+                        "user": uniform_shard(rng, n, 3, d_user)},
+        ids={"userId": rng.integers(0, 7, n).astype(str)})
+    for shard in ("global", "user"):
+        assert isinstance(F.features_to_device(data.feature_shards[shard]),
+                          F.SlotMajorEllFeatures)
+    fe = FixedEffectModel(LogisticRegressionModel(Coefficients(
+        jnp.asarray(rng.normal(size=d)))), "global")
+    ds = build_random_effect_dataset(
+        data, RandomEffectDataConfiguration("userId", "user"),
+        intercept_col=d_user - 1)
+    re = RandomEffectModel.zeros_like_dataset(ds, dtype=jnp.float64)
+    re = re.with_coefs([jnp.asarray(rng.normal(size=np.asarray(c).shape))
+                        for c in re.local_coefs])
+    gm = GameModel({"fixed": fe, "perUser": re}, TASK)
+    got = np.asarray(DeviceGameScorer(gm, data, dtype=jnp.float64).score(gm))
+    np.testing.assert_allclose(got, gm.score(data), rtol=1e-10, atol=1e-10)
