@@ -4,6 +4,7 @@ on the cell's own data at its own size (PR 35; PERF.md section 5's per-index
 table came from this):
 
     chiprun -- python dev_scripts/sparse_products_probe.py [rows] [csr]
+    chiprun -- python dev_scripts/sparse_products_probe.py [rows] codes [widths]
 
 Each form of ``X.w`` / ``X^T.u`` (over ``[n, k]`` arrays, over ``[k, n]``,
 one flat operation, and the slot-major loops ``SlotMajorEllFeatures`` runs)
@@ -14,7 +15,11 @@ two functions here before it is added to the program. With ``csr`` only
 the program's two layouts are timed, side by side on the same data: the
 flat triplet ``choose_layout`` weighs the slot-major ELL against
 (``CSRFeatures``: a gather and a segment-sum a non-zero) and the ELL
-itself, into ``chiprun_out/probe_csr.json``.
+itself, into ``chiprun_out/probe_csr.json``. With ``codes`` (PR 36) ONE
+slot of n rows is read by gather from ``f32[d]`` and, through a code a row
+and a table of V entries, by every candidate form of ``code_forms``, for V
+in ``CODE_WIDTHS``: the table ``ops.features.CODED_SLOT_WIDTH`` was set
+from (``chiprun_out/probe_codes.json``; docs/SCALE.md has the law).
 """
 import functools
 import json
@@ -66,6 +71,185 @@ def V_rmv_flat(cols, vals, u, d):
     k = cols.shape[0] // n
     return jnp.zeros((d,), jnp.float32).at[cols].add(
         vals * jnp.tile(u, k), mode="promise_in_bounds")
+
+
+
+# -- one slot read through codes (PR 36) ---------------------------------------
+# Every form is ``fn(out, x, idx, table) -> out + x * table[idx]``: what one
+# step of ``SlotMajorEllFeatures._by_row`` adds. ``idx`` is the slot's column
+# ids (``gather_d``: the table is all of w) or its codes (the table is the
+# slot's V dictionary entries of w).
+CODE_WIDTHS = (1, 3, 64, 128, 256, 512, 1024, 2048, 4096)
+LANES = 128
+PALLAS_ROWS = 2048  # rows of 128 codes a grid step, at most
+
+
+def C_gather(out, x, idx, table):
+    return out + x * table.at[idx].get(mode="promise_in_bounds")
+
+
+def C_chain(out, x, code, table):
+    """One compare and one select an entry, unrolled: one elementwise
+    fusion, nothing of size [V, n]."""
+    t = jnp.zeros(out.shape, table.dtype)
+    for v in range(table.shape[0]):
+        t = jnp.where(code == v, table[v], t)
+    return out + x * t
+
+
+def C_chain_chunked(out, x, code, table):
+    """The chain in a loop of 32 unrolled entries a step (64 a step is
+    slower): the text stays small at any V, at one more pass over an
+    n-vector a step."""
+    def body(i, t):
+        for j in range(32):
+            v = i * 32 + j
+            t = jnp.where(code == v.astype(code.dtype), table[v], t)
+        return t
+    t = lax.fori_loop(0, table.shape[0] // 32, body,
+                      jnp.zeros(out.shape, table.dtype))
+    return out + x * t
+
+
+def C_program(out, x, code, table):
+    """The form the program runs (``ops.features._select``): a loop over
+    chunks of the table, inside a chunk a tree of selects on the code's low
+    bits, then one compare of its high bits and one select."""
+    from photon_ml_tpu.ops.features import _select
+
+    return out + x * _select(code, table)
+
+
+def C_tree(out, x, code, table):
+    """V - 1 selects on the code's bits, lowest first."""
+    level = [table[v] for v in range(table.shape[0])]
+    bit = 0
+    while len(level) > 1:
+        m = ((code >> bit) & 1) != 0
+        level = [jnp.where(m, level[i + 1], level[i])
+                 for i in range(0, len(level), 2)]
+        bit += 1
+    return out + x * level[0]
+
+
+def C_reduce(out, x, code, table):
+    """``sum_v where(code == v, table[v], 0)``: XLA's own reduce over V."""
+    v = jnp.arange(table.shape[0], dtype=code.dtype)
+    t = jnp.sum(jnp.where(code[None, :] == v[:, None], table[:, None], 0.0),
+                axis=0)
+    return out + x * t
+
+
+def C_onehot(out, x, code, table):
+    """A one-hot row times the table on the MXU, exact at ``highest``."""
+    hot = jax.nn.one_hot(code, table.shape[0], dtype=table.dtype)
+    return out + x * jnp.dot(hot, table, precision="highest")
+
+
+def _lane_gather_kernel(code_ref, table_ref, t_ref):
+    code = code_ref[...].astype(jnp.int32)
+    groups = table_ref.shape[0]
+    t = jnp.zeros(code.shape, jnp.float32)
+    for g in range(groups):
+        row = jnp.broadcast_to(table_ref[g:g + 1, :], code.shape)
+        got = jnp.take_along_axis(row, code & (LANES - 1), axis=1)
+        t = got if groups == 1 else jnp.where(code >> 7 == g, got, t)
+    t_ref[...] = t
+
+
+def C_pallas(out, x, code2d, table):
+    """The lane-local dynamic gather of the TPU's vector unit: the table
+    lies along the 128 lanes (one row a group of 128 entries), the codes
+    ``[rows, 128]``; one gather a vector register and group."""
+    from jax.experimental import pallas as pl
+
+    groups = -(-table.shape[0] // LANES)
+    tab = jnp.pad(table, (0, groups * LANES - table.shape[0])).reshape(
+        groups, LANES)
+    rows = code2d.shape[0]
+    step = PALLAS_ROWS // max(1, groups // 8)  # wide tables: smaller blocks
+    t = pl.pallas_call(
+        _lane_gather_kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        grid=(rows // step,),
+        in_specs=[pl.BlockSpec((step, LANES), lambda i: (i, 0)),
+                  pl.BlockSpec((groups, LANES), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((step, LANES), lambda i: (i, 0)),
+        interpret=jax.default_backend() != "tpu",
+    )(code2d, tab)
+    return out + x * t.reshape(-1)[:out.shape[0]]
+
+
+def code_forms(v: int) -> dict:
+    """``{name: (function, the codes' dtype)}``: the candidates at width
+    ``v`` (the unrolled forms only where their text stays compilable, the
+    MXU form only where its ``[n, V]`` operand could fit)."""
+    narrow = jnp.uint8 if v <= 256 else jnp.uint16
+    forms = {"gather_v_i32": (C_gather, jnp.int32),
+             "pallas_i32": (C_pallas, jnp.int32),
+             "pallas_narrow": (C_pallas, narrow)}
+    if v <= 256:  # at 1,024 the unrolled text compiles for 13-22 s
+        forms["chain_narrow"] = (C_chain, narrow)
+        forms["chain_i32"] = (C_chain, jnp.int32)
+    if v >= 64:
+        forms["chain_chunked_i32"] = (C_chain_chunked, jnp.int32)
+        forms["chain_chunked_narrow"] = (C_chain_chunked, narrow)
+        forms["program_narrow"] = (C_program, narrow)
+    if 2 <= v <= 256 and v & (v - 1) == 0:
+        forms["tree_i32"] = (C_tree, jnp.int32)
+    if v <= 256:
+        forms["reduce_i32"] = (C_reduce, jnp.int32)
+        forms["reduce_narrow"] = (C_reduce, narrow)
+    if v <= 128:
+        forms["onehot_i32"] = (C_onehot, jnp.int32)
+    return forms
+
+
+def code_form_shapes(name, n, v, code_dt, shape_of):
+    """The arguments of ``code_forms(v)[name]`` as shapes (``shape_of(shape,
+    dtype)``: a ``ShapeDtypeStruct`` for a described chip, or an array)."""
+    rows = -(-n // (LANES * PALLAS_ROWS)) * PALLAS_ROWS
+    idx = (rows, LANES) if name.startswith("pallas") else (n,)
+    return (shape_of((n,), jnp.float32), shape_of((n,), jnp.float32),
+            shape_of(idx, code_dt), shape_of((v,), jnp.float32))
+
+
+def coded_slot(rows: int, widths=CODE_WIDTHS) -> dict:
+    """The gather-against-code table: one slot of ``rows`` rows, ms a slot
+    and ns a row for every form and width, and whether the result is
+    bitwise the gather's."""
+    n, d = rows, 1000001
+    out = {"n": n, "d": d, "widths": {}}
+    key = jax.random.PRNGKey(2147486601)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (d,), jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 2), (n,), jnp.float32)
+    acc = jax.random.normal(jax.random.fold_in(key, 3), (n,), jnp.float32)
+    for v in widths:
+        row = out["widths"][str(v)] = {}
+        dictionary = jnp.sort(jax.random.choice(
+            jax.random.fold_in(key, 10 + v), d, (v,), replace=False)
+        ).astype(jnp.int32)
+        code = jax.random.randint(jax.random.fold_in(key, 20 + v), (n,), 0, v,
+                                  jnp.int32)
+        cols = dictionary[code]
+        table = w[dictionary]
+        ref = timed(row, "gather_d", jax.jit(C_gather), (acc, x, cols, w), n)
+        for name, (fn, code_dt) in code_forms(v).items():
+            idx = code.astype(code_dt)
+            if name.startswith("pallas"):
+                shape = code_form_shapes(name, n, v, code_dt,
+                                         lambda s, _: s)[2]
+                idx = jnp.pad(idx, (0, shape[0] * LANES - n)).reshape(shape)
+            try:
+                timed(row, name, jax.jit(fn), (acc, x, idx, table), n, ref)
+            except Exception as e:  # a form the compiler refuses: recorded
+                row[name] = {"failed": f"{type(e).__name__}: {e}"[:400]}
+                print(name, json.dumps(row[name]), flush=True)
+    out["device"] = str(jax.local_devices()[0].device_kind)
+    (ROOT / "chiprun_out").mkdir(exist_ok=True)
+    (ROOT / "chiprun_out/probe_codes.json").write_text(
+        json.dumps(out, indent=1))
+    return out
 
 
 _transpose = jax.jit(lambda a: a.T)
@@ -178,5 +362,9 @@ def main(rows: int) -> dict:
 
 if __name__ == "__main__":
     rows = int(sys.argv[1]) if len(sys.argv) > 1 else 9168123
-    print(json.dumps(program_layouts(rows) if "csr" in sys.argv[2:]
-                     else main(rows)))
+    mode = sys.argv[2] if len(sys.argv) > 2 else ""
+    if mode == "codes" and len(sys.argv) > 3:  # ... codes 128,1024
+        coded_slot(rows, tuple(int(v) for v in sys.argv[3].split(",")))
+    else:
+        print(json.dumps({"csr": program_layouts, "codes": coded_slot}.get(
+            mode, main)(rows)))

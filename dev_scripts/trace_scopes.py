@@ -333,12 +333,16 @@ def place(path: str) -> dict:
     ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
     ``photon.re.solve``; ``product``: the sparse product
     (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
-    ``<leaf>/<product>``; ``scoped``: under any ``photon.*`` at all."""
-    leaf = coordinate = size_class = product = None
+    ``<leaf>/<product>``; ``part``: the matvec's coded or gathered slots
+    (PR 36), as ``<leaf>/<product>/<part>``; ``scoped``: under any
+    ``photon.*`` at all."""
+    leaf = coordinate = size_class = product = piece = None
     parts = path.split("/")
     for i, part in enumerate(parts):
         if part in scopes.FE_PRODUCT_SCOPES and leaf:
             product = f"{leaf}/{part}"
+        elif part in scopes.FE_MATVEC_PARTS and product:
+            piece = f"{product}/{part}"
         elif part in scopes.DEVICE_SCOPES:
             leaf = part
             if (part == scopes.RE_SOLVE and i + 1 < len(parts)
@@ -347,7 +351,7 @@ def place(path: str) -> dict:
         elif part.startswith(scopes.cd_coordinate("")):
             coordinate = part
     return {"leaf": leaf, "coordinate": coordinate, "size_class": size_class,
-            "product": product,
+            "product": product, "part": piece,
             "scoped": leaf is not None or coordinate is not None}
 
 
@@ -408,8 +412,9 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
         scoped.append(iv)
         if where["leaf"]:
             by_leaf.setdefault(where["leaf"], []).append(iv)
-        if where["product"]:
-            by_product.setdefault(where["product"], []).append(iv)
+        for key in (where["product"], where["part"]):
+            if key:
+                by_product.setdefault(key, []).append(iv)
         if where["coordinate"]:
             by_coord.setdefault(where["coordinate"], []).append(iv)
         if where["size_class"]:
@@ -555,10 +560,12 @@ def print_report(result: dict, out=sys.stdout) -> None:
             mine = {c: v for c, v in block.get("product_ms", {}).items()
                     if c.startswith(s + "/")}
             for c, v in mine.items():
-                print(f"| &nbsp;&nbsp;`{c[len(s) + 1:]}` | {v:.3f} | "
+                indent = "&nbsp;&nbsp;" * c.count("/")
+                print(f"| {indent}`{c.rsplit('/', 1)[1]}` | {v:.3f} | "
                       f"{100 * v / busy:.2f}% |", file=out)
             if mine:
-                rest = ms - sum(mine.values())
+                rest = ms - sum(v for c, v in mine.items()
+                                if c.count("/") == 1)
                 print(f"| &nbsp;&nbsp;the rest of `{s}` (d-space, "
                       f"n-vectors) | {rest:.3f} | {100 * rest / busy:.2f}% |",
                       file=out)

@@ -186,6 +186,8 @@ class CoordinateDescent:
                 sum(c.slots for c in sparse))
             telemetry.gauge(scopes.GAUGE_FE_MAX_COL_DEGREE).set(
                 max(c.max_col_degree for c in sparse))
+            telemetry.gauge(scopes.GAUGE_FE_CODED_SLOTS).set(
+                sum(c.coded_slots for c in sparse))
         routed = [c for c in self.coordinates.values()
                   if hasattr(c, "routing")]
         buckets = [b for c in routed for b in c.routing()]
@@ -332,7 +334,8 @@ class CoordinateDescent:
             from photon_ml_tpu.utils.compile_cache import note_layout
 
             note_layout(scopes.CD_BLOCK, ",".join(sorted(
-                {c.layout for c in sparse})))
+                {c.layout for c in sparse})),
+                sum(c.coded_slots for c in sparse))
         self._block_fns[cache_key] = fn
         self.tracing_guard.track(
             f"block:{n_iters}" if whole

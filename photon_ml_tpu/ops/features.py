@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Optional, Tuple, Union
 
 import jax
@@ -951,6 +952,65 @@ def sort_permute_ell_from_scipy(mat, max_groups: int = 8,
 
 
 
+#: The most distinct columns a slot of a slot-major ELL may name and still
+#: be read by code (``SlotMajorEllFeatures``, coded slots). Measured on a
+#: TPU v5e, one slot of 9,168,123 rows (PERF.md section 5, PR 36;
+#: ``dev_scripts/sparse_products_probe.py <rows> codes`` reads it again): by
+#: gather from ``f32[1000001]`` 61.9 ms; by ``_select`` over a table of 128
+#: entries 1.4 ms, of 512 2.4, of 1,024 4.0, of 2,048 7.0, of 4,096 12.9
+#: (1.0 ms of passes over n-vectors + 2.9 us an entry), bitwise the
+#: gather's result. A coded slot pays for the WIDTH, not for the columns
+#: it names: its dictionary is padded to it, so that the compiled products
+#: depend on which slots are coded and never on their counts. 1,024 is
+#: where the click log the cell ``sparse-lr.fit`` is shaped after stops
+#: gaining: its fields of 583, 305 and 633 values join the 22 slots of at
+#: most 128 (25 x 4.0 + 15 x 61.9 = 1,029 ms a product; at 128: 22 x 1.4 +
+#: 18 x 61.9 = 1,145; at 2,048 one field more, 1,048).
+CODED_SLOT_WIDTH = 1024
+_CODE_DTYPE = jnp.uint16  # the narrowest that holds 0 .. CODED_SLOT_WIDTH - 1
+_CODE_ALIGN = 4096  # codes a slot start on a tile boundary of the narrow type
+_SELECT_CHUNK = 32  # table entries a step of ``_select``; 64 is no faster
+
+
+def _runs(coded: Tuple[int, ...], k: int):
+    """The k slots in slot order as runs of one kind: ``(first, stop, at)``,
+    ``at`` the place in ``coded`` of a coded run's first slot, None for a
+    run of gathered slots."""
+    at = {s: j for j, s in enumerate(coded)}
+    for is_coded, run in itertools.groupby(range(k), key=at.__contains__):
+        run = list(run)
+        yield run[0], run[-1] + 1, at[run[0]] if is_coded else None
+
+
+def _code_stride(n_rows: int) -> int:
+    """Where the next coded slot's codes begin: ``n_rows`` rounded up."""
+    return -(-n_rows // _CODE_ALIGN) * _CODE_ALIGN
+
+
+@jax.checkpoint
+def _select(code: Array, table: Array) -> Array:
+    """``table[code]`` without an index operation, ``_SELECT_CHUNK`` table
+    entries a step of a loop: inside a chunk one select an entry on the
+    code's low bits (a tree: 31 for 32 entries), then one compare of its
+    high bits and one select. Every step is one elementwise fusion; nothing
+    of size ``[len(table), n]`` exists. Exact: the entry selected, or 0 for
+    a code past the table. Linear in ``table``; differentiated, it
+    recomputes its compares and keeps none."""
+    bits = _SELECT_CHUNK.bit_length() - 1
+    low = [((code >> b) & 1) != 0 for b in range(bits)]
+    high = code >> bits
+
+    def step(i, out):
+        level = [table[i * _SELECT_CHUNK + j] for j in range(_SELECT_CHUNK)]
+        for bit in low:
+            level = [jnp.where(bit, level[a + 1], level[a])
+                     for a in range(0, len(level), 2)]
+        return jnp.where(high == i.astype(code.dtype), level[0], out)
+
+    return lax.fori_loop(0, table.shape[0] // _SELECT_CHUNK, step,
+                         jnp.zeros(code.shape, table.dtype))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class SlotMajorEllFeatures:
@@ -968,7 +1028,20 @@ class SlotMajorEllFeatures:
     products over ``[n, k]`` arrays first copy both into a layout padded
     from k to 128 lanes (9.4 GB at that size). Columns that collide cost the
     scatter-add 1% at that log's skew (30% if EVERY update lands on one
-    address), so no column is treated apart.
+    address), so the scatter side treats no column apart.
+
+    **Coded slots** (PR 36; the row-wise products only). Where rows come
+    field by field, a slot names few columns: the intercept's one, a binned
+    field's 64. Such a slot (at most ``CODED_SLOT_WIDTH`` distinct columns
+    over ALL rows, counted where the matrix is built) also carries a code a
+    row and a dictionary of its columns, and ``matvec`` / ``row_sq_matvec``
+    read it as ``select(code, v[dictionary])``: lane-wise compares against
+    the slot's few table entries in place of n gathers from ``v``, and the
+    number selected is the number the gather fetches. Slots are visited in
+    slot order, coded or not, so a row's sum has the same terms in the same
+    order; a matrix with no such slot runs the one loop it ran before.
+    ``cols`` and ``vals`` stay whole: the column-wise products, ``to_csr``
+    and everything that unrolls the layout read them and ignore the codes.
     """
 
     cols: Array  # i32[k * n], slot-major
@@ -977,6 +1050,13 @@ class SlotMajorEllFeatures:
     n_features: int
     # what the chooser counted, where it built this matrix (static, aux data)
     counts: Optional["LayoutCounts"] = None
+    # the coded slots' codes, slot after slot in the order of ``coded``, each
+    # slot's n codes at a stride of ``_code_stride(n)``; their dictionaries
+    # i32[len(coded), CODED_SLOT_WIDTH] (ascending, padded with column 0);
+    # and which slots they are (ascending; static, aux data)
+    codes: Optional[Array] = None
+    dicts: Optional[Array] = None
+    coded: Tuple[int, ...] = ()
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -998,14 +1078,38 @@ class SlotMajorEllFeatures:
 
     def _by_row(self, v: Array, square: bool) -> Array:
         acc = jnp.promote_types(v.dtype, jnp.float32)
+        n, k = self.n_rows, self.slots_per_row
 
-        def body(s, out):
+        def gathered(s, out):
             c, x = self._slot(s, square, acc)
             # in bounds by construction (checked where the matrix is built)
             return out + x * v.at[c].get(mode="promise_in_bounds")
 
-        return lax.fori_loop(0, self.slots_per_row, body,
-                             jnp.zeros((self.n_rows,), acc))
+        out = jnp.zeros((n,), acc)
+        if not self.coded:
+            return lax.fori_loop(0, k, gathered, out)
+        # every coded slot's table entries: len(coded) * WIDTH gathers
+        tables = v.at[self.dicts].get(mode="promise_in_bounds")
+        stride = _code_stride(n)
+        # A loop a run of like slots, in slot order. (One loop over all
+        # slots with a ``cond`` a slot compiles to a ninth of the text, but
+        # a gather inside a conditional's branch reads its indices from
+        # HBM, 108 ms a slot against 61.9: PERF.md section 6, PR 36.)
+        for first, stop, at in _runs(self.coded, k):
+            if at is None:
+                with jax.named_scope(scopes.FE_MATVEC_GATHERED):
+                    out = lax.fori_loop(first, stop, gathered, out)
+                continue
+
+            def by_code(s, out, shift=first - at):
+                _, x = self._slot(s, square, acc)
+                j = s - shift
+                code = lax.dynamic_slice(self.codes, (j * stride,), (n,))
+                return out + x * _select(code, tables[j])
+
+            with jax.named_scope(scopes.FE_MATVEC_CODED):
+                out = lax.fori_loop(first, stop, by_code, out)
+        return out
 
     def _by_column(self, u: Array, square: bool) -> Array:
         acc = jnp.promote_types(u.dtype, jnp.float32)
@@ -1044,12 +1148,15 @@ class SlotMajorEllFeatures:
                            self.n_features, self.counts)
 
     def tree_flatten(self):
-        return (self.cols, self.vals), (self.n_rows, self.n_features,
-                                        self.counts)
+        return ((self.cols, self.vals, self.codes, self.dicts),
+                (self.n_rows, self.n_features, self.counts, self.coded))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
+        cols, vals, codes, dicts = children
+        n_rows, n_features, counts, coded = aux
+        return cls(cols, vals, n_rows, n_features, counts, codes, dicts,
+                   coded)
 
 
 FeatureMatrix = Union[DenseFeatures, CSRFeatures, BlockedCSRFeatures,
@@ -1073,6 +1180,7 @@ class LayoutCounts:
     max_col_degree: int  # non-zeros of the fullest column
     layout: str = ""  # the layout chosen
     slots: int = 0  # the slots that layout stores
+    coded_slots: int = 0  # of the k slots a row, those read by code
 
 
 def choose_layout(counts: LayoutCounts) -> str:
@@ -1106,6 +1214,93 @@ def _count_rows(cols, vals, n_features: int):
 @jax.named_scope(scopes.FE_LAYOUT)
 def _slot_major(a):
     return a.T.reshape(-1)
+
+
+def _prefix_sums(x):
+    """Inclusive prefix sums of a long vector, as ``cumsum`` along rows of
+    1,024 and over the rows' totals: the TPU's compiler takes 19 s over
+    ``jnp.cumsum`` of ``i32[1000001]`` and 0.4 s over this."""
+    side = 1024
+    m = x.shape[0]
+    if m <= side:
+        return jnp.cumsum(x)
+    rows = -(-m // side)
+    within = jnp.cumsum(
+        jnp.pad(x, (0, rows * side - m)).reshape(rows, side), axis=1)
+    totals = within[:, -1]
+    return (within + (_prefix_sums(totals) - totals)[:, None]).reshape(-1)[:m]
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_features"))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _slot_dictionaries(cols, n_rows: int, n_features: int):
+    """Of slot-major ``cols``, slot by slot: the distinct columns over all
+    rows (exact; a padded slot's column 0 counts) and the first
+    ``CODED_SLOT_WIDTH`` of them ascending, padded with column 0: ``i32[k]``
+    and ``i32[k, CODED_SLOT_WIDTH]``. One scatter of n a slot."""
+    k = cols.shape[0] // n_rows
+    want = jnp.arange(1, CODED_SLOT_WIDTH + 1, dtype=jnp.int32)
+
+    def one(s, carry):
+        distinct, dicts = carry
+        c = lax.dynamic_slice(cols, (s * n_rows,), (n_rows,))
+        upto = _prefix_sums(
+            jnp.zeros((n_features,), jnp.int32).at[c].set(1))
+        first = jnp.where(want <= upto[-1], jnp.searchsorted(upto, want), 0)
+        return (distinct.at[s].set(upto[-1]),
+                dicts.at[s].set(first.astype(jnp.int32)))
+
+    return lax.fori_loop(0, k, one, (
+        jnp.zeros((k,), jnp.int32),
+        jnp.zeros((k, CODED_SLOT_WIDTH), jnp.int32)))
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_features"))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _slot_codes(cols, dicts, distinct, coded, n_rows: int, n_features: int):
+    """Row by row the place of the row's column in its slot's dictionary,
+    for the slots ``coded`` (``i32[m]``), and those slots' dictionaries:
+    one gather of n a coded slot. The program depends on how many slots
+    are coded, not on which or on their counts."""
+    stride = _code_stride(n_rows)
+    place = jnp.arange(CODED_SLOT_WIDTH, dtype=jnp.int32)
+
+    def one(j, codes):
+        s = coded[j]
+        c = lax.dynamic_slice(cols, (s * n_rows,), (n_rows,))
+        # the padding of a dictionary is no entry: dropped, past the table
+        at = jnp.where(place < distinct[s], dicts[s], n_features)
+        rank = jnp.zeros((n_features,), jnp.int32).at[at].set(
+            place, mode="drop")
+        return lax.dynamic_update_slice(
+            codes, rank[c].astype(_CODE_DTYPE), (j * stride,))
+
+    codes = lax.fori_loop(0, coded.shape[0], one, jnp.zeros(
+        (coded.shape[0] * stride,), _CODE_DTYPE))
+    return codes, dicts[coded]
+
+
+def _slot_major_ell(cols, vals, counts: "LayoutCounts"
+                    ) -> SlotMajorEllFeatures:
+    """The slot-major arrays as the matrix the program runs, its small
+    slots coded: which are small is counted here, on the device, of which
+    k numbers come back."""
+    n, k, d = counts.n_rows, counts.slots_per_row, counts.n_features
+    counts = dataclasses.replace(counts, layout="slot_major_ell",
+                                 slots=n * k)
+    if n * k == 0:
+        return SlotMajorEllFeatures(cols, vals, n, d, counts)
+    distinct, dicts = _slot_dictionaries(cols, n_rows=n, n_features=d)
+    coded = tuple(int(s) for s in np.flatnonzero(
+        np.asarray(jax.device_get(distinct)) <= CODED_SLOT_WIDTH))
+    counts = dataclasses.replace(counts, coded_slots=len(coded))
+    if not coded:
+        return SlotMajorEllFeatures(cols, vals, n, d, counts)
+    codes, dicts = _slot_codes(
+        cols, dicts, distinct, jax.device_put(np.asarray(coded, np.int32)),
+        n_rows=n, n_features=d)
+    return SlotMajorEllFeatures(cols, vals, n, d, counts, codes, dicts,
+                                coded)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz",))
@@ -1142,9 +1337,7 @@ def sparse_rows_to_device(cols: Array, vals: Array,
                           nnz=nnz, max_col_degree=max_deg)
     layout = choose_layout(counts)
     if layout == "slot_major_ell":
-        return SlotMajorEllFeatures(
-            _slot_major(cols), _slot_major(vals), n, n_features,
-            dataclasses.replace(counts, layout=layout, slots=n * k))
+        return _slot_major_ell(_slot_major(cols), _slot_major(vals), counts)
     return CSRFeatures(
         *_compact_rows(cols, vals, nnz), n, n_features,
         dataclasses.replace(counts, layout=layout, slots=nnz))
@@ -1251,11 +1444,9 @@ def lay_out_triplet(feats: CSRFeatures) -> "FeatureMatrix":
                           max_col_degree=max_deg)
     layout = choose_layout(counts)
     if layout == "slot_major_ell":
-        cols, vals = _slot_major_of_triplet(
-            feats.values, feats.col_ids, feats.row_ids, n_rows=n, k=k)
-        return SlotMajorEllFeatures(
-            cols, vals, n, d,
-            dataclasses.replace(counts, layout=layout, slots=n * k))
+        return _slot_major_ell(*_slot_major_of_triplet(
+            feats.values, feats.col_ids, feats.row_ids, n_rows=n, k=k),
+            counts)
     return dataclasses.replace(feats, counts=dataclasses.replace(
         counts, layout=layout, slots=int(feats.values.shape[-1])))
 

@@ -23,6 +23,12 @@ FE_SCORE = "photon.fe.score"      # _fe_score_impl
 # matrix opens neither: its products are the solve's fusions)
 FE_MATVEC = "photon.fe.matvec"
 FE_RMATVEC = "photon.fe.rmatvec"
+# the two parts of a matvec over a slot-major ELL that has coded slots
+# (``ops.features.SlotMajorEllFeatures``): the slots read through a code and
+# the slot's few table entries, and the slots read by gather. Children of
+# FE_MATVEC, opened only where a matrix has a coded slot.
+FE_MATVEC_CODED = "photon.fe.matvec.coded"
+FE_MATVEC_GATHERED = "photon.fe.matvec.gathered"
 # the programs that count a sparse matrix on the device and lay it out
 # (``ops.features.sparse_rows_to_device``): at construction, never in a fit
 FE_LAYOUT = "photon.fe.layout"
@@ -47,6 +53,9 @@ DEVICE_SCOPES = (FE_SOLVE, FE_SCORE, RE_GATHER, RE_SOLVE, RE_MARGINS,
 #: (the two-loop, the line search's n-vectors). Not in ``DEVICE_SCOPES``:
 #: a fit over a dense matrix has no such operation.
 FE_PRODUCT_SCOPES = (FE_MATVEC, FE_RMATVEC)
+#: Children of ``FE_MATVEC`` where the matrix has coded slots: together
+#: they are the matvec, and the script prints each under it.
+FE_MATVEC_PARTS = (FE_MATVEC_CODED, FE_MATVEC_GATHERED)
 #: gather + margins + scatter: the score exchange.
 EXCHANGE_SCOPES = (RE_GATHER, RE_MARGINS, RE_SCATTER)
 
@@ -116,6 +125,11 @@ GAUGE_RE_SCORE_UNSLOTTED_ROWS = "training.re.score.unslotted_rows"
 GAUGE_FE_NNZ = "training.fe.nnz"
 GAUGE_FE_SLOTS = "training.fe.slots"
 GAUGE_FE_MAX_COL_DEGREE = "training.fe.max_col_degree"
+#: Of a row's k slots, those the slot-major ELL reads by code in its
+#: row-wise products (at most ``ops.features.CODED_SLOT_WIDTH`` distinct
+#: columns over all rows): 0 says the mechanism found nothing to engage on
+#: (rows sorted by column id scatter the fields over the slots).
+GAUGE_FE_CODED_SLOTS = "training.fe.coded_slots"
 
 # -- gauges of a fit whose coordinates lie over a device mesh (mesh=) ----------
 GAUGE_MESH_DEVICES = "training.mesh.devices"

@@ -55,7 +55,7 @@ _unclaimed = {"retrieval_s": 0.0, "cache_hits": 0}
 # Devices a function's program is partitioned over, where its owner said so
 # (``note_partitions``): JAX's events carry a function's name and no more.
 _partitions: Dict[str, int] = {}
-_layouts: Dict[str, str] = {}
+_layouts: Dict[str, tuple] = {}
 
 
 def _function_name(fun_name) -> str:
@@ -108,12 +108,14 @@ def note_partitions(fun_name: str, partitions: int) -> None:
         _partitions[str(fun_name)] = int(partitions)
 
 
-def note_layout(fun_name: str, layout: str) -> None:
+def note_layout(fun_name: str, layout: str, coded_slots: int = 0) -> None:
     """The owner of a jitted function that runs a sparse fixed effect says
-    in which layout the chooser put its matrix: the ledger's row of that
-    name reads ``fe_layout`` (no such key where nobody spoke)."""
+    in which layout the chooser put its matrix, and how many of a row's
+    slots that layout reads by code: the ledger's row of that name reads
+    ``fe_layout`` and ``fe_coded_slots`` (no such keys where nobody
+    spoke)."""
     with _lock:
-        _layouts[str(fun_name)] = str(layout)
+        _layouts[str(fun_name)] = (str(layout), int(coded_slots))
 
 
 def compile_ledger(top: Optional[int] = None) -> dict:
@@ -127,16 +129,17 @@ def compile_ledger(top: Optional[int] = None) -> dict:
     where it hit: ``retrieval_s`` and ``cache_hits`` are that part), and
     how often each happened (``traces``, ``lowerings``, ``compiles``),
     and ``partitions``, the devices the program was lowered for
-    (``note_partitions``; 1 where nobody said); ``fe_layout`` where the
-    function runs a sparse fixed effect (``note_layout``).
+    (``note_partitions``; 1 where nobody said); ``fe_layout`` and
+    ``fe_coded_slots`` where the function runs a sparse fixed effect
+    (``note_layout``).
     Names are the jitted functions' (``cd_block``), as JAX reports them.
     Totals add ``cache_requests``; requests minus hits were compiled."""
     with _lock:
         rows = {k: dict(v, partitions=_partitions.get(k, 1))
                 for k, v in _functions.items()}
-        for k, layout in _layouts.items():
+        for k, (layout, coded_slots) in _layouts.items():
             if k in rows:
-                rows[k]["fe_layout"] = layout
+                rows[k].update(fe_layout=layout, fe_coded_slots=coded_slots)
         totals = dict(_totals)
     if top is not None:
         cost = lambda r: r["trace_s"] + r["lower_s"] + r["backend_s"]

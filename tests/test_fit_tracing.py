@@ -347,6 +347,31 @@ def test_place_of_an_operation(path, leaf, coordinate, size_class):
     assert where["scoped"] == (leaf is not None or coordinate is not None)
 
 
+@pytest.mark.parametrize("tail, product, part", [
+    # a matvec whose matrix has coded slots (PR 36): each part is a row
+    # under the product, which is a row under the scope that ran it
+    ("photon.fe.matvec/photon.fe.matvec.coded/while/body/closed_call/"
+     "checkpoint/while/body/select_n",
+     "photon.fe.solve/photon.fe.matvec",
+     "photon.fe.solve/photon.fe.matvec/photon.fe.matvec.coded"),
+    ("photon.fe.matvec/photon.fe.matvec.gathered/while/body/closed_call/"
+     "gather",
+     "photon.fe.solve/photon.fe.matvec",
+     "photon.fe.solve/photon.fe.matvec/photon.fe.matvec.gathered"),
+    ("photon.fe.matvec/while/body/gather",
+     "photon.fe.solve/photon.fe.matvec", None),
+    ("photon.fe.rmatvec/while/body/scatter-add",
+     "photon.fe.solve/photon.fe.rmatvec", None),
+    # a part under no product is no part
+    ("photon.fe.matvec.coded/select_n", None, None),
+])
+def test_place_of_a_sparse_product_and_its_parts(tail, product, part):
+    where = trace_scopes.place(
+        f"{_BLOCK}/photon.cd.fixed/jit(_solve_fixed)/photon.fe.solve/{tail}")
+    assert where["leaf"] == scopes.FE_SOLVE
+    assert (where["product"], where["part"]) == (product, part)
+
+
 @pytest.mark.parametrize("event, want", [
     ("%all-reduce.12 = f32[200]{0:T(256)} all-reduce(f32[200]{0} %dot.3), "
      "channel_id=3", True),

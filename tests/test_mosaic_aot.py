@@ -231,3 +231,44 @@ def test_kernel_compiles_under_shard_map(topo, tpu_lowering):
     assert KERNEL_MARKER in compiled.as_text()
     memory = compiled.memory_analysis()  # bytes per device
     assert 0 < memory.argument_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_the_coded_matvec_compiles_at_the_sparse_cells_shape(one_chip,
+                                                             tpu_lowering):
+    """``sparse-lr.fit``'s matvec (PR 36): 9,168,123 rows x 40 slots, the
+    25 slots whose field has at most ``CODED_SLOT_WIDTH`` values read by
+    code. The compiled program's temporaries are n-vectors (0.56 GB
+    together, nothing of size ``[width, n]``), its gathers sit in plain loops (a
+    conditional's branch is out of the memory-space assignment's reach:
+    PERF.md section 6), and the gather is gone from the coded slots."""
+    import json
+    import pathlib
+    import re
+
+    from photon_ml_tpu.ops import features as F
+
+    config = json.loads((pathlib.Path(__file__).resolve().parent.parent
+                         / "benchmark/configs/sparse-lr-criteo.json"
+                         ).read_text())
+    fields = config["fixed"]["fields"]
+    n, k, d = config["n_rows"], len(fields) + 1, config["fixed"]["d"]
+    coded = tuple(f for f, card in enumerate(fields)
+                  if card - 1 <= F.CODED_SLOT_WIDTH) + (k - 1,)
+    assert len(coded) == 25
+    s = _struct(one_chip)
+    feats = F.SlotMajorEllFeatures(
+        s((k * n,), jnp.int32), s((k * n,)), n, d, None,
+        s((len(coded) * F._code_stride(n),), F._CODE_DTYPE),
+        s((len(coded), F.CODED_SLOT_WIDTH), jnp.int32), coded)
+    compiled = jax.jit(lambda f, v: f.matvec(v)).lower(
+        feats, s((d,))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 ** 30  # [width, n] is 37.5 GB
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    gathered = len(list(F._runs(coded, k))) - sum(
+        1 for _, _, at in F._runs(coded, k) if at is not None)
+    # one gather of n a run of gathered slots (the loop's body), and the
+    # dictionaries' own 25 x 1,024 entries
+    assert len(re.findall(rf"= f32\[{n}\][^ ]* fusion\([^)]*\), "
+                          r"kind=kCustom", text)) == gathered

@@ -408,8 +408,9 @@ def test_a_mesh_fit_takes_a_shard_the_chooser_lays_slot_major(
         float)
     data = GameDataset.build(responses=labels,
                              feature_shards={"global": mat}, ids={})
-    assert isinstance(data.fixed_effect_batch("global").features,
-                      F.SlotMajorEllFeatures)
+    laid = data.fixed_effect_batch("global").features
+    assert isinstance(laid, F.SlotMajorEllFeatures)
+    assert laid.coded == tuple(range(k))  # d <= CODED_SLOT_WIDTH: all of them
 
     def fit(**kw):
         coord = FixedEffectCoordinate(
@@ -453,8 +454,9 @@ def test_the_device_scorer_takes_shards_the_chooser_lays_slot_major():
                         "user": uniform_shard(rng, n, 3, d_user)},
         ids={"userId": rng.integers(0, 7, n).astype(str)})
     for shard in ("global", "user"):
-        assert isinstance(F.features_to_device(data.feature_shards[shard]),
-                          F.SlotMajorEllFeatures)
+        laid = F.features_to_device(data.feature_shards[shard])
+        assert isinstance(laid, F.SlotMajorEllFeatures)
+        assert laid.coded  # d <= CODED_SLOT_WIDTH: the scorer meets codes
     fe = FixedEffectModel(LogisticRegressionModel(Coefficients(
         jnp.asarray(rng.normal(size=d)))), "global")
     ds = build_random_effect_dataset(
@@ -466,3 +468,290 @@ def test_the_device_scorer_takes_shards_the_chooser_lays_slot_major():
     gm = GameModel({"fixed": fe, "perUser": re}, TASK)
     got = np.asarray(DeviceGameScorer(gm, data, dtype=jnp.float64).score(gm))
     np.testing.assert_allclose(got, gm.score(data), rtol=1e-10, atol=1e-10)
+
+
+# -- coded slots: a slot that names few columns is read by code (PR 36) ---------
+
+W = F.CODED_SLOT_WIDTH
+
+
+def fielded_rows(rng, n, d, widths, pad=()):
+    """Rows that come field by field, as a click log does: slot f of every
+    row names one of ``widths[f]`` columns of its own (a width over
+    ``CODED_SLOT_WIDTH`` is a slot the program gathers); the rows of ``pad``
+    leave their slot 0 empty (value 0 at column 0)."""
+    k = len(widths)
+    cols = np.empty((n, k), np.int32)
+    for f, width in enumerate(widths):
+        own = rng.choice(d, size=width, replace=False)
+        draw = rng.integers(0, width, n)
+        draw[:width] = np.arange(width)  # every one of them is named
+        cols[:, f] = own[draw]
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    vals[vals == 0] = 1.0
+    for i in pad:
+        cols[i, 0], vals[i, 0] = 0, 0.0
+    return cols, vals
+
+
+WIDTHS = (1, 64, W + 900, W, W + 1, 3, W + 500, W + 200, 7, 1)
+WANT_CODED = (0, 1, 3, 5, 8, 9)
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def coded(request):
+    n, d = 3 * W, 5 * W
+    cols, vals = fielded_rows(np.random.default_rng(36), n, d, WIDTHS,
+                              pad=(5, 17, n - 1))
+    vals = jnp.asarray(vals).astype(request.param)
+    feats = F.sparse_rows_to_device(jnp.asarray(cols), vals, d)
+    plain = dataclasses.replace(feats, codes=None, dicts=None, coded=())
+    return {"feats": feats, "plain": plain, "cols": cols,
+            "vals": np.asarray(vals.astype(jnp.float32)), "n": n, "d": d}
+
+
+def test_a_slot_of_exactly_the_width_is_coded_and_one_more_is_not(coded):
+    feats = coded["feats"]
+    assert feats.coded == WANT_CODED  # W is in, W + 1 is out
+    assert F.layout_counts(feats).coded_slots == len(WANT_CODED)
+    assert feats.dicts.shape == (len(WANT_CODED), W)
+    assert feats.codes.dtype == jnp.uint16
+    # cols and vals stay whole: what every other reader of the layout reads
+    k = len(WIDTHS)
+    assert feats.cols.shape == feats.vals.shape == (coded["n"] * k,)
+    np.testing.assert_array_equal(
+        np.asarray(feats.cols).reshape(k, -1).T, coded["cols"])
+
+
+def test_what_a_coded_slot_selects_is_bitwise_what_the_gather_fetches(coded):
+    feats, n = coded["feats"], coded["n"]
+    v = jnp.asarray(np.random.default_rng(1).normal(size=coded["d"]),
+                    jnp.float32)
+    stride = F._code_stride(n)
+    for j, s in enumerate(feats.coded):
+        code = feats.codes[j * stride:j * stride + n]
+        selected = np.asarray(F._select(code, v[feats.dicts[j]]))
+        fetched = np.asarray(v[coded["cols"][:, s]])
+        assert selected.tobytes() == fetched.tobytes(), s
+    # the padded rows of slot 0 (value 0 at column 0) carry column 0's code
+    assert int(feats.dicts[0, 0]) == 0
+    assert [int(feats.codes[i]) for i in (5, 17, n - 1)] == [0, 0, 0]
+
+
+@pytest.mark.parametrize("product", ["matvec", "row_sq_matvec"])
+def test_row_products_with_coded_slots_equal_the_gathered_ones(coded,
+                                                               product):
+    v = jnp.asarray(np.random.default_rng(2).normal(size=coded["d"]),
+                    jnp.float32)
+    got = np.asarray(getattr(coded["feats"], product)(v))
+    want = np.asarray(getattr(coded["plain"], product)(v))
+    assert got.dtype == want.dtype == np.float32
+    # the same terms in the same order; the CPU backend contracts the
+    # multiply-add differently in the two programs: a rounding of a term
+    x = coded["vals"] ** 2 if product == "row_sq_matvec" else coded["vals"]
+    terms = np.abs(x * np.asarray(v)[coded["cols"]])
+    assert (np.abs(got - want) <= 2.0 ** -23 * terms.sum(axis=1)).all()
+    # and both are the product: against float64 on the host
+    exact = (x.astype(np.float64)
+             * np.asarray(v, np.float64)[coded["cols"]]).sum(axis=1)
+    np.testing.assert_allclose(got, exact, rtol=0,
+                               atol=4e-6 * terms.sum(axis=1).max())
+
+
+def test_column_products_and_the_triplet_ignore_the_codes(coded):
+    feats, plain = coded["feats"], coded["plain"]
+    u = jnp.asarray(np.random.default_rng(3).normal(size=coded["n"]),
+                    jnp.float32)
+    for product in ("rmatvec", "sq_rmatvec"):
+        assert (np.asarray(getattr(feats, product)(u)).tobytes()
+                == np.asarray(getattr(plain, product)(u)).tobytes())
+    csr = feats.to_csr()
+    assert isinstance(csr, F.CSRFeatures)
+    assert F.layout_counts(csr).coded_slots == len(WANT_CODED)
+    v = jnp.asarray(np.random.default_rng(4).normal(size=coded["d"]),
+                    jnp.float32)
+    np.testing.assert_allclose(np.asarray(csr.matvec(v)),
+                               np.asarray(feats.matvec(v)), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_autodiff_through_coded_slots_is_the_transposed_product(coded):
+    """What TRON and OWL-QN do (``jax.value_and_grad`` of the objective,
+    ``jax.jvp`` of that gradient): the coded slots differentiate to the
+    column-wise product, as the gathered ones do."""
+    feats, plain = coded["feats"], coded["plain"]
+    rng = np.random.default_rng(6)
+    v = jnp.asarray(rng.normal(size=coded["d"]), jnp.float32)
+    u = jnp.asarray(rng.normal(size=coded["n"]), jnp.float32)
+
+    def loss(f, v):
+        return jnp.sum(jnp.tanh(f.matvec(v)) * u)
+
+    grad = jax.jit(jax.grad(loss, argnums=1))
+    got, want = np.asarray(grad(feats, v)), np.asarray(grad(plain, v))
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * np.abs(want).max())
+    linear = np.asarray(jax.grad(lambda v: jnp.sum(feats.matvec(v) * u))(v))
+    np.testing.assert_allclose(linear, np.asarray(feats.rmatvec(u)),
+                               rtol=1e-4, atol=1e-5 * np.abs(linear).max())
+
+    def hvp(f):
+        return jax.jvp(lambda w: jax.grad(loss, argnums=1)(f, w), (v,),
+                       (v,))[1]
+
+    np.testing.assert_allclose(np.asarray(hvp(feats)),
+                               np.asarray(hvp(plain)), rtol=1e-3,
+                               atol=1e-4 * float(jnp.abs(hvp(plain)).max()))
+
+
+def test_codes_ride_through_jit_tree_map_and_replace(coded):
+    feats = coded["feats"]
+    for other in (jax.jit(lambda f: f)(feats),
+                  jax.tree_util.tree_map(lambda a: a, feats),
+                  dataclasses.replace(feats, n_features=feats.n_features)):
+        assert other.coded == feats.coded
+        assert other.counts == feats.counts
+        assert (np.asarray(other.codes).tobytes()
+                == np.asarray(feats.codes).tobytes())
+        np.testing.assert_array_equal(np.asarray(other.dicts),
+                                      np.asarray(feats.dicts))
+    leaves, tree = jax.tree_util.tree_flatten(feats)
+    assert len(leaves) == 4
+    assert len(jax.tree_util.tree_leaves(coded["plain"])) == 2
+    again = jax.tree_util.tree_unflatten(tree, leaves)
+    assert again.coded == feats.coded and again.n_rows == feats.n_rows
+
+
+def _parent_by_row(cols, vals, v, n, k):
+    """``SlotMajorEllFeatures._by_row`` as it stood before any slot was
+    coded (PR 35), operation for operation."""
+    acc = jnp.promote_types(v.dtype, jnp.float32)
+
+    def body(s, out):
+        c = jax.lax.dynamic_slice(cols, (s * n,), (n,))
+        x = jax.lax.dynamic_slice(vals, (s * n,), (n,)).astype(acc)
+        return out + x * v.at[c].get(mode="promise_in_bounds")
+
+    return jax.lax.fori_loop(0, k, body, jnp.zeros((n,), acc))
+
+
+def test_a_matrix_with_no_small_slot_runs_the_loop_it_ran_before():
+    n, d = 2 * W, 5 * W
+    widths = (W + 300, W + 500, W + 1, W + 900)
+    cols, vals = fielded_rows(np.random.default_rng(8), n, d, widths)
+    feats = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
+    assert feats.coded == () and feats.codes is None and feats.dicts is None
+    assert F.layout_counts(feats).coded_slots == 0
+    v = jnp.zeros((d,), jnp.float32)
+    k = len(widths)
+    now = jax.jit(lambda cols, vals, v: F.SlotMajorEllFeatures(
+        cols, vals, n, d).matvec(v)).lower(feats.cols, feats.vals, v)
+    then = jax.jit(lambda cols, vals, v: _parent_by_row(
+        cols, vals, v, n, k)).lower(feats.cols, feats.vals, v)
+    assert now.as_text() == then.as_text()
+    # with a coded slot the text differs, and names both parts
+    cols[:, 0] = 7
+    some = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
+    assert some.coded == (0,)
+    text = jax.jit(lambda f, v: f.matvec(v)).lower(some, v).as_text(
+        debug_info=True)
+    for part in scopes.FE_MATVEC_PARTS:
+        assert part in text
+    assert scopes.FE_MATVEC_CODED not in now.as_text(debug_info=True)
+
+
+def test_both_constructors_code_the_same_slots():
+    """Rows whose columns ascend along the slots, so that scipy's sorted
+    rows keep every entry in its slot: the device path and the host path
+    (``features_to_device`` -> ``lay_out_triplet``) must agree."""
+    n, d = 2 * W, 14 * W
+    rng = np.random.default_rng(9)
+    widths = (1, 30, W + 200, W, W + 100, W + 1, 1)
+    cols = np.empty((n, len(widths)), np.int32)
+    for f, width in enumerate(widths):  # field f owns columns [2 W f, ...)
+        draw = rng.integers(0, width, n)
+        draw[:width] = np.arange(width)
+        cols[:, f] = 2 * W * f + draw
+    vals = rng.normal(size=cols.shape).astype(np.float32)
+    vals[vals == 0] = 1.0
+    born = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
+    host = F.features_to_device(as_scipy(cols, vals, d))
+    assert isinstance(host, F.SlotMajorEllFeatures)
+    assert born.coded == host.coded == (0, 1, 3, 6)
+    assert F.layout_counts(born) == F.layout_counts(host)
+    np.testing.assert_array_equal(np.asarray(born.dicts),
+                                  np.asarray(host.dicts))
+    assert (np.asarray(born.codes).tobytes()
+            == np.asarray(host.codes).tobytes())
+
+
+def test_shard_batch_and_the_mesh_fit_hold_with_coded_slots(coded):
+    from photon_ml_tpu.parallel import make_mesh, shard_batch
+
+    feats, n = coded["feats"], coded["n"]
+    batch = GLMBatch(feats, jnp.zeros((n,)), jnp.zeros((n,)), jnp.ones((n,)))
+    sharded = shard_batch(batch, make_mesh(4))
+    assert isinstance(sharded.features, F.CSRFeatures)
+    v = jnp.asarray(np.random.default_rng(5).normal(size=coded["d"]),
+                    jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(sharded.features.matvec(v))[:n],
+        np.asarray(feats.matvec(v)), rtol=1e-5, atol=1e-5)
+
+
+def test_the_gauge_and_the_ledger_row_read_the_coded_count(problem):
+    enable_compile_cache()
+    telemetry.enable()
+    # ``problem``'s rows are full and end in the intercept: one coded slot
+    feats = F.sparse_rows_to_device(problem.cols, problem.vals,
+                                    problem.n_features)
+    k = problem.cols.shape[1]
+    assert feats.coded == (k - 1,)
+    cd, coord = descent(problem, feats)
+    assert coord.sparse_work()[0].coded_slots == 1
+    assert telemetry.snapshot()["gauges"][scopes.GAUGE_FE_CODED_SLOTS] == 1
+    cd.run(1)
+    row = compile_ledger()["functions"][scopes.CD_BLOCK]
+    assert (row["fe_layout"], row["fe_coded_slots"]) == ("slot_major_ell", 1)
+    # ... and 0 where no slot is small
+    telemetry.reset()
+    n, d = W + 10, 3 * W
+    cols, vals = fielded_rows(np.random.default_rng(10), n, d,
+                              (W + 1, W + 2))
+    none = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
+    p = Problem(n, d, None, None, jnp.zeros((n,), jnp.float32),
+                jnp.zeros((n,), jnp.float32), jnp.ones((n,), jnp.float32))
+    descent(p, none)
+    assert telemetry.snapshot()["gauges"][scopes.GAUGE_FE_CODED_SLOTS] == 0
+
+
+def test_two_seeds_of_the_recipe_share_every_compiled_program():
+    """The cell's own rows at 30,000: the 25 slots whose field has at most
+    ``CODED_SLOT_WIDTH`` values are coded on every seed, the dictionaries
+    are padded to the width, and so nothing compiles for the second seed:
+    not at construction, not the products."""
+    import json
+    import pathlib
+
+    from benchmark.recipes import sparse_glm as recipe
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    config = recipe.scale_down(json.loads(
+        (root / "benchmark/configs/sparse-lr-criteo.json").read_text()),
+        30000)
+    fields = config["fixed"]["fields"]
+    want = tuple(f for f, card in enumerate(fields) if card - 1 <= W
+                 ) + (len(fields),)  # ranks 1 .. card - 1, and the intercept
+    matvec = jax.jit(lambda f, w: f.matvec(w))
+    programs = (F._slot_dictionaries, F._slot_codes, F._slot_major,
+                F._count_rows, matvec)
+    sizes = []
+    for seed in (2147486611, 2147486612):
+        p = recipe.make(config, seed)
+        feats = F.sparse_rows_to_device(p.cols, p.vals, p.n_features)
+        assert feats.coded == want and len(want) == 25
+        assert F.layout_counts(feats).coded_slots == 25
+        jax.block_until_ready(matvec(feats, jnp.zeros((p.n_features,),
+                                                      jnp.float32)))
+        sizes.append([fn._cache_size() for fn in programs])
+    assert sizes[0] == sizes[1]
