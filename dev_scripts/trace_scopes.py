@@ -14,8 +14,11 @@ drops), and prints per job:
 - device ms by scope of ``photon_ml_tpu/telemetry/scopes.py``: an operation
   counts under the innermost table scope on its path; a scope's time is the
   union of its operations' intervals; the size classes ``r<rows>`` are
-  listed under ``photon.re.solve`` with the path each took;
-- the same rolled up by ``photon.cd.<coordinate>``;
+  listed under ``photon.re.solve`` with the path each took (and, where a
+  fit has a factored coordinate, its ``photon.mf.*`` rows with the latent
+  classes under ``photon.mf.latent``);
+- the same rolled up by ``photon.cd.<coordinate>`` (with more than two
+  coordinates, each one's update by leaf scope under it);
 - the exchange (gather + margins + scatter);
 - what of each scope ran before ``cd_block``'s first operation (the eager
   initial-scores pass of a warm start; nothing on a cold one);
@@ -103,6 +106,9 @@ def is_collective(name: str) -> bool:
 
 
 NO_SCOPE = "(no scope)"
+#: Every leaf scope of the table: the factored coordinate's own rows are
+#: printed only where a trace holds such operations.
+LEAF_SCOPES = scopes.DEVICE_SCOPES + scopes.MF_SCOPES
 # The stat that carries the HLO metadata's op_name (``tf_op`` on the v5e,
 # PERF.md §5); any other stat whose value holds a ``photon.`` scope is
 # taken where a later profiler renames it.
@@ -331,7 +337,7 @@ def covered_ms(intervals: Iterable[Interval], lo: int, hi: int) -> float:
 def place(path: str) -> dict:
     """``leaf``: the innermost table scope on the path; ``coordinate``: its
     ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
-    ``photon.re.solve``; ``product``: the sparse product
+    ``photon.re.solve`` or ``photon.mf.latent``; ``product``: the sparse product
     (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
     ``<leaf>/<product>``; ``part``: the matvec's coded or gathered slots
     (PR 36), as ``<leaf>/<product>/<part>``; ``scoped``: under any
@@ -343,9 +349,10 @@ def place(path: str) -> dict:
             product = f"{leaf}/{part}"
         elif part in scopes.FE_MATVEC_PARTS and product:
             piece = f"{product}/{part}"
-        elif part in scopes.DEVICE_SCOPES:
+        elif part in LEAF_SCOPES:
             leaf = part
-            if (part == scopes.RE_SOLVE and i + 1 < len(parts)
+            if (part in (scopes.RE_SOLVE, scopes.MF_LATENT)
+                    and i + 1 < len(parts)
                     and _SIZE_CLASS.match(parts[i + 1])):
                 size_class = parts[i + 1]
         elif part.startswith(scopes.cd_coordinate("")):
@@ -394,6 +401,8 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
     by_leaf: Dict[str, list] = {}
     by_coord: Dict[str, list] = {}
     by_class: Dict[str, dict] = {}
+    by_latent: Dict[str, dict] = {}
+    by_cell: Dict[str, list] = {}
     by_product: Dict[str, list] = {}
     scoped, everything, loose = [], [], {}
     collectives: Dict[str, list] = {}
@@ -417,9 +426,14 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
                 by_product.setdefault(key, []).append(iv)
         if where["coordinate"]:
             by_coord.setdefault(where["coordinate"], []).append(iv)
+            by_cell.setdefault(
+                f"{where['coordinate']}/{where['leaf'] or NO_SCOPE}",
+                []).append(iv)
         if where["size_class"]:
-            cls = by_class.setdefault(where["size_class"],
-                                      {"ivs": [], "kernel": False})
+            classes = (by_latent if where["leaf"] == scopes.MF_LATENT
+                       else by_class)
+            cls = classes.setdefault(where["size_class"],
+                                     {"ivs": [], "kernel": False})
             cls["ivs"].append(iv)
             if short_name(name).lstrip("%").startswith(scopes.KERNEL):
                 cls["kernel"] = True
@@ -438,7 +452,15 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] - edges[i] > gap_ms * 1e6]
     scope_ms = {s: covered_ms(by_leaf.get(s, []), lo, hi)
-                for s in scopes.DEVICE_SCOPES}
+                for s in LEAF_SCOPES
+                if s in scopes.DEVICE_SCOPES or s in by_leaf}
+
+    def class_ms(classes):
+        return {c: {"ms": covered_ms(v["ivs"], lo, hi),
+                    "path": "kernel" if v["kernel"] else "vmapped"}
+                for c, v in sorted(classes.items(),
+                                   key=lambda kv: int(kv[0][1:]))}
+
     # What ran before the block's first operation: a cold start's initial
     # scores are built, so no scoring scope may show here (a warm start's,
     # and a coordinate's that declares no zero start, do).
@@ -456,11 +478,12 @@ def reduce_job(events: List[list], spans, lo: int, hi: int,
                           for s, ivs in sorted(collectives.items())},
         "collectives_ms": covered_ms(
             [iv for ivs in collectives.values() for iv in ivs], lo, hi),
-        "size_class_ms": {
-            c: {"ms": covered_ms(v["ivs"], lo, hi),
-                "path": "kernel" if v["kernel"] else "vmapped"}
-            for c, v in sorted(by_class.items(),
-                               key=lambda kv: int(kv[0][1:]))},
+        "size_class_ms": class_ms(by_class),
+        # the factored coordinate's latent solves, by size class
+        "latent_class_ms": class_ms(by_latent),
+        # every coordinate's update by leaf scope (``<coordinate>/<leaf>``)
+        "cell_ms": {c: covered_ms(ivs, lo, hi)
+                    for c, ivs in sorted(by_cell.items())},
         # a sparse fixed effect's products, by the scope that ran them
         "product_ms": {c: covered_ms(ivs, lo, hi)
                        for c, ivs in sorted(by_product.items())},
@@ -527,18 +550,19 @@ def _mean(per_job: List[dict]) -> dict:
            for k in ("window_ms", "busy_ms", "exchange_ms", "collectives_ms",
                      "unattributed_ms", "unattributed_share")}
     for key in ("scope_ms", "before_block_ms", "coordinate_ms",
-                "collective_ms", "product_ms"):
+                "collective_ms", "product_ms", "cell_ms"):
         names = sorted({s for j in per_job for s in j[key]})
         out[key] = {s: sum(j[key].get(s, 0.0) for j in per_job) / n
                     for s in names}
-    classes = sorted({c for j in per_job for c in j["size_class_ms"]},
-                     key=lambda c: int(c[1:]))
-    out["size_class_ms"] = {
-        c: {"ms": sum(j["size_class_ms"].get(c, {"ms": 0.0})["ms"]
-                      for j in per_job) / n,
-            "path": next(j["size_class_ms"][c]["path"] for j in per_job
-                         if c in j["size_class_ms"])}
-        for c in classes}
+    for key in ("size_class_ms", "latent_class_ms"):
+        classes = sorted({c for j in per_job for c in j[key]},
+                         key=lambda c: int(c[1:]))
+        out[key] = {
+            c: {"ms": sum(j[key].get(c, {"ms": 0.0})["ms"]
+                          for j in per_job) / n,
+                "path": next(j[key][c]["path"] for j in per_job
+                             if c in j[key])}
+            for c in classes}
     return out
 
 
@@ -553,8 +577,10 @@ def print_report(result: dict, out=sys.stdout) -> None:
         print("| scope | ms | share of busy | of it collectives, ms |",
               file=out)
         print("| --- | --- | --- | --- |", file=out)
-        for s in scopes.DEVICE_SCOPES:
-            ms = block["scope_ms"].get(s, 0.0)
+        for s in LEAF_SCOPES:  # the table's order, not the dict's
+            if s not in block["scope_ms"]:
+                continue
+            ms = block["scope_ms"][s]
             print(f"| `{s}` | {ms:.3f} | {100 * ms / busy:.2f}% | "
                   f"{coll.get(s, 0.0):.3f} |", file=out)
             mine = {c: v for c, v in block.get("product_ms", {}).items()
@@ -569,8 +595,10 @@ def print_report(result: dict, out=sys.stdout) -> None:
                 print(f"| &nbsp;&nbsp;the rest of `{s}` (d-space, "
                       f"n-vectors) | {rest:.3f} | {100 * rest / busy:.2f}% |",
                       file=out)
-            if s == scopes.RE_SOLVE:
-                for c, v in block["size_class_ms"].items():
+            if s in (scopes.RE_SOLVE, scopes.MF_LATENT):
+                classes = block["size_class_ms" if s == scopes.RE_SOLVE
+                                else "latent_class_ms"]
+                for c, v in classes.items():
                     print(f"| &nbsp;&nbsp;`{c}` ({v['path']}) | "
                           f"{v['ms']:.3f} | {100 * v['ms'] / busy:.2f}% |",
                           file=out)
@@ -580,6 +608,12 @@ def print_report(result: dict, out=sys.stdout) -> None:
         for c, ms in block["coordinate_ms"].items():
             print(f"| `{c}` (whole update) | {ms:.3f} | "
                   f"{100 * ms / busy:.2f}% |", file=out)
+            if len(block["coordinate_ms"]) > 2:  # which scope, whose update
+                for cell, v in block["cell_ms"].items():
+                    if cell.startswith(c + "/"):
+                        print(f"| &nbsp;&nbsp;`{cell.split('/', 1)[1]}` | "
+                              f"{v:.3f} | {100 * v / busy:.2f}% |",
+                              file=out)
         print(f"| under no `photon.*` scope | "
               f"{block['unattributed_ms']:.3f} | "
               f"{100 * block['unattributed_share']:.2f}% | "

@@ -56,6 +56,9 @@ _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
 _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
 _M_FE_PRODUCTS = telemetry.counter(scopes.COUNTER_FE_PRODUCTS)
+_M_MF_ALTERNATIONS = telemetry.counter(scopes.COUNTER_MF_ALTERNATIONS)
+_M_MF_REFIT_ITERATIONS = telemetry.counter(
+    scopes.COUNTER_MF_REFIT_ITERATIONS)
 
 
 def _unstack_tracker_block(trs: Dict[str, object], names: Sequence[str],
@@ -188,8 +191,11 @@ class CoordinateDescent:
                 max(c.max_col_degree for c in sparse))
             telemetry.gauge(scopes.GAUGE_FE_CODED_SLOTS).set(
                 sum(c.coded_slots for c in sparse))
+        # A factored coordinate's classes are solved at its latent width,
+        # and counted apart from the random effects' (``training.mf.*``).
+        factored = [c for c in self.coordinates.values() if c.factored]
         routed = [c for c in self.coordinates.values()
-                  if hasattr(c, "routing")]
+                  if hasattr(c, "routing") and not c.factored]
         buckets = [b for c in routed for b in c.routing()]
         on_kernel = sum(b["entities"] for b in buckets
                         if b["path"] == "kernel")
@@ -200,6 +206,17 @@ class CoordinateDescent:
         telemetry.gauge(scopes.GAUGE_RE_KERNEL_ENTITIES).set(on_kernel)
         telemetry.gauge(scopes.GAUGE_RE_FALLBACK_ENTITIES).set(
             sum(b["entities"] for b in buckets) - on_kernel)
+        if factored:
+            latent = [b for c in factored for b in c.routing()]
+            on_kernel = sum(b["entities"] for b in latent
+                            if b["path"] == "kernel")
+            telemetry.gauge(scopes.GAUGE_MF_FACTORS).set(
+                sum(c.mf_config.num_factors for c in factored))
+            telemetry.gauge(scopes.GAUGE_MF_SLOTS).set(
+                sum(b["slots"] for b in latent))
+            telemetry.gauge(scopes.GAUGE_MF_KERNEL_ENTITIES).set(on_kernel)
+            telemetry.gauge(scopes.GAUGE_MF_FALLBACK_ENTITIES).set(
+                sum(b["entities"] for b in latent) - on_kernel)
         indexed = [c for c in self.coordinates.values()
                    if hasattr(c, "unslotted_rows")]
         telemetry.gauge(scopes.GAUGE_RE_SCORE_ROWS).set(
@@ -667,6 +684,14 @@ class CoordinateDescent:
                     _M_FE_PRODUCTS.inc(sum(
                         c.sparse_work(lazy[name])[1]
                         for name, c in self.coordinates.items()))
+                if telemetry.enabled():
+                    # what the factored coordinates' updates ran: a fetch
+                    # of their trackers too
+                    for name, c in self.coordinates.items():
+                        if c.factored:
+                            runs, its = c.factored_work(lazy[name])
+                            _M_MF_ALTERNATIONS.inc(runs)
+                            _M_MF_REFIT_ITERATIONS.inc(its)
                 return CoordinateDescentResult(
                     model=final,
                     objective_history=list(objective_history),

@@ -77,6 +77,10 @@ class Coordinate:
 
     name: str
 
+    # whether the coordinate's classes are solved at a latent width and
+    # counted under ``training.mf.*`` (and then it has ``factored_work``)
+    factored = False
+
     @property
     def zero_start(self) -> bool:
         """Whether ``initialize_model()`` is KNOWN to score zero on every
@@ -656,25 +660,17 @@ class RandomEffectCoordinate(Coordinate):
         (``rows``), its entities and slots, and the path the guard picks
         (``kernel``, or ``vmapped`` with the guard's ``reason``). Decided
         from shapes and configuration alone, as the trace decides it."""
-        out = []
-        for block, norm, bounds in zip(self.dataset.blocks,
-                                       self._norm_blocks,
-                                       self._bounds_blocks):
-            e, r, _ = block.x.shape
-            refusal = _kernel_refusal(
-                self._objective, self.config, block.x,
-                norm=norm, bounds=bounds)
-            out.append({"rows": int(r), "entities": int(e),
-                        "slots": int(e) * int(r),
-                        "path": "kernel" if refusal is None else "vmapped",
-                        "reason": refusal and refusal[0]})
-        return out
+        return _class_routing(self.dataset.blocks, [
+            _kernel_refusal(self._objective, self.config, block.x,
+                            norm=norm, bounds=bounds)
+            for block, norm, bounds in zip(self.dataset.blocks,
+                                           self._norm_blocks,
+                                           self._bounds_blocks)])
 
     def true_rows(self) -> int:
         """Rows that are data and not padding, over all buckets (one small
         device reduction a bucket: for set-up and reports, not the loop)."""
-        return sum(int(jnp.sum(b.row_ids < self.dataset.n_rows))
-                   for b in self.dataset.blocks)
+        return _true_rows(self.dataset)
 
     @scores_zero_by_construction
     def initialize_model(self) -> RandomEffectModel:
@@ -773,6 +769,24 @@ class RandomEffectCoordinate(Coordinate):
                 c = gathered_to_normalized_space(c, *norm)
             out.append((c, self._l1, self._l2))
         return out
+
+
+def _class_routing(blocks, refusals) -> List[dict]:
+    """``routing()``'s rows: a size class a block, with the guard's word
+    on it (``_kernel_refusal``'s result, None where the kernel takes it)."""
+    out = []
+    for block, refusal in zip(blocks, refusals):
+        e, r, _ = block.x.shape
+        out.append({"rows": int(r), "entities": int(e),
+                    "slots": int(e) * int(r),
+                    "path": "kernel" if refusal is None else "vmapped",
+                    "reason": refusal and refusal[0]})
+    return out
+
+
+def _true_rows(dataset: RandomEffectDataset) -> int:
+    return sum(int(jnp.sum(b.row_ids < dataset.n_rows))
+               for b in dataset.blocks)
 
 
 def _gather_block_normalization(normalization, block: EntityBlock):
@@ -1065,6 +1079,19 @@ def _telemetry_span(stage: str):
     return span(stage)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class FactoredAlternationResult(OptimizerResult):
+    """One alternation of a factored update: the refit's
+    ``OptimizerResult`` (``x`` is B, flat), and with it the iterations every
+    entity's latent solve took before it, ``i32[E]`` a size class."""
+
+    latent_iterations: Tuple[Array, ...] = ()
+
+    def tree_flatten(self):
+        return super().tree_flatten()[0] + (self.latent_iterations,), None
+
+
 @dataclasses.dataclass
 class FactoredRandomEffectCoordinate(Coordinate):
     """Matrix-factorization-flavored random effect
@@ -1095,6 +1122,8 @@ class FactoredRandomEffectCoordinate(Coordinate):
     seed: int = 7
     mesh: Optional[object] = None
 
+    factored = True  # no annotation: a class attribute, not a field
+
     def __post_init__(self):
         if self.dataset.projection is not None:
             raise ValueError(
@@ -1118,6 +1147,30 @@ class FactoredRandomEffectCoordinate(Coordinate):
     @property
     def _dtype(self):
         return self.dataset.blocks[0].x.dtype
+
+    def routing(self) -> List[dict]:
+        """``RandomEffectCoordinate.routing`` for the latent solves: a row
+        a size class, whose solve is over the PROJECTED features
+        ``[E, r, k]``, so the guard decides by ``r x k`` and admits
+        classes it refuses at the blocks' own width."""
+        k = self.mf_config.num_factors
+        return _class_routing(self.dataset.blocks, [
+            _kernel_refusal(
+                self._objective, self.config, jax.ShapeDtypeStruct(
+                    block.x.shape[:2] + (k,), block.x.dtype))
+            for block in self.dataset.blocks])
+
+    def true_rows(self) -> int:
+        """``RandomEffectCoordinate.true_rows``."""
+        return _true_rows(self.dataset)
+
+    def factored_work(self, trackers):
+        """``(alternations, refit iterations)`` the updates of ``trackers``
+        ran; ``CoordinateDescent`` sums these into the ``training.mf.*``
+        counters."""
+        refits = [tr for update in trackers for tr in update]
+        return len(refits), sum(int(np.asarray(tr.iterations))
+                                for tr in refits)
 
     @scores_zero_by_construction  # B is Gaussian, every latent factor zero
     def initialize_model(self):
@@ -1186,18 +1239,21 @@ class FactoredRandomEffectCoordinate(Coordinate):
             blocks, residuals, d)
         trackers = []
         for _ in range(self.mf_config.max_iterations):
-            gammas = [
+            latent = [
                 _solve_factored_block(
                     self._objective, self.config, block, B, extra, g0, d,
-                    mesh=self.mesh).x
+                    mesh=self.mesh)
                 for block, extra, g0 in zip(blocks, residuals, gammas)]
+            gammas = [r.x for r in latent]
             batch = GLMBatch(
                 KroneckerFeatures(x_flat, _flatten_gammas(blocks, gammas)),
                 y_flat, off_flat, w_flat)
             result = _solve_latent_matrix(
                 self._objective, self.latent_config, batch, B.reshape(-1))
             B = result.x.reshape(B.shape)
-            trackers.append(result)
+            trackers.append(FactoredAlternationResult(
+                *result.tree_flatten()[0],
+                latent_iterations=tuple(r.iterations for r in latent)))
         return (tuple(gammas), B), trackers
 
     def pure_score(self, data, params) -> Array:
@@ -1227,31 +1283,40 @@ def _solve_factored_block(
     TPU — the latent bucket has the same shape contract as the
     random-effect one, see _solve_block; with a mesh the kernel runs per
     device over the entity-sharded bucket via shard_map, B replicated)."""
-    lat = jnp.einsum("end,kd->enk", block.x[..., :d], B)
-    offsets = block.offsets if extra_offsets is None else \
-        block.offsets + extra_offsets.astype(block.offsets.dtype)
+    with jax.named_scope(scopes.MF_PROJECT):
+        # a true matrix product: exact float32 products, not the MXU's
+        # bfloat16 default (``KroneckerFeatures`` says why)
+        lat = jnp.einsum("end,kd->enk", block.x[..., :d], B,
+                         precision=KroneckerFeatures.PRECISION)
+    offsets = block.offsets
+    if extra_offsets is not None:
+        with jax.named_scope(scopes.RE_GATHER):
+            offsets = offsets + extra_offsets.astype(offsets.dtype)
 
     use_kernel = _use_pallas_entity_solver(objective, config, lat)
 
-    if use_kernel and mesh is not None:
-        return _shard_mapped_pallas_solver(
-            objective, config, mesh, lat, block.labels, offsets,
-            block.weights, gamma0)
+    with jax.named_scope(scopes.MF_LATENT), \
+            jax.named_scope(scopes.re_size_class(lat.shape[1])):
+        if use_kernel and mesh is not None:
+            return _shard_mapped_pallas_solver(
+                objective, config, mesh, lat, block.labels, offsets,
+                block.weights, gamma0)
 
-    if use_kernel:
-        return _dispatch_pallas_solver(objective, config, lat,
-                                       block.labels, offsets,
-                                       block.weights, gamma0)
+        if use_kernel:
+            return _dispatch_pallas_solver(objective, config, lat,
+                                           block.labels, offsets,
+                                           block.weights, gamma0)
 
-    def fit_one(g0, x_lat, y, off, w):
-        from photon_ml_tpu.ops.features import DenseFeatures
-        batch = GLMBatch(DenseFeatures(x_lat), y, off, w)
-        return solve_glm(objective, batch, config, g0)
+        def fit_one(g0, x_lat, y, off, w):
+            from photon_ml_tpu.ops.features import DenseFeatures
+            batch = GLMBatch(DenseFeatures(x_lat), y, off, w)
+            return solve_glm(objective, batch, config, g0)
 
-    return jax.vmap(fit_one)(gamma0, lat, block.labels, offsets,
-                             block.weights)
+        return jax.vmap(fit_one)(gamma0, lat, block.labels, offsets,
+                                 block.weights)
 
 
+@jax.named_scope(scopes.MF_FLATTEN)
 def _flatten_factored_static(blocks, residuals, d: int):
     """All active rows across buckets in row-major order — the
     iteration-invariant half of the latent-matrix refit batch (replaces the
@@ -1269,6 +1334,7 @@ def _flatten_factored_static(blocks, residuals, d: int):
             jnp.concatenate(offs), jnp.concatenate(ws))
 
 
+@jax.named_scope(scopes.MF_REFIT)
 def _flatten_gammas(blocks, gammas) -> Array:
     """Per-row latent factors aligned with _flatten_factored_static's rows."""
     gs = []
@@ -1281,6 +1347,7 @@ def _flatten_gammas(blocks, gammas) -> Array:
 
 
 @functools.partial(jax.jit, static_argnames=("objective", "config"))
+@jax.named_scope(scopes.MF_REFIT)
 def _solve_latent_matrix(
     objective: GLMObjective, config: GLMOptimizationConfiguration,
     batch: GLMBatch, coef0,
@@ -1781,8 +1848,11 @@ def _fre_score_impl(blocks, pblocks, gammas, B, slot_of_row, n_rows: int,
                     d: int, mesh=None):
     """``_re_score_impl`` where entity e's coefficients are
     ``gamma_e @ B``."""
+    @jax.named_scope(scopes.RE_MARGINS)
     def block_margins(block, gamma):
-        coefs = gamma @ B  # [E, d]
+        # [E, d]; at the MXU's bfloat16 default the scores were 5e-4 from
+        # what the float32 model scores (PR 37's chip run)
+        coefs = jnp.matmul(gamma, B, precision=KroneckerFeatures.PRECISION)
         pad = block.d_pad - d
         if pad:
             coefs = jnp.pad(coefs, ((0, 0), (0, pad)))

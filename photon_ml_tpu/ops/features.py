@@ -202,7 +202,17 @@ class KroneckerFeatures:
 
     Flattening convention: coefficient index (a, j) -> a * d + j, i.e.
     ``B.reshape(-1)`` of a [k, d] matrix.
+
+    Every contraction asks for ``Precision.HIGHEST``: these are true matrix
+    products, which the TPU's MXU multiplies in bfloat16 by default (a
+    matrix-VECTOR product, ``DenseFeatures``', compiles to an exact float32
+    multiply-reduce and needs no such word). A float32 refit at bfloat16
+    products ends 1e-3 from its own objective's minimiser (PR 37's chip
+    runs, read again in PR 38); the products are k*d = 200 multiply-adds a
+    row of 33 floats read, far under the MXU's peak at any precision.
     """
+
+    PRECISION = jax.lax.Precision.HIGHEST
 
     x: Array  # f[n, d]
     gamma: Array  # f[n, k]
@@ -221,19 +231,22 @@ class KroneckerFeatures:
     def matvec(self, v: Array) -> Array:
         """margin_i = γ_iᵀ B x_i."""
         return jnp.einsum("nd,kd,nk->n", self.x, self._as_matrix(v),
-                          self.gamma)
+                          self.gamma, precision=self.PRECISION)
 
     def rmatvec(self, u: Array) -> Array:
         """Σ_i u_i γ_i x_iᵀ, flattened."""
-        return jnp.einsum("n,nk,nd->kd", u, self.gamma, self.x).reshape(-1)
+        return jnp.einsum("n,nk,nd->kd", u, self.gamma, self.x,
+                          precision=self.PRECISION).reshape(-1)
 
     def row_sq_matvec(self, v: Array) -> Array:
         return jnp.einsum("nd,kd,nk->n", jnp.square(self.x),
-                          self._as_matrix(v), jnp.square(self.gamma))
+                          self._as_matrix(v), jnp.square(self.gamma),
+                          precision=self.PRECISION)
 
     def sq_rmatvec(self, u: Array) -> Array:
         return jnp.einsum("n,nk,nd->kd", u, jnp.square(self.gamma),
-                          jnp.square(self.x)).reshape(-1)
+                          jnp.square(self.x),
+                          precision=self.PRECISION).reshape(-1)
 
     def tree_flatten(self):
         return (self.x, self.gamma), None

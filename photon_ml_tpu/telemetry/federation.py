@@ -121,6 +121,11 @@ GAUGE_MERGE_POLICIES: Dict[str, str] = {
     # ... but what ONE device of a process's mesh holds is no holding to
     # add up: the fleet keeps the newest writer, as for training.mesh.*.
     "training.re.slots_per_device.": "last",
+    # A factored coordinate's work (slots and entities by solve path) is a
+    # per-process holding like the random effects'; its latent width is a
+    # setting, and the fleet keeps the newest writer's.
+    "training.mf.": "sum",
+    "training.mf.factors": "last",
     # Network front door (serving/netserver.py): connections held open
     # are per-process holdings — the fleet has the sum. (Everything
     # else under serving.net.* is a counter; lint rule counter-family.)

@@ -43,11 +43,22 @@ RE_MARGINS = "photon.re.margins"  # block.local_margins
 # exchange's way back, whatever operation does it.
 RE_SCATTER = "photon.re.scatter"
 CD_OBJECTIVE = "photon.cd.objective"  # the loss sum and the penalties
+# the factored (matrix-factorization) coordinate's own phases, under its
+# ``photon.cd.<coordinate>``; its residual gather, its margins and their way
+# back keep RE_GATHER / RE_MARGINS / RE_SCATTER
+MF_FLATTEN = "photon.mf.flatten"  # _flatten_factored_static: x, y, off, w
+MF_PROJECT = "photon.mf.project"  # x . B^T, one einsum a size class
+MF_LATENT = "photon.mf.latent"    # the latent solves, one child a class
+MF_REFIT = "photon.mf.refit"      # per-slot factors and the solve for B
 
 #: The leaf scopes: an operation counts under the innermost of these on
 #: its path.
 DEVICE_SCOPES = (FE_SOLVE, FE_SCORE, RE_GATHER, RE_SOLVE, RE_MARGINS,
                  RE_SCATTER, CD_OBJECTIVE)
+#: The factored coordinate's leaf scopes: only a fit that has such a
+#: coordinate has operations under them, so they are no part of
+#: ``DEVICE_SCOPES`` (which every random-effect fit fills).
+MF_SCOPES = (MF_FLATTEN, MF_PROJECT, MF_LATENT, MF_REFIT)
 #: Children of ``FE_SOLVE`` / ``FE_SCORE``: an operation under one of them
 #: counts there, and what is left of the parent's row is the d-space work
 #: (the two-loop, the line search's n-vectors). Not in ``DEVICE_SCOPES``:
@@ -69,7 +80,8 @@ def cd_coordinate(name: str) -> str:
 
 
 def re_size_class(rows: int) -> str:
-    """Child of ``RE_SOLVE``: one per bucket size class (padded rows)."""
+    """Child of ``RE_SOLVE`` and of ``MF_LATENT``: one per bucket size
+    class (padded rows)."""
     return f"r{int(rows)}"
 
 
@@ -116,6 +128,17 @@ GAUGE_RE_FALLBACK_ENTITIES = "training.re.fallback_entities"
 GAUGE_RE_SCORE_ROWS = "training.re.score.rows"
 GAUGE_RE_SCORE_UNSLOTTED_ROWS = "training.re.score.unslotted_rows"
 
+# -- gauges set when a factored random-effect coordinate is built --------------
+#: Summed over the fit's factored coordinates: the latent width k, the slots
+#: of the blocks they solve over (entities x padded rows, every class), and
+#: the entities whose latent solve (at width k, not d: the guard decides by
+#: ``r x k``) goes to the fused kernel or to the vmapped solver. The
+#: ``training.re.*`` gauges above leave these coordinates out.
+GAUGE_MF_FACTORS = "training.mf.factors"
+GAUGE_MF_SLOTS = "training.mf.slots"
+GAUGE_MF_KERNEL_ENTITIES = "training.mf.kernel_entities"
+GAUGE_MF_FALLBACK_ENTITIES = "training.mf.fallback_entities"
+
 # -- gauges of a fit whose fixed effect's matrix is sparse ---------------------
 #: Summed over the fixed-effect coordinates whose matrix came from the
 #: chooser (``ops.features.layout_counts``): the stored values that are not
@@ -153,6 +176,12 @@ COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
 #: here. 0 where no fixed effect is sparse; a TRON, OWL-QN or bounded solve
 #: (whose iterations are not products) adds nothing.
 COUNTER_FE_PRODUCTS = "training.fe.products"
+#: Per run, over its factored coordinates' updates: the alternations (latent
+#: solves then a refit of B) they ran, and the solver iterations of those
+#: refits, from the trackers' ``iterations`` (a fetch: only while telemetry
+#: is enabled). 0 where no coordinate is factored.
+COUNTER_MF_ALTERNATIONS = "training.mf.alternations"
+COUNTER_MF_REFIT_ITERATIONS = "training.mf.refit_iterations"
 #: Per run, the random-effect coordinates built over a mesh: each divides
 #: its score exchange over it (each device gathers the residual into the
 #: slots of its own entities and the margins into its own rows, one
