@@ -15,11 +15,16 @@ two functions here before it is added to the program. With ``csr`` only
 the program's two layouts are timed, side by side on the same data: the
 flat triplet ``choose_layout`` weighs the slot-major ELL against
 (``CSRFeatures``: a gather and a segment-sum a non-zero) and the ELL
-itself, into ``chiprun_out/probe_csr.json``. With ``codes`` (PR 36) ONE
+itself (with every slot's distinct columns and the coded slots' classes),
+into ``chiprun_out/probe_csr.json``. With ``codes`` (PR 36) ONE
 slot of n rows is read by gather from ``f32[d]`` and, through a code a row
 and a table of V entries, by every candidate form of ``code_forms``, for V
-in ``CODE_WIDTHS``: the table ``ops.features.CODED_SLOT_WIDTH`` was set
-from (``chiprun_out/probe_codes.json``; docs/SCALE.md has the law).
+in ``CODE_WIDTHS``: the table ``ops.features.CODED_SLOT_TOP_CLASS`` was set
+from (``chiprun_out/probe_codes.json``; docs/SCALE.md has the law). Since
+PR 39 ``program`` is the program's own ``_lookup`` (the lane gather in a
+Pallas kernel), and ``program_b<block>_g<groups>`` the same kernel at other
+sizes than the ones it ships with (rows of codes a grid step, groups of the
+table a loop step).
 """
 import functools
 import json
@@ -79,9 +84,11 @@ def V_rmv_flat(cols, vals, u, d):
 # step of ``SlotMajorEllFeatures._by_row`` adds. ``idx`` is the slot's column
 # ids (``gather_d``: the table is all of w) or its codes (the table is the
 # slot's V dictionary entries of w).
-CODE_WIDTHS = (1, 3, 64, 128, 256, 512, 1024, 2048, 4096)
+CODE_WIDTHS = (1, 3, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384,
+               32768, 65536)
 LANES = 128
-PALLAS_ROWS = 2048  # rows of 128 codes a grid step, at most
+# rows of codes a grid step and groups a loop step, beside the program's own
+KERNEL_SIZES = ((512, 4), (512, 16), (1024, 8), (256, 8))
 
 
 def C_gather(out, x, idx, table):
@@ -111,13 +118,31 @@ def C_chain_chunked(out, x, code, table):
     return out + x * t
 
 
-def C_program(out, x, code, table):
-    """The form the program runs (``ops.features._select``): a loop over
-    chunks of the table, inside a chunk a tree of selects on the code's low
-    bits, then one compare of its high bits and one select."""
-    from photon_ml_tpu.ops.features import _select
+def C_program(out, x, codes, table):
+    """The form the program runs (``ops.features._lookup`` and
+    ``_add_term``): the table along the 128 lanes (one row a group of 128
+    entries), the codes ``[1, rows, 128]``; one lane-local dynamic gather
+    and one select a group."""
+    from photon_ml_tpu.ops import features as F
 
-    return out + x * _select(code, table)
+    found = F._lookup(codes, table, jnp.zeros((1,), jnp.int32))
+    return F._add_term(out, x, found, fenced=F._off_tpu())
+
+
+def program_at(block: int, groups: int):
+    """``C_program`` with the kernel's rows of codes a grid step and its
+    groups a loop step set otherwise: the two are static arguments of the
+    program's ``_lookup_call``, so every pair is traced and compiled on its
+    own."""
+    from photon_ml_tpu.ops import features as F
+
+    def form(out, x, codes, table):
+        found = F._lookup_call(jnp.zeros((1,), jnp.int32), codes, table,
+                               interpret=F._off_tpu(), block=block,
+                               groups=groups)
+        return F._add_term(out, x, found, fenced=F._off_tpu())
+
+    return form
 
 
 def C_tree(out, x, code, table):
@@ -146,60 +171,27 @@ def C_onehot(out, x, code, table):
     return out + x * jnp.dot(hot, table, precision="highest")
 
 
-def _lane_gather_kernel(code_ref, table_ref, t_ref):
-    code = code_ref[...].astype(jnp.int32)
-    groups = table_ref.shape[0]
-    t = jnp.zeros(code.shape, jnp.float32)
-    for g in range(groups):
-        row = jnp.broadcast_to(table_ref[g:g + 1, :], code.shape)
-        got = jnp.take_along_axis(row, code & (LANES - 1), axis=1)
-        t = got if groups == 1 else jnp.where(code >> 7 == g, got, t)
-    t_ref[...] = t
-
-
-def C_pallas(out, x, code2d, table):
-    """The lane-local dynamic gather of the TPU's vector unit: the table
-    lies along the 128 lanes (one row a group of 128 entries), the codes
-    ``[rows, 128]``; one gather a vector register and group."""
-    from jax.experimental import pallas as pl
-
-    groups = -(-table.shape[0] // LANES)
-    tab = jnp.pad(table, (0, groups * LANES - table.shape[0])).reshape(
-        groups, LANES)
-    rows = code2d.shape[0]
-    step = PALLAS_ROWS // max(1, groups // 8)  # wide tables: smaller blocks
-    t = pl.pallas_call(
-        _lane_gather_kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-        grid=(rows // step,),
-        in_specs=[pl.BlockSpec((step, LANES), lambda i: (i, 0)),
-                  pl.BlockSpec((groups, LANES), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((step, LANES), lambda i: (i, 0)),
-        interpret=jax.default_backend() != "tpu",
-    )(code2d, tab)
-    return out + x * t.reshape(-1)[:out.shape[0]]
-
-
 def code_forms(v: int) -> dict:
     """``{name: (function, the codes' dtype)}``: the candidates at width
     ``v`` (the unrolled forms only where their text stays compilable, the
     MXU form only where its ``[n, V]`` operand could fit)."""
     narrow = jnp.uint8 if v <= 256 else jnp.uint16
-    forms = {"gather_v_i32": (C_gather, jnp.int32),
-             "pallas_i32": (C_pallas, jnp.int32),
-             "pallas_narrow": (C_pallas, narrow)}
+    forms = {"program": (C_program, jnp.uint16)}
+    for block, groups in KERNEL_SIZES:
+        forms[f"program_b{block}_g{groups}"] = (program_at(block, groups),
+                                                jnp.uint16)
+    if v <= 4096:  # past it only the kernel is a candidate
+        forms["gather_v_i32"] = (C_gather, jnp.int32)
+    if 64 <= v <= 4096:
+        forms["chain_chunked_i32"] = (C_chain_chunked, jnp.int32)
+        forms["chain_chunked_narrow"] = (C_chain_chunked, narrow)
     if v <= 256:  # at 1,024 the unrolled text compiles for 13-22 s
         forms["chain_narrow"] = (C_chain, narrow)
         forms["chain_i32"] = (C_chain, jnp.int32)
-    if v >= 64:
-        forms["chain_chunked_i32"] = (C_chain_chunked, jnp.int32)
-        forms["chain_chunked_narrow"] = (C_chain_chunked, narrow)
-        forms["program_narrow"] = (C_program, narrow)
-    if 2 <= v <= 256 and v & (v - 1) == 0:
-        forms["tree_i32"] = (C_tree, jnp.int32)
-    if v <= 256:
         forms["reduce_i32"] = (C_reduce, jnp.int32)
         forms["reduce_narrow"] = (C_reduce, narrow)
+    if 2 <= v <= 256 and v & (v - 1) == 0:
+        forms["tree_i32"] = (C_tree, jnp.int32)
     if v <= 128:
         forms["onehot_i32"] = (C_onehot, jnp.int32)
     return forms
@@ -207,11 +199,18 @@ def code_forms(v: int) -> dict:
 
 def code_form_shapes(name, n, v, code_dt, shape_of):
     """The arguments of ``code_forms(v)[name]`` as shapes (``shape_of(shape,
-    dtype)``: a ``ShapeDtypeStruct`` for a described chip, or an array)."""
-    rows = -(-n // (LANES * PALLAS_ROWS)) * PALLAS_ROWS
-    idx = (rows, LANES) if name.startswith("pallas") else (n,)
+    dtype)``: a ``ShapeDtypeStruct`` for a described chip, or an array). The
+    program's kernel takes a slot's codes as the program stores them, and a
+    table of whole groups of 128."""
+    from photon_ml_tpu.ops.features import _code_stride, _slot_class
+
+    kernel = name.startswith("program")
+    step = max(block for block, _ in KERNEL_SIZES)  # whole steps of each
+    rows = -(-_code_stride(n) // (step * LANES)) * step
+    idx = (1, rows, LANES) if kernel else (n,)
     return (shape_of((n,), jnp.float32), shape_of((n,), jnp.float32),
-            shape_of(idx, code_dt), shape_of((v,), jnp.float32))
+            shape_of(idx, code_dt),
+            shape_of((_slot_class(v) if kernel else v,), jnp.float32))
 
 
 def coded_slot(rows: int, widths=CODE_WIDTHS) -> dict:
@@ -235,13 +234,15 @@ def coded_slot(rows: int, widths=CODE_WIDTHS) -> dict:
         table = w[dictionary]
         ref = timed(row, "gather_d", jax.jit(C_gather), (acc, x, cols, w), n)
         for name, (fn, code_dt) in code_forms(v).items():
-            idx = code.astype(code_dt)
-            if name.startswith("pallas"):
-                shape = code_form_shapes(name, n, v, code_dt,
-                                         lambda s, _: s)[2]
-                idx = jnp.pad(idx, (0, shape[0] * LANES - n)).reshape(shape)
+            idx, entries = code.astype(code_dt), table
+            if name.startswith("program"):
+                shapes = code_form_shapes(name, n, v, code_dt,
+                                          lambda s, _: s)
+                idx = jnp.pad(idx, (0, shapes[2][1] * LANES - n)).reshape(
+                    shapes[2])
+                entries = jnp.pad(table, (0, shapes[3][0] - v))
             try:
-                timed(row, name, jax.jit(fn), (acc, x, idx, table), n, ref)
+                timed(row, name, jax.jit(fn), (acc, x, idx, entries), n, ref)
             except Exception as e:  # a form the compiler refuses: recorded
                 row[name] = {"failed": f"{type(e).__name__}: {e}"[:400]}
                 print(name, json.dumps(row[name]), flush=True)
@@ -296,6 +297,11 @@ def program_layouts(rows: int) -> dict:
     rmatvec = jax.jit(lambda f, v: f.rmatvec(v))
     ell = F.sparse_rows_to_device(p.cols, p.vals, d)
     out["chosen"] = F.layout_counts(ell).layout
+    # what the coded side was built from: every slot's distinct columns, the
+    # slots read by code and each one's class (PR 39)
+    out["distinct"] = [int(v) for v in jax.device_get(F._slot_dictionaries(
+        ell.cols, n_rows=n, n_features=d)[0])]
+    out["coded"], out["classes"] = list(ell.coded), list(ell.classes)
     ref_mv = timed(out, "ell_matvec", matvec, (ell, w), n * k)
     ref_rmv = timed(out, "ell_rmatvec", rmatvec, (ell, u), n * k)
     del ell

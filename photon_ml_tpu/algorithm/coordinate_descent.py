@@ -191,6 +191,8 @@ class CoordinateDescent:
                 max(c.max_col_degree for c in sparse))
             telemetry.gauge(scopes.GAUGE_FE_CODED_SLOTS).set(
                 sum(c.coded_slots for c in sparse))
+            telemetry.gauge(scopes.GAUGE_FE_CODED_ENTRIES).set(
+                sum(c.coded_entries for c in sparse))
         # A factored coordinate's classes are solved at its latent width,
         # and counted apart from the random effects' (``training.mf.*``).
         factored = [c for c in self.coordinates.values() if c.factored]
@@ -352,7 +354,8 @@ class CoordinateDescent:
 
             note_layout(scopes.CD_BLOCK, ",".join(sorted(
                 {c.layout for c in sparse})),
-                sum(c.coded_slots for c in sparse))
+                sum(c.coded_slots for c in sparse),
+                sum(c.coded_entries for c in sparse))
         self._block_fns[cache_key] = fn
         self.tracing_guard.track(
             f"block:{n_iters}" if whole

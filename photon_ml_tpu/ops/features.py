@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from photon_ml_tpu.telemetry import scopes
 
@@ -965,24 +967,38 @@ def sort_permute_ell_from_scipy(mat, max_groups: int = 8,
 
 
 
-#: The most distinct columns a slot of a slot-major ELL may name and still
-#: be read by code (``SlotMajorEllFeatures``, coded slots). Measured on a
-#: TPU v5e, one slot of 9,168,123 rows (PERF.md section 5, PR 36;
-#: ``dev_scripts/sparse_products_probe.py <rows> codes`` reads it again): by
-#: gather from ``f32[1000001]`` 61.9 ms; by ``_select`` over a table of 128
-#: entries 1.4 ms, of 512 2.4, of 1,024 4.0, of 2,048 7.0, of 4,096 12.9
-#: (1.0 ms of passes over n-vectors + 2.9 us an entry), bitwise the
-#: gather's result. A coded slot pays for the WIDTH, not for the columns
-#: it names: its dictionary is padded to it, so that the compiled products
-#: depend on which slots are coded and never on their counts. 1,024 is
-#: where the click log the cell ``sparse-lr.fit`` is shaped after stops
-#: gaining: its fields of 583, 305 and 633 values join the 22 slots of at
-#: most 128 (25 x 4.0 + 15 x 61.9 = 1,029 ms a product; at 128: 22 x 1.4 +
-#: 18 x 61.9 = 1,145; at 2,048 one field more, 1,048).
-CODED_SLOT_WIDTH = 1024
-_CODE_DTYPE = jnp.uint16  # the narrowest that holds 0 .. CODED_SLOT_WIDTH - 1
-_CODE_ALIGN = 4096  # codes a slot start on a tile boundary of the narrow type
-_SELECT_CHUNK = 32  # table entries a step of ``_select``; 64 is no faster
+#: The widest table a coded slot's dictionary may be padded to
+#: (``SlotMajorEllFeatures``, coded slots): a slot that names at most this
+#: many distinct columns over all rows is read by code, its dictionary padded
+#: to the smallest power of two that holds it (its CLASS, at least the 128
+#: lanes of a vector register), so that the compiled products depend on which
+#: slots are coded and on their classes, never on their counts. A coded slot
+#: pays for its class. Measured on a TPU v5e, one slot of 9,168,123 rows
+#: (PERF.md section 5, PR 39; ``dev_scripts/sparse_products_probe.py <rows>
+#: codes`` reads it again), ``out + x * table[code]`` bitwise the gather's
+#: result at every class: by gather from ``f32[1000001]`` 61.9 ms; by
+#: ``_lookup`` 0.95 ms at 128 entries, 0.91 at 256, 1.06 at 512, 1.18 at
+#: 1,024, 1.54 at 2,048, 2.14 at 4,096, 3.59 at 8,192, 6.11 at 16,384, 11.2 at
+#: 32,768, 21.8 at 65,536 (0.9 ms of passes over n-vectors + 0.041 ms a group
+#: of 128 entries: 4.5 ns a vector register of codes and group). Alone, every
+#: class a ``uint16`` code can name costs under half a slot by gather; a
+#: class is admitted where it has also been read IN A JOB (PR 36: a form
+#: right alone can be wrong there), and 16,384 is the widest that has: the
+#: cell ``sparse-lr.fit`` codes 32 slots in the classes 128 to 16,384, 0.73 ms
+#: a slot in its ``cd_block`` (its next fields name 89,000 columns and more).
+#: 32,768 and 65,536 wait for a matrix that has such a slot.
+CODED_SLOT_TOP_CLASS = 16384
+_CODE_DTYPE = jnp.uint16  # the narrowest that holds 0 .. CODED_SLOT_TOP_CLASS - 1
+_LANES = 128  # a vector register's lanes: a group of a table, the least class
+_CODE_ALIGN = 4096  # a slot's codes are whole tiles of the narrow type
+_LOOKUP_BLOCK_ROWS = 512  # rows of 128 codes a grid step of ``_lookup``
+_LOOKUP_GROUPS = 8  # groups of the table a step of the kernel's loop
+
+
+def _slot_class(distinct: int) -> int:
+    """The table width of a slot that names ``distinct`` columns: the
+    smallest power of two that holds them, at least one group of lanes."""
+    return max(_LANES, 1 << (int(distinct) - 1).bit_length())
 
 
 def _runs(coded: Tuple[int, ...], k: int):
@@ -996,32 +1012,108 @@ def _runs(coded: Tuple[int, ...], k: int):
 
 
 def _code_stride(n_rows: int) -> int:
-    """Where the next coded slot's codes begin: ``n_rows`` rounded up."""
-    return -(-n_rows // _CODE_ALIGN) * _CODE_ALIGN
+    """The codes a coded slot stores: ``n_rows`` rounded up to whole grid
+    steps of ``_lookup`` (to whole tiles where one step holds them all)."""
+    step = _LOOKUP_BLOCK_ROWS * _LANES
+    align = step if n_rows > step else _CODE_ALIGN
+    return -(-n_rows // align) * align
 
 
-@jax.checkpoint
-def _select(code: Array, table: Array) -> Array:
-    """``table[code]`` without an index operation, ``_SELECT_CHUNK`` table
-    entries a step of a loop: inside a chunk one select an entry on the
-    code's low bits (a tree: 31 for 32 entries), then one compare of its
-    high bits and one select. Every step is one elementwise fusion; nothing
-    of size ``[len(table), n]`` exists. Exact: the entry selected, or 0 for
-    a code past the table. Linear in ``table``; differentiated, it
-    recomputes its compares and keeps none."""
-    bits = _SELECT_CHUNK.bit_length() - 1
-    low = [((code >> b) & 1) != 0 for b in range(bits)]
-    high = code >> bits
+def _off_tpu() -> bool:
+    """Whether ``_lookup``'s kernel is interpreted (asked while tracing)."""
+    return jax.default_backend() != "tpu"
 
-    def step(i, out):
-        level = [table[i * _SELECT_CHUNK + j] for j in range(_SELECT_CHUNK)]
-        for bit in low:
-            level = [jnp.where(bit, level[a + 1], level[a])
-                     for a in range(0, len(level), 2)]
-        return jnp.where(high == i.astype(code.dtype), level[0], out)
 
-    return lax.fori_loop(0, table.shape[0] // _SELECT_CHUNK, step,
-                         jnp.zeros(code.shape, table.dtype))
+def _lookup_kernel(j_ref, code_ref, table_ref, out_ref, *, groups: int):
+    """A block of codes against the whole table, which lies along the
+    lanes, 128 entries a group: one lane-local dynamic gather by the code's
+    low seven bits and one select on its high bits a group, ``groups`` of
+    them a step of the loop (a step takes ~100 ns however little it holds:
+    PERF.md section 6, PR 39)."""
+    del j_ref  # the block's place, read by the index map
+    code = code_ref[...].astype(jnp.int32)
+    lane, high = code & (_LANES - 1), code >> 7
+
+    def some_groups(i, found):
+        for g in range(groups):
+            g = i.astype(jnp.int32) * groups + g
+            row = jnp.broadcast_to(table_ref[pl.ds(g, 1), :], code.shape)
+            found = jnp.where(
+                high == g, jnp.take_along_axis(row, lane, axis=1), found)
+        return found
+
+    out_ref[...] = lax.fori_loop(
+        0, table_ref.shape[0] // groups, some_groups,
+        jnp.zeros(code.shape, out_ref.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block", "groups"))
+def _lookup_call(j, codes, table, interpret: bool,
+                 block: int = _LOOKUP_BLOCK_ROWS,
+                 groups: int = _LOOKUP_GROUPS):
+    """One compiled program a class, whichever slot (traced once, so that
+    a product run eagerly does not compile its kernels anew a call).
+    ``block`` and ``groups`` are the program's two constants; the probe
+    that chose them (``dev_scripts/sparse_products_probe.py``) passes
+    others."""
+    rows = codes.shape[1]
+    block = min(block, rows)
+    table = table.reshape(-1, _LANES)
+    return pl.pallas_call(
+        functools.partial(_lookup_kernel,
+                          groups=min(groups, table.shape[0])),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), table.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // block,),
+            in_specs=[
+                pl.BlockSpec((None, block, _LANES), lambda i, j: (j[0], i, 0)),
+                pl.BlockSpec(table.shape, lambda i, j: (0, 0))],
+            out_specs=pl.BlockSpec((block, _LANES), lambda i, j: (i, 0))),
+        name="coded_slot_lookup",
+        interpret=interpret,
+    )(j, codes, table)
+
+
+@jax.custom_jvp
+def _lookup(codes: Array, table: Array, j: Array) -> Array:
+    """``table[codes[j[0]]]`` by the vector unit's lane-local dynamic
+    gather, in a Pallas kernel (interpreted off the TPU): ``codes`` ``[m,
+    rows, 128]``, read in place, ``table`` a whole number of groups of 128
+    entries, resident in VMEM (64 KB at the top class). Exact: the entry
+    the code names, or 0 for a code past the table. Linear in ``table``:
+    differentiated, it is XLA's gather by code, whose transpose is the
+    scatter-add of the cotangent into the table (what a gathered slot
+    differentiates to). Called inside a staged loop it differentiates once
+    and not twice (``SlotMajorEllFeatures`` says why), so ``_by_row`` calls
+    it slot by slot and in no loop of its own."""
+    return _lookup_call(j, codes, table, interpret=_off_tpu())
+
+
+@_lookup.defjvp
+def _lookup_jvp(primals, tangents):
+    codes, table, j = primals
+    return _lookup(codes, table, j), tangents[1].at[
+        codes[j[0]].astype(jnp.int32)].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.jit, static_argnames=("fenced",))
+def _add_term(out: Array, x: Array, found: Array, fenced: bool) -> Array:
+    """``out + x * found``: a coded slot's term of every row's sum, after
+    the terms before it. ``fenced`` (off the TPU), the sum so far passes an
+    optimization barrier first, so that the multiply-add is compiled alone,
+    as a step of the gathered loop is, and rounds as that step rounds. The
+    CPU backend contracts a multiply and the add it feeds into one rounding
+    where it finds them in one fusion, and a run of terms fused into ONE
+    expression it is free to contract otherwise than the loop's one term a
+    step: unfenced, a sum with coded slots differed from the all-gathered
+    one by a rounding in a row in sixteen, and two fits that should agree
+    to 3e-7 ended 3.8e-4 apart. The TPU rounds every product and every sum
+    (bitwise equal unfenced, PERF.md section 6, PR 39), and there a run of
+    terms stays one pass over the n-vectors."""
+    if fenced:
+        out = lax.optimization_barrier(out)
+    return out + x * found.reshape(-1)[:out.shape[0]]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -1043,18 +1135,32 @@ class SlotMajorEllFeatures:
     scatter-add 1% at that log's skew (30% if EVERY update lands on one
     address), so the scatter side treats no column apart.
 
-    **Coded slots** (PR 36; the row-wise products only). Where rows come
-    field by field, a slot names few columns: the intercept's one, a binned
-    field's 64. Such a slot (at most ``CODED_SLOT_WIDTH`` distinct columns
+    **Coded slots** (PR 36, width classes since PR 39; the row-wise products
+    only). Where rows come field by field, a slot names few columns: the
+    intercept's one, a binned field's 64, a categorical field's few
+    thousand. Such a slot (at most ``CODED_SLOT_TOP_CLASS`` distinct columns
     over ALL rows, counted where the matrix is built) also carries a code a
-    row and a dictionary of its columns, and ``matvec`` / ``row_sq_matvec``
-    read it as ``select(code, v[dictionary])``: lane-wise compares against
-    the slot's few table entries in place of n gathers from ``v``, and the
-    number selected is the number the gather fetches. Slots are visited in
-    slot order, coded or not, so a row's sum has the same terms in the same
-    order; a matrix with no such slot runs the one loop it ran before.
-    ``cols`` and ``vals`` stay whole: the column-wise products, ``to_csr``
-    and everything that unrolls the layout read them and ignore the codes.
+    row and a dictionary of its columns, padded to the slot's class
+    (``_slot_class``), and ``matvec`` / ``row_sq_matvec`` read it as
+    ``lookup(code, v[dictionary])``: the vector unit's lane gather against
+    the slot's table, held in VMEM, in place of n gathers from ``v``, and
+    the number looked up is the number the gather fetches. Slots are
+    visited in slot order, coded or not, so a row's sum has the same terms
+    in the same order; a matrix with no such slot runs the one loop it ran
+    before. ``cols`` and ``vals`` stay whole: the column-wise products,
+    ``to_csr`` and everything that unrolls the layout read them and ignore
+    the codes.
+
+    Under ``jax.vmap`` over the vector, and differentiated (``jax.grad``,
+    ``jax.jvp``, one over the other as TRON's Hessian-vector product is),
+    the row-wise products behave as the gathered ones do. ONE RESTRICTION:
+    a staged loop (``lax.scan``, ``fori_loop``) with a row-wise product in
+    its body can be differentiated THROUGH once, either way, but not twice:
+    JAX keeps no derivative rule of a function's own through a loop's
+    partial evaluation, so the second derivative meets the kernel itself
+    and raises ``NotImplementedError``. Derivatives taken INSIDE a loop's
+    body are not through the loop and are fine at any order: TRON's
+    conjugate-gradient loop takes its Hessian-vector products so.
     """
 
     cols: Array  # i32[k * n], slot-major
@@ -1063,13 +1169,14 @@ class SlotMajorEllFeatures:
     n_features: int
     # what the chooser counted, where it built this matrix (static, aux data)
     counts: Optional["LayoutCounts"] = None
-    # the coded slots' codes, slot after slot in the order of ``coded``, each
-    # slot's n codes at a stride of ``_code_stride(n)``; their dictionaries
-    # i32[len(coded), CODED_SLOT_WIDTH] (ascending, padded with column 0);
-    # and which slots they are (ascending; static, aux data)
+    # the coded slots' codes, ``[len(coded), _code_stride(n) / 128, 128]`` in
+    # the order of ``coded``; their dictionaries end to end (ascending, each
+    # padded with column 0 to its slot's class); which slots they are
+    # (ascending) and each one's class (static, aux data)
     codes: Optional[Array] = None
     dicts: Optional[Array] = None
     coded: Tuple[int, ...] = ()
+    classes: Tuple[int, ...] = ()
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -1101,27 +1208,26 @@ class SlotMajorEllFeatures:
         out = jnp.zeros((n,), acc)
         if not self.coded:
             return lax.fori_loop(0, k, gathered, out)
-        # every coded slot's table entries: len(coded) * WIDTH gathers
-        tables = v.at[self.dicts].get(mode="promise_in_bounds")
-        stride = _code_stride(n)
-        # A loop a run of like slots, in slot order. (One loop over all
+        # every coded slot's table entries: sum(classes) gathers
+        tables = v.at[self.dicts].get(mode="promise_in_bounds").astype(acc)
+        starts = (0, *itertools.accumulate(self.classes))
+        # A loop a run of gathered slots, in slot order. (One loop over all
         # slots with a ``cond`` a slot compiles to a ninth of the text, but
         # a gather inside a conditional's branch reads its indices from
-        # HBM, 108 ms a slot against 61.9: PERF.md section 6, PR 36.)
+        # HBM, 108 ms a slot against 61.9: PERF.md section 6, PR 36.) The
+        # coded slots one by one, each a kernel call of its own class.
         for first, stop, at in _runs(self.coded, k):
             if at is None:
                 with jax.named_scope(scopes.FE_MATVEC_GATHERED):
                     out = lax.fori_loop(first, stop, gathered, out)
                 continue
-
-            def by_code(s, out, shift=first - at):
-                _, x = self._slot(s, square, acc)
-                j = s - shift
-                code = lax.dynamic_slice(self.codes, (j * stride,), (n,))
-                return out + x * _select(code, tables[j])
-
             with jax.named_scope(scopes.FE_MATVEC_CODED):
-                out = lax.fori_loop(first, stop, by_code, out)
+                for j in range(at, at + stop - first):
+                    _, x = self._slot(self.coded[j], square, acc)
+                    found = _lookup(self.codes,
+                                    tables[starts[j]:starts[j + 1]],
+                                    jnp.full((1,), j, jnp.int32))
+                    out = _add_term(out, x, found, fenced=_off_tpu())
         return out
 
     def _by_column(self, u: Array, square: bool) -> Array:
@@ -1162,14 +1268,15 @@ class SlotMajorEllFeatures:
 
     def tree_flatten(self):
         return ((self.cols, self.vals, self.codes, self.dicts),
-                (self.n_rows, self.n_features, self.counts, self.coded))
+                (self.n_rows, self.n_features, self.counts, self.coded,
+                 self.classes))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         cols, vals, codes, dicts = children
-        n_rows, n_features, counts, coded = aux
+        n_rows, n_features, counts, coded, classes = aux
         return cls(cols, vals, n_rows, n_features, counts, codes, dicts,
-                   coded)
+                   coded, classes)
 
 
 FeatureMatrix = Union[DenseFeatures, CSRFeatures, BlockedCSRFeatures,
@@ -1194,6 +1301,7 @@ class LayoutCounts:
     layout: str = ""  # the layout chosen
     slots: int = 0  # the slots that layout stores
     coded_slots: int = 0  # of the k slots a row, those read by code
+    coded_entries: int = 0  # their tables' entries, each padded to its class
 
 
 def choose_layout(counts: LayoutCounts) -> str:
@@ -1249,10 +1357,11 @@ def _prefix_sums(x):
 def _slot_dictionaries(cols, n_rows: int, n_features: int):
     """Of slot-major ``cols``, slot by slot: the distinct columns over all
     rows (exact; a padded slot's column 0 counts) and the first
-    ``CODED_SLOT_WIDTH`` of them ascending, padded with column 0: ``i32[k]``
-    and ``i32[k, CODED_SLOT_WIDTH]``. One scatter of n a slot."""
+    ``CODED_SLOT_TOP_CLASS`` of them ascending, padded with column 0:
+    ``i32[k]`` and ``i32[k, CODED_SLOT_TOP_CLASS]``. One scatter of n a
+    slot."""
     k = cols.shape[0] // n_rows
-    want = jnp.arange(1, CODED_SLOT_WIDTH + 1, dtype=jnp.int32)
+    want = jnp.arange(1, CODED_SLOT_TOP_CLASS + 1, dtype=jnp.int32)
 
     def one(s, carry):
         distinct, dicts = carry
@@ -1265,18 +1374,18 @@ def _slot_dictionaries(cols, n_rows: int, n_features: int):
 
     return lax.fori_loop(0, k, one, (
         jnp.zeros((k,), jnp.int32),
-        jnp.zeros((k, CODED_SLOT_WIDTH), jnp.int32)))
+        jnp.zeros((k, CODED_SLOT_TOP_CLASS), jnp.int32)))
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "n_features"))
 @jax.named_scope(scopes.FE_LAYOUT)
 def _slot_codes(cols, dicts, distinct, coded, n_rows: int, n_features: int):
     """Row by row the place of the row's column in its slot's dictionary,
-    for the slots ``coded`` (``i32[m]``), and those slots' dictionaries:
-    one gather of n a coded slot. The program depends on how many slots
-    are coded, not on which or on their counts."""
+    for the slots ``coded`` (``i32[m]``), 128 to a row of the result, and
+    those slots' dictionaries: one gather of n a coded slot. The program
+    depends on how many slots are coded, not on which or on their counts."""
     stride = _code_stride(n_rows)
-    place = jnp.arange(CODED_SLOT_WIDTH, dtype=jnp.int32)
+    place = jnp.arange(CODED_SLOT_TOP_CLASS, dtype=jnp.int32)
 
     def one(j, codes):
         s = coded[j]
@@ -1290,30 +1399,42 @@ def _slot_codes(cols, dicts, distinct, coded, n_rows: int, n_features: int):
 
     codes = lax.fori_loop(0, coded.shape[0], one, jnp.zeros(
         (coded.shape[0] * stride,), _CODE_DTYPE))
-    return codes, dicts[coded]
+    return codes.reshape(coded.shape[0], -1, _LANES), dicts[coded]
+
+
+@functools.partial(jax.jit, static_argnames=("classes",))
+@jax.named_scope(scopes.FE_LAYOUT)
+def _cut_to_classes(dicts, classes: Tuple[int, ...]):
+    """Row j of ``dicts`` cut to ``classes[j]`` entries, the rows end to
+    end."""
+    return jnp.concatenate([dicts[j, :width]
+                            for j, width in enumerate(classes)])
 
 
 def _slot_major_ell(cols, vals, counts: "LayoutCounts"
                     ) -> SlotMajorEllFeatures:
     """The slot-major arrays as the matrix the program runs, its small
-    slots coded: which are small is counted here, on the device, of which
-    k numbers come back."""
+    slots coded, each in the class its distinct columns fill: which are
+    small is counted here, on the device, of which k numbers come back."""
     n, k, d = counts.n_rows, counts.slots_per_row, counts.n_features
     counts = dataclasses.replace(counts, layout="slot_major_ell",
                                  slots=n * k)
     if n * k == 0:
         return SlotMajorEllFeatures(cols, vals, n, d, counts)
     distinct, dicts = _slot_dictionaries(cols, n_rows=n, n_features=d)
+    named = np.asarray(jax.device_get(distinct))
     coded = tuple(int(s) for s in np.flatnonzero(
-        np.asarray(jax.device_get(distinct)) <= CODED_SLOT_WIDTH))
-    counts = dataclasses.replace(counts, coded_slots=len(coded))
+        named <= CODED_SLOT_TOP_CLASS))
+    classes = tuple(_slot_class(named[s]) for s in coded)
+    counts = dataclasses.replace(counts, coded_slots=len(coded),
+                                 coded_entries=sum(classes))
     if not coded:
         return SlotMajorEllFeatures(cols, vals, n, d, counts)
     codes, dicts = _slot_codes(
         cols, dicts, distinct, jax.device_put(np.asarray(coded, np.int32)),
         n_rows=n, n_features=d)
-    return SlotMajorEllFeatures(cols, vals, n, d, counts, codes, dicts,
-                                coded)
+    return SlotMajorEllFeatures(cols, vals, n, d, counts, codes,
+                                _cut_to_classes(dicts, classes), coded, classes)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz",))

@@ -149,10 +149,13 @@ GAUGE_FE_NNZ = "training.fe.nnz"
 GAUGE_FE_SLOTS = "training.fe.slots"
 GAUGE_FE_MAX_COL_DEGREE = "training.fe.max_col_degree"
 #: Of a row's k slots, those the slot-major ELL reads by code in its
-#: row-wise products (at most ``ops.features.CODED_SLOT_WIDTH`` distinct
+#: row-wise products (at most ``ops.features.CODED_SLOT_TOP_CLASS`` distinct
 #: columns over all rows): 0 says the mechanism found nothing to engage on
-#: (rows sorted by column id scatter the fields over the slots).
+#: (rows sorted by column id scatter the fields over the slots). And the
+#: entries of those slots' tables, each padded to its slot's class: what the
+#: coded side pays for, and how far the classes reached on this matrix.
 GAUGE_FE_CODED_SLOTS = "training.fe.coded_slots"
+GAUGE_FE_CODED_ENTRIES = "training.fe.coded_entries"
 
 # -- gauges of a fit whose coordinates lie over a device mesh (mesh=) ----------
 GAUGE_MESH_DEVICES = "training.mesh.devices"

@@ -55,7 +55,7 @@ _unclaimed = {"retrieval_s": 0.0, "cache_hits": 0}
 # Devices a function's program is partitioned over, where its owner said so
 # (``note_partitions``): JAX's events carry a function's name and no more.
 _partitions: Dict[str, int] = {}
-_layouts: Dict[str, tuple] = {}
+_layouts: Dict[str, dict] = {}
 
 
 def _function_name(fun_name) -> str:
@@ -108,14 +108,18 @@ def note_partitions(fun_name: str, partitions: int) -> None:
         _partitions[str(fun_name)] = int(partitions)
 
 
-def note_layout(fun_name: str, layout: str, coded_slots: int = 0) -> None:
+def note_layout(fun_name: str, layout: str, coded_slots: int = 0,
+                coded_entries: int = 0) -> None:
     """The owner of a jitted function that runs a sparse fixed effect says
-    in which layout the chooser put its matrix, and how many of a row's
-    slots that layout reads by code: the ledger's row of that name reads
-    ``fe_layout`` and ``fe_coded_slots`` (no such keys where nobody
+    in which layout the chooser put its matrix, how many of a row's slots
+    that layout reads by code and how many entries their padded tables
+    hold: the ledger's row of that name reads ``fe_layout``,
+    ``fe_coded_slots`` and ``fe_coded_entries`` (no such keys where nobody
     spoke)."""
     with _lock:
-        _layouts[str(fun_name)] = (str(layout), int(coded_slots))
+        _layouts[str(fun_name)] = dict(
+            fe_layout=str(layout), fe_coded_slots=int(coded_slots),
+            fe_coded_entries=int(coded_entries))
 
 
 def compile_ledger(top: Optional[int] = None) -> dict:
@@ -129,17 +133,17 @@ def compile_ledger(top: Optional[int] = None) -> dict:
     where it hit: ``retrieval_s`` and ``cache_hits`` are that part), and
     how often each happened (``traces``, ``lowerings``, ``compiles``),
     and ``partitions``, the devices the program was lowered for
-    (``note_partitions``; 1 where nobody said); ``fe_layout`` and
-    ``fe_coded_slots`` where the function runs a sparse fixed effect
-    (``note_layout``).
+    (``note_partitions``; 1 where nobody said); ``fe_layout``,
+    ``fe_coded_slots`` and ``fe_coded_entries`` where the function runs a
+    sparse fixed effect (``note_layout``).
     Names are the jitted functions' (``cd_block``), as JAX reports them.
     Totals add ``cache_requests``; requests minus hits were compiled."""
     with _lock:
         rows = {k: dict(v, partitions=_partitions.get(k, 1))
                 for k, v in _functions.items()}
-        for k, (layout, coded_slots) in _layouts.items():
+        for k, layout in _layouts.items():
             if k in rows:
-                rows[k].update(fe_layout=layout, fe_coded_slots=coded_slots)
+                rows[k].update(layout)
         totals = dict(_totals)
     if top is not None:
         cost = lambda r: r["trace_s"] + r["lower_s"] + r["backend_s"]

@@ -233,42 +233,102 @@ def test_kernel_compiles_under_shard_map(topo, tpu_lowering):
     assert 0 < memory.argument_size_in_bytes < V5E_HBM_BYTES
 
 
-def test_the_coded_matvec_compiles_at_the_sparse_cells_shape(one_chip,
-                                                             tpu_lowering):
-    """``sparse-lr.fit``'s matvec (PR 36): 9,168,123 rows x 40 slots, the
-    25 slots whose field has at most ``CODED_SLOT_WIDTH`` values read by
-    code. The compiled program's temporaries are n-vectors (0.56 GB
-    together, nothing of size ``[width, n]``), its gathers sit in plain loops (a
-    conditional's branch is out of the memory-space assignment's reach:
-    PERF.md section 6), and the gather is gone from the coded slots."""
+@pytest.fixture
+def lookup_on_the_chip(monkeypatch):
+    """``ops.features._lookup`` asks the attached backend (the CPU, here)
+    whether to interpret its kernel: these tests lower it for the chip."""
+    from photon_ml_tpu.ops import features as F
+
+    monkeypatch.setattr(F, "_off_tpu", lambda: False)
+    return F
+
+
+def _cell_shape():
+    """``sparse-lr.fit``'s rows, slots and columns, and the distinct
+    columns each slot names at that size (a field draws ranks 1 .. card - 1;
+    the last slot is the intercept)."""
     import json
     import pathlib
-    import re
-
-    from photon_ml_tpu.ops import features as F
 
     config = json.loads((pathlib.Path(__file__).resolve().parent.parent
                          / "benchmark/configs/sparse-lr-criteo.json"
                          ).read_text())
     fields = config["fixed"]["fields"]
-    n, k, d = config["n_rows"], len(fields) + 1, config["fixed"]["d"]
-    coded = tuple(f for f, card in enumerate(fields)
-                  if card - 1 <= F.CODED_SLOT_WIDTH) + (k - 1,)
-    assert len(coded) == 25
+    return (config["n_rows"], len(fields) + 1, config["fixed"]["d"],
+            [card - 1 for card in fields] + [1])
+
+
+@pytest.mark.parametrize("width", [128, 1024, 16384, 65536])
+def test_the_lookup_kernel_compiles_at_every_class(one_chip, tpu_lowering,
+                                                   lookup_on_the_chip, width):
+    """``_lookup`` (PR 39) over one slot of the cell's 9,168,123 rows, read
+    in place from the codes of three: the lane gather, the dynamic row of
+    the table and the ``uint16`` blocks pass Mosaic, the table (64 KB at
+    the top class, 256 KB at the widest a ``uint16`` code names, which the
+    probe reads) fits VMEM, and nothing is copied around the call."""
+    F = lookup_on_the_chip
+    n = _cell_shape()[0]
+    s = _struct(one_chip)
+    compiled = jax.jit(lambda codes, table: F._lookup(
+        codes, table, jnp.ones((1,), jnp.int32))).lower(
+        s((3, F._code_stride(n) // 128, 128), F._CODE_DTYPE),
+        s((width,))).compile()
+    assert "coded_slot_lookup" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_the_coded_matvec_compiles_under_vmap(one_chip, tpu_lowering,
+                                              lookup_on_the_chip):
+    """A batch of vectors over one matrix (a grid of regularisation
+    weights solved at once): the kernel call takes the batch as one more
+    grid axis over tables, the codes and the scalar that places the slot
+    unbatched, and Mosaic takes that too."""
+    F = lookup_on_the_chip
+    n, d, coded, classes = 300000, 50000, (0, 2, 3), (128, 2048, 16384)
+    s = _struct(one_chip)
+    feats = F.SlotMajorEllFeatures(
+        s((4 * n,), jnp.int32), s((4 * n,)), n, d, None,
+        s((len(coded), F._code_stride(n) // 128, 128), F._CODE_DTYPE),
+        s((sum(classes),), jnp.int32), coded, classes)
+    compiled = jax.jit(lambda f, vs: jax.vmap(f.matvec)(vs)).lower(
+        feats, s((5, d))).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(coded)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+def test_the_coded_matvec_compiles_at_the_sparse_cells_shape(
+        one_chip, tpu_lowering, lookup_on_the_chip):
+    """``sparse-lr.fit``'s matvec: 9,168,123 rows x 40 slots, the 32 slots
+    whose field has at most ``CODED_SLOT_TOP_CLASS`` values read by code
+    (PR 36; by the lane-gather kernel in width classes since PR 39), each in
+    its class. The compiled program's temporaries are n-vectors, its
+    gathers sit in plain loops (a conditional's branch is out of the
+    memory-space assignment's reach: PERF.md section 6), the gather is gone
+    from the coded slots, and each of those is one kernel call."""
+    import re
+
+    F = lookup_on_the_chip
+    n, k, d, named = _cell_shape()
+    coded = tuple(f for f, count in enumerate(named)
+                  if count <= F.CODED_SLOT_TOP_CLASS)
+    classes = tuple(F._slot_class(named[f]) for f in coded)
+    assert len(coded) == 32 and sum(classes) == 64768
+    assert sorted(set(classes)) == [128, 512, 1024, 2048, 4096, 8192, 16384]
     s = _struct(one_chip)
     feats = F.SlotMajorEllFeatures(
         s((k * n,), jnp.int32), s((k * n,)), n, d, None,
-        s((len(coded) * F._code_stride(n),), F._CODE_DTYPE),
-        s((len(coded), F.CODED_SLOT_WIDTH), jnp.int32), coded)
+        s((len(coded), F._code_stride(n) // 128, 128), F._CODE_DTYPE),
+        s((sum(classes),), jnp.int32), coded, classes)
     compiled = jax.jit(lambda f, v: f.matvec(v)).lower(
         feats, s((d,))).compile()
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 2 ** 30  # [width, n] is 37.5 GB
+    assert memory.temp_size_in_bytes < 2 ** 30
     text = compiled.as_text()
     assert " conditional(" not in text
-    gathered = len(list(F._runs(coded, k))) - sum(
-        1 for _, _, at in F._runs(coded, k) if at is not None)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == len(coded)
+    gathered = sum(1 for _, _, at in F._runs(coded, k) if at is None)
     # one gather of n a run of gathered slots (the loop's body), and the
-    # dictionaries' own 25 x 1,024 entries
+    # dictionaries' own 64,768 entries
     assert len(re.findall(rf"= f32\[{n}\][^ ]* fusion\([^)]*\), "
                           r"kind=kCustom", text)) == gathered

@@ -410,7 +410,7 @@ def test_a_mesh_fit_takes_a_shard_the_chooser_lays_slot_major(
                              feature_shards={"global": mat}, ids={})
     laid = data.fixed_effect_batch("global").features
     assert isinstance(laid, F.SlotMajorEllFeatures)
-    assert laid.coded == tuple(range(k))  # d <= CODED_SLOT_WIDTH: all of them
+    assert laid.coded == tuple(range(k))  # d <= the top class: all of them
 
     def fit(**kw):
         coord = FixedEffectCoordinate(
@@ -456,7 +456,7 @@ def test_the_device_scorer_takes_shards_the_chooser_lays_slot_major():
     for shard in ("global", "user"):
         laid = F.features_to_device(data.feature_shards[shard])
         assert isinstance(laid, F.SlotMajorEllFeatures)
-        assert laid.coded  # d <= CODED_SLOT_WIDTH: the scorer meets codes
+        assert laid.coded  # d <= the top class: the scorer meets codes
     fe = FixedEffectModel(LogisticRegressionModel(Coefficients(
         jnp.asarray(rng.normal(size=d)))), "global")
     ds = build_random_effect_dataset(
@@ -470,16 +470,18 @@ def test_the_device_scorer_takes_shards_the_chooser_lays_slot_major():
     np.testing.assert_allclose(got, gm.score(data), rtol=1e-10, atol=1e-10)
 
 
-# -- coded slots: a slot that names few columns is read by code (PR 36) ---------
+# -- coded slots: a slot that names few columns is read by code (PR 36), each in
+# -- the class its distinct columns fill (PR 39) --------------------------------
 
-W = F.CODED_SLOT_WIDTH
+TOP = F.CODED_SLOT_TOP_CLASS
+W = 1024  # a class in the middle
 
 
 def fielded_rows(rng, n, d, widths, pad=()):
     """Rows that come field by field, as a click log does: slot f of every
     row names one of ``widths[f]`` columns of its own (a width over
-    ``CODED_SLOT_WIDTH`` is a slot the program gathers); the rows of ``pad``
-    leave their slot 0 empty (value 0 at column 0)."""
+    ``CODED_SLOT_TOP_CLASS`` is a slot the program gathers); the rows of
+    ``pad`` leave their slot 0 empty (value 0 at column 0)."""
     k = len(widths)
     cols = np.empty((n, k), np.int32)
     for f, width in enumerate(widths):
@@ -494,28 +496,37 @@ def fielded_rows(rng, n, d, widths, pad=()):
     return cols, vals
 
 
-WIDTHS = (1, 64, W + 900, W, W + 1, 3, W + 500, W + 200, 7, 1)
-WANT_CODED = (0, 1, 3, 5, 8, 9)
+# a slot of exactly a class's width, and of one more: at the least class, in
+# the middle, and at the top, where one more is a slot the program gathers
+WIDTHS = (1, 64, TOP + 1, W, W + 1, 3, 128, 129, 7, TOP, 4096, 4097)
+WANT_CODED = (0, 1, 3, 4, 5, 6, 7, 8, 9, 10, 11)
+WANT_CLASSES = (128, 128, W, 2 * W, 128, 128, 256, 128, TOP, 4096, 8192)
 
 
 @pytest.fixture(scope="module", params=["float32", "bfloat16"])
 def coded(request):
-    n, d = 3 * W, 5 * W
+    n, d = TOP + 2 * W, 2 * TOP
     cols, vals = fielded_rows(np.random.default_rng(36), n, d, WIDTHS,
                               pad=(5, 17, n - 1))
     vals = jnp.asarray(vals).astype(request.param)
     feats = F.sparse_rows_to_device(jnp.asarray(cols), vals, d)
-    plain = dataclasses.replace(feats, codes=None, dicts=None, coded=())
+    plain = dataclasses.replace(feats, codes=None, dicts=None, coded=(),
+                                classes=())
     return {"feats": feats, "plain": plain, "cols": cols,
             "vals": np.asarray(vals.astype(jnp.float32)), "n": n, "d": d}
 
 
-def test_a_slot_of_exactly_the_width_is_coded_and_one_more_is_not(coded):
+def test_a_slot_of_exactly_a_class_gets_it_and_one_more_the_next(coded):
     feats = coded["feats"]
-    assert feats.coded == WANT_CODED  # W is in, W + 1 is out
-    assert F.layout_counts(feats).coded_slots == len(WANT_CODED)
-    assert feats.dicts.shape == (len(WANT_CODED), W)
+    assert feats.coded == WANT_CODED  # TOP is in, TOP + 1 is gathered
+    assert feats.classes == WANT_CLASSES
+    counts = F.layout_counts(feats)
+    assert counts.coded_slots == len(WANT_CODED)
+    assert counts.coded_entries == sum(WANT_CLASSES)
+    assert feats.dicts.shape == (sum(WANT_CLASSES),)
     assert feats.codes.dtype == jnp.uint16
+    assert feats.codes.shape == (
+        len(WANT_CODED), F._code_stride(coded["n"]) // 128, 128)
     # cols and vals stay whole: what every other reader of the layout reads
     k = len(WIDTHS)
     assert feats.cols.shape == feats.vals.shape == (coded["n"] * k,)
@@ -523,19 +534,40 @@ def test_a_slot_of_exactly_the_width_is_coded_and_one_more_is_not(coded):
         np.asarray(feats.cols).reshape(k, -1).T, coded["cols"])
 
 
-def test_what_a_coded_slot_selects_is_bitwise_what_the_gather_fetches(coded):
+@pytest.mark.parametrize("distinct,want", [
+    (1, 128), (128, 128), (129, 256), (1459, 2048), (2048, 2048),
+    (2049, 4096), (14991, 16384), (TOP - 1, TOP), (TOP, TOP)])
+def test_a_slots_class_is_the_least_power_of_two_that_holds_it(distinct,
+                                                               want):
+    assert F._slot_class(distinct) == want
+
+
+@pytest.mark.parametrize("j", range(len(WANT_CODED)),
+                         ids=[f"class{c}" for c in WANT_CLASSES])
+def test_what_a_coded_slot_looks_up_is_bitwise_what_the_gather_fetches(
+        coded, j):
     feats, n = coded["feats"], coded["n"]
     v = jnp.asarray(np.random.default_rng(1).normal(size=coded["d"]),
                     jnp.float32)
-    stride = F._code_stride(n)
-    for j, s in enumerate(feats.coded):
-        code = feats.codes[j * stride:j * stride + n]
-        selected = np.asarray(F._select(code, v[feats.dicts[j]]))
-        fetched = np.asarray(v[coded["cols"][:, s]])
-        assert selected.tobytes() == fetched.tobytes(), s
+    start = sum(feats.classes[:j])
+    table = v[feats.dicts[start:start + feats.classes[j]]]
+    found = np.asarray(F._lookup(feats.codes, table, jnp.full((1,), j, jnp.int32))).reshape(-1)[:n]
+    fetched = np.asarray(v[coded["cols"][:, feats.coded[j]]])
+    assert found.tobytes() == fetched.tobytes()
+
+
+def test_padded_rows_carry_column_zeros_code_and_a_code_past_the_table_reads_0(
+        coded):
+    feats, n = coded["feats"], coded["n"]
     # the padded rows of slot 0 (value 0 at column 0) carry column 0's code
-    assert int(feats.dicts[0, 0]) == 0
-    assert [int(feats.codes[i]) for i in (5, 17, n - 1)] == [0, 0, 0]
+    assert int(feats.dicts[0]) == 0
+    flat = feats.codes.reshape(-1)
+    assert [int(flat[i]) for i in (5, 17, n - 1)] == [0, 0, 0]
+    code = jnp.asarray(np.arange(32 * 128).reshape(1, 32, 128), jnp.uint16)
+    table = jnp.arange(1.0, 257.0, dtype=jnp.float32)
+    got = np.asarray(F._lookup(code, table, jnp.zeros((1,), jnp.int32))).reshape(-1)
+    np.testing.assert_array_equal(got[:256], np.asarray(table))
+    assert not got[256:].any()
 
 
 @pytest.mark.parametrize("product", ["matvec", "row_sq_matvec"])
@@ -546,12 +578,15 @@ def test_row_products_with_coded_slots_equal_the_gathered_ones(coded,
     got = np.asarray(getattr(coded["feats"], product)(v))
     want = np.asarray(getattr(coded["plain"], product)(v))
     assert got.dtype == want.dtype == np.float32
-    # the same terms in the same order; the CPU backend contracts the
-    # multiply-add differently in the two programs: a rounding of a term
+    # the same terms in the same order, each rounded as the gathered loop
+    # rounds it (``_add_term``): bitwise, off the TPU as on it
+    assert got.tobytes() == want.tobytes()
+    jitted = jax.jit(lambda f, v: getattr(f, product)(v))
+    assert (np.asarray(jitted(coded["feats"], v)).tobytes()
+            == np.asarray(jitted(coded["plain"], v)).tobytes())
+    # and both are the product: against float64 on the host
     x = coded["vals"] ** 2 if product == "row_sq_matvec" else coded["vals"]
     terms = np.abs(x * np.asarray(v)[coded["cols"]])
-    assert (np.abs(got - want) <= 2.0 ** -23 * terms.sum(axis=1)).all()
-    # and both are the product: against float64 on the host
     exact = (x.astype(np.float64)
              * np.asarray(v, np.float64)[coded["cols"]]).sum(axis=1)
     np.testing.assert_allclose(got, exact, rtol=0,
@@ -604,6 +639,83 @@ def test_autodiff_through_coded_slots_is_the_transposed_product(coded):
                                atol=1e-4 * float(jnp.abs(hvp(plain)).max()))
 
 
+@pytest.mark.parametrize("product", ["matvec", "row_sq_matvec"])
+def test_vmap_over_the_vector_runs_the_coded_slots_a_vector_at_a_time(
+        coded, product):
+    """The module's promise (vmap-safe products) with a kernel call inside:
+    a batch of vectors is a batch of tables against the same codes."""
+    feats, plain = coded["feats"], coded["plain"]
+    vs = jnp.asarray(np.random.default_rng(7).normal(size=(3, coded["d"])),
+                     jnp.float32)
+    got = np.asarray(jax.jit(jax.vmap(getattr(feats, product)))(vs))
+    assert got.shape == (3, coded["n"])
+    for b in range(3):
+        want = np.asarray(getattr(plain, product)(vs[b]))
+        np.testing.assert_allclose(got[b], want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+    # and differentiated under the batch: a gradient a vector
+    u = jnp.asarray(np.random.default_rng(8).normal(size=coded["n"]),
+                    jnp.float32)
+
+    def loss(f, v):
+        return jnp.sum(jnp.tanh(getattr(f, product)(v)) * u)
+
+    grads = np.asarray(jax.vmap(jax.grad(lambda v: loss(feats, v)))(vs))
+    for b in range(3):
+        want = np.asarray(jax.grad(lambda v: loss(plain, v))(vs[b]))
+        np.testing.assert_allclose(grads[b], want, rtol=1e-4,
+                                   atol=1e-5 * np.abs(want).max())
+
+
+def test_a_scan_around_coded_products_differentiates_once_either_way(coded):
+    """``SlotMajorEllFeatures``' stated restriction: inside a staged loop
+    the coded slots differentiate once, forward or backward; differentiated
+    TWICE there the lookup's own rule is lost (JAX keeps none through a
+    loop's partial evaluation) and the kernel itself would be
+    differentiated, which raises rather than answer wrongly. Derivatives
+    taken inside a loop's body are fine at any order."""
+    feats, plain = coded["feats"], coded["plain"]
+    rng = np.random.default_rng(9)
+    v = jnp.asarray(0.1 * rng.normal(size=coded["d"]), jnp.float32)
+    t = jnp.asarray(0.1 * rng.normal(size=coded["d"]), jnp.float32)
+    u = jnp.asarray(rng.normal(size=coded["n"]), jnp.float32)
+
+    def descended(f, v):
+        def step(v, _):
+            return v - 1e-5 * f.rmatvec(jnp.tanh(f.matvec(v)) * u), None
+
+        return jnp.sum(jax.lax.scan(step, v, None, length=3)[0] ** 2)
+
+    def close(got, want):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(want).max()))
+
+    close(jax.grad(lambda v: descended(feats, v))(v),
+          jax.grad(lambda v: descended(plain, v))(v))
+    close(jax.jvp(lambda v: descended(feats, v), (v,), (t,))[1],
+          jax.jvp(lambda v: descended(plain, v), (v,), (t,))[1])
+
+    def hvp(f):
+        return jax.jvp(jax.grad(lambda v: descended(f, v)), (v,), (t,))[1]
+
+    # derivatives taken INSIDE a loop's body are not through the loop: a
+    # Hessian-vector product a step, as TRON's conjugate gradients take
+    def inside(f):
+        def loss(w):
+            return jnp.sum(jnp.tanh(f.matvec(w)) * u)
+
+        return jax.lax.fori_loop(0, 3, lambda _, t: t - 1e-3 * jax.jvp(
+            jax.grad(loss), (v,), (t,))[1], t)
+
+    close(inside(feats), inside(plain))
+    try:
+        twice = hvp(feats)
+    except NotImplementedError:
+        return
+    close(twice, hvp(plain))
+
+
 def test_codes_ride_through_jit_tree_map_and_replace(coded):
     feats = coded["feats"]
     for other in (jax.jit(lambda f: f)(feats),
@@ -636,12 +748,13 @@ def _parent_by_row(cols, vals, v, n, k):
 
 
 def test_a_matrix_with_no_small_slot_runs_the_loop_it_ran_before():
-    n, d = 2 * W, 5 * W
-    widths = (W + 300, W + 500, W + 1, W + 900)
+    n, d = TOP + 2 * W, 2 * TOP
+    widths = (TOP + 300, TOP + 500, TOP + 1, TOP + 900)
     cols, vals = fielded_rows(np.random.default_rng(8), n, d, widths)
     feats = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
     assert feats.coded == () and feats.codes is None and feats.dicts is None
-    assert F.layout_counts(feats).coded_slots == 0
+    counts = F.layout_counts(feats)
+    assert counts.coded_slots == counts.coded_entries == 0
     v = jnp.zeros((d,), jnp.float32)
     k = len(widths)
     now = jax.jit(lambda cols, vals, v: F.SlotMajorEllFeatures(
@@ -652,7 +765,7 @@ def test_a_matrix_with_no_small_slot_runs_the_loop_it_ran_before():
     # with a coded slot the text differs, and names both parts
     cols[:, 0] = 7
     some = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
-    assert some.coded == (0,)
+    assert some.coded == (0,) and some.classes == (128,)
     text = jax.jit(lambda f, v: f.matvec(v)).lower(some, v).as_text(
         debug_info=True)
     for part in scopes.FE_MATVEC_PARTS:
@@ -664,20 +777,22 @@ def test_both_constructors_code_the_same_slots():
     """Rows whose columns ascend along the slots, so that scipy's sorted
     rows keep every entry in its slot: the device path and the host path
     (``features_to_device`` -> ``lay_out_triplet``) must agree."""
-    n, d = 2 * W, 14 * W
+    n = TOP + 2 * W
     rng = np.random.default_rng(9)
-    widths = (1, 30, W + 200, W, W + 100, W + 1, 1)
+    widths = (1, 30, TOP + 200, W, TOP, TOP + 1, 1)
+    d = 2 * TOP * len(widths)
     cols = np.empty((n, len(widths)), np.int32)
-    for f, width in enumerate(widths):  # field f owns columns [2 W f, ...)
+    for f, width in enumerate(widths):  # field f owns columns [2 TOP f, ...)
         draw = rng.integers(0, width, n)
         draw[:width] = np.arange(width)
-        cols[:, f] = 2 * W * f + draw
+        cols[:, f] = 2 * TOP * f + draw
     vals = rng.normal(size=cols.shape).astype(np.float32)
     vals[vals == 0] = 1.0
     born = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
     host = F.features_to_device(as_scipy(cols, vals, d))
     assert isinstance(host, F.SlotMajorEllFeatures)
-    assert born.coded == host.coded == (0, 1, 3, 6)
+    assert born.coded == host.coded == (0, 1, 3, 4, 6)
+    assert born.classes == host.classes == (128, 128, W, TOP, 128)
     assert F.layout_counts(born) == F.layout_counts(host)
     np.testing.assert_array_equal(np.asarray(born.dicts),
                                   np.asarray(host.dicts))
@@ -699,37 +814,49 @@ def test_shard_batch_and_the_mesh_fit_hold_with_coded_slots(coded):
         np.asarray(feats.matvec(v)), rtol=1e-5, atol=1e-5)
 
 
-def test_the_gauge_and_the_ledger_row_read_the_coded_count(problem):
+def test_the_gauges_and_the_ledger_row_read_the_coded_counts(problem):
     enable_compile_cache()
     telemetry.enable()
     # ``problem``'s rows are full and end in the intercept: one coded slot
+    # more than before PR 39, when a slot of over 1,024 columns was gathered
     feats = F.sparse_rows_to_device(problem.cols, problem.vals,
                                     problem.n_features)
     k = problem.cols.shape[1]
-    assert feats.coded == (k - 1,)
+    assert feats.coded[-1] == k - 1 and feats.classes[-1] == 128
+    slots, entries = len(feats.coded), sum(feats.classes)
     cd, coord = descent(problem, feats)
-    assert coord.sparse_work()[0].coded_slots == 1
-    assert telemetry.snapshot()["gauges"][scopes.GAUGE_FE_CODED_SLOTS] == 1
+    counts = coord.sparse_work()[0]
+    assert (counts.coded_slots, counts.coded_entries) == (slots, entries)
+    gauges = telemetry.snapshot()["gauges"]
+    assert gauges[scopes.GAUGE_FE_CODED_SLOTS] == slots
+    assert gauges[scopes.GAUGE_FE_CODED_ENTRIES] == entries
     cd.run(1)
     row = compile_ledger()["functions"][scopes.CD_BLOCK]
-    assert (row["fe_layout"], row["fe_coded_slots"]) == ("slot_major_ell", 1)
+    assert (row["fe_layout"], row["fe_coded_slots"],
+            row["fe_coded_entries"]) == ("slot_major_ell", slots, entries)
     # ... and 0 where no slot is small
     telemetry.reset()
-    n, d = W + 10, 3 * W
+    n, d = TOP + 10, 3 * TOP
     cols, vals = fielded_rows(np.random.default_rng(10), n, d,
-                              (W + 1, W + 2))
+                              (TOP + 1, TOP + 2))
     none = F.sparse_rows_to_device(jnp.asarray(cols), jnp.asarray(vals), d)
     p = Problem(n, d, None, None, jnp.zeros((n,), jnp.float32),
                 jnp.zeros((n,), jnp.float32), jnp.ones((n,), jnp.float32))
     descent(p, none)
-    assert telemetry.snapshot()["gauges"][scopes.GAUGE_FE_CODED_SLOTS] == 0
+    gauges = telemetry.snapshot()["gauges"]
+    assert gauges[scopes.GAUGE_FE_CODED_SLOTS] == 0
+    assert gauges[scopes.GAUGE_FE_CODED_ENTRIES] == 0
 
 
 def test_two_seeds_of_the_recipe_share_every_compiled_program():
-    """The cell's own rows at 30,000: the 25 slots whose field has at most
-    ``CODED_SLOT_WIDTH`` values are coded on every seed, the dictionaries
-    are padded to the width, and so nothing compiles for the second seed:
-    not at construction, not the products."""
+    """The cell's own rows, all 40 slots, at 100,000: enough rows that the
+    eight widest fields name more columns than the top class holds and are
+    gathered, and that each of the 32 others fills the class it fills at
+    the cell's 9.2M rows (the seven wide ones name 2,168 to 11,447 columns
+    here, all their 1,459 to 14,991 there). Every seed codes the same
+    slots in the same classes, and the dictionaries are padded to the
+    classes, so nothing compiles for the second seed: not at construction,
+    not the products."""
     import json
     import pathlib
 
@@ -738,20 +865,36 @@ def test_two_seeds_of_the_recipe_share_every_compiled_program():
     root = pathlib.Path(__file__).resolve().parent.parent
     config = recipe.scale_down(json.loads(
         (root / "benchmark/configs/sparse-lr-criteo.json").read_text()),
-        30000)
-    fields = config["fixed"]["fields"]
-    want = tuple(f for f, card in enumerate(fields) if card - 1 <= W
-                 ) + (len(fields),)  # ranks 1 .. card - 1, and the intercept
+        100000)
+    # a field draws ranks 1 .. card - 1; the last slot is the intercept
+    named = [card - 1 for card in config["fixed"]["fields"]] + [1]
+    want = tuple(f for f, count in enumerate(named) if count <= TOP)
+    classes = tuple(F._slot_class(named[f]) for f in want)
+    assert len(want) == 32 and sum(classes) == 64768
+    assert sum(1 for f in want if named[f] <= 1024) == 25  # coded at PR 36
     matvec = jax.jit(lambda f, w: f.matvec(w))
-    programs = (F._slot_dictionaries, F._slot_codes, F._slot_major,
-                F._count_rows, matvec)
+    programs = (F._slot_dictionaries, F._slot_codes, F._cut_to_classes,
+                F._slot_major, F._count_rows, F._lookup_call, F._add_term,
+                matvec)
     sizes = []
     for seed in (2147486611, 2147486612):
         p = recipe.make(config, seed)
         feats = F.sparse_rows_to_device(p.cols, p.vals, p.n_features)
-        assert feats.coded == want and len(want) == 25
-        assert F.layout_counts(feats).coded_slots == 25
+        assert feats.coded == want and feats.classes == classes
+        counts = F.layout_counts(feats)
+        assert (counts.coded_slots, counts.coded_entries) == (32, 64768)
         jax.block_until_ready(matvec(feats, jnp.zeros((p.n_features,),
                                                       jnp.float32)))
         sizes.append([fn._cache_size() for fn in programs])
+        # no count lies near a class's edge, here as at the cell's size:
+        # another seed's few columns more or fewer move no class
+        cols = np.asarray(p.cols)
+        for f in want:
+            here = len(np.unique(cols[:, f]))
+            for count in (here, named[f]):
+                if count > 128:
+                    assert 1.05 * (classes[want.index(f)] // 2) < count
+                    assert count < classes[want.index(f)] / 1.05
+        for f in set(range(len(named))) - set(want):
+            assert len(np.unique(cols[:, f])) > 1.05 * TOP
     assert sizes[0] == sizes[1]
