@@ -49,20 +49,33 @@ entry, without ``photon.re.scatter``). This script therefore turns the
 persistent cache off for its own process: it compiles what it traces,
 with the tree's names, and leaves the machine's cache as it found it.
 
+``--join`` (PR 40) also reads the SAME trace as the benchmark does: its
+frozen reduction (``benchmark/trace_reduce.py``: seconds by instruction name,
+no path) joined with the instruction table the program published for the
+block it dispatched (``utils.compile_cache.instruction_scopes``) through
+``benchmark/scope_seconds.py``, and prints every ``per_layer`` metric of the
+cell that a file under ``benchmark/metrics`` reads from it beside this
+script's own number for the same scope. ``--cached`` leaves the persistent
+cache on (half the set-up; right where no scope was renamed since the cache
+was filled, and the join is right either way: table and trace are read from
+the one executable that ran).
+
 ``--save-trace`` keeps the flattened trace with its paths (one job with
 ``--cut-jobs 1``: the recorded trace of ``tests/test_fit_tracing.py``);
 ``--dump-stats N`` prints every stat of the N longest device events, to
 see by hand which one carries the path on a new chip or JAX.
 
-Wiring the same reduction into the benchmark's ``ctx`` is the next
-``benchmark`` issue's (PERF.md §7); this script edits nothing there.
+How a path resolves to the table's scopes (``place``) and what a collective
+is (``is_collective``) are ``photon_ml_tpu/telemetry/scopes.py``'s since
+PR 40, shared with the benchmark's readers of the block's instruction table
+(``benchmark/scope_seconds.py``), which serve the same numbers by summing
+``op_seconds`` by instruction name; this script edits nothing there.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import re
 import struct
 import sys
 from pathlib import Path
@@ -73,6 +86,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmark.trace_reduce import (  # noqa: E402
+    CONTAINER_OPS,
     DEVICE_PLANE_PREFIXES,
     JOB_SPAN,
     OPS_LINE,
@@ -84,36 +98,19 @@ from benchmark.trace_reduce import (  # noqa: E402
 from photon_ml_tpu.telemetry import scopes  # noqa: E402
 
 HOST_SPAN_PREFIXES = (scopes.PREFIX, "bench.")
-# Operations that are collectives. One the partitioner (or a compiler pass)
-# made is named after its opcode (``%all-reduce.12``: what the v5e prints,
-# looked at by hand, PR 31, JAX 0.9.0, ``PERF.md`` section 5); one the
-# program wrote is named after JAX's primitive and shows its opcode only
-# behind the `` = `` (``%psum_invariant.16 = f32[20000265]{...}
-# all-reduce(...)``: the divided exchange's two, PR 32). Either counts. An
-# asynchronous one is two events, ``<name>-start`` and ``<name>-done``:
-# both carry the prefix, both count.
-COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
-                       "all-to-all", "collective-permute")
-_COLLECTIVE_OPCODE = re.compile(
-    r" = (?:\([^=]*?\)|\S+) (?:%s)(?:-start|-done)?\("
-    % "|".join(COLLECTIVE_PREFIXES))
-
-
-def is_collective(name: str) -> bool:
-    """Whether a device event (its full HLO text) is a collective."""
-    return (short_name(name).lstrip("%").startswith(COLLECTIVE_PREFIXES)
-            or _COLLECTIVE_OPCODE.search(name) is not None)
+# How a path resolves to the table's scopes, and what a collective is, are
+# the program's (one definition for this script, the benchmark's readers and
+# an operator: PR 40).
+place = scopes.place
+is_collective = scopes.is_collective
 
 
 NO_SCOPE = "(no scope)"
-#: Every leaf scope of the table: the factored coordinate's own rows are
-#: printed only where a trace holds such operations.
-LEAF_SCOPES = scopes.DEVICE_SCOPES + scopes.MF_SCOPES
+LEAF_SCOPES = scopes.LEAF_SCOPES
 # The stat that carries the HLO metadata's op_name (``tf_op`` on the v5e,
 # PERF.md §5); any other stat whose value holds a ``photon.`` scope is
 # taken where a later profiler renames it.
 PATH_STATS = ("tf_op", "op_name")
-_SIZE_CLASS = re.compile(r"^r\d+$")
 
 Interval = Tuple[int, int]
 
@@ -332,35 +329,7 @@ def covered_ms(intervals: Iterable[Interval], lo: int, hi: int) -> float:
     return total(clip(union(intervals), lo, hi)) / 1e6
 
 
-# -- an operation's place in the table ----------------------------------------
-
-def place(path: str) -> dict:
-    """``leaf``: the innermost table scope on the path; ``coordinate``: its
-    ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
-    ``photon.re.solve`` or ``photon.mf.latent``; ``product``: the sparse product
-    (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
-    ``<leaf>/<product>``; ``part``: the matvec's coded or gathered slots
-    (PR 36), as ``<leaf>/<product>/<part>``; ``scoped``: under any
-    ``photon.*`` at all."""
-    leaf = coordinate = size_class = product = piece = None
-    parts = path.split("/")
-    for i, part in enumerate(parts):
-        if part in scopes.FE_PRODUCT_SCOPES and leaf:
-            product = f"{leaf}/{part}"
-        elif part in scopes.FE_MATVEC_PARTS and product:
-            piece = f"{product}/{part}"
-        elif part in LEAF_SCOPES:
-            leaf = part
-            if (part in (scopes.RE_SOLVE, scopes.MF_LATENT)
-                    and i + 1 < len(parts)
-                    and _SIZE_CLASS.match(parts[i + 1])):
-                size_class = parts[i + 1]
-        elif part.startswith(scopes.cd_coordinate("")):
-            coordinate = part
-    return {"leaf": leaf, "coordinate": coordinate, "size_class": size_class,
-            "product": product, "part": piece,
-            "scoped": leaf is not None or coordinate is not None}
-
+# -- where an operation ran ----------------------------------------------------
 
 def in_block(path: str) -> bool:
     return path.startswith(f"jit({scopes.CD_BLOCK})/")
@@ -664,7 +633,7 @@ def dump_stats(planes: List[dict], n: int, out=sys.stdout) -> None:
 # -- the traced run ------------------------------------------------------------
 
 def trace_cell(workload: str, seed: int, jobs: int, rehearse_rows: int,
-               trace_dir: Path):
+               trace_dir: Path, cached: bool = False):
     """The cell's job as the harness builds it, warmed up, then ``jobs``
     jobs under a profiler session; returns the ``.xplane.pb``'s path."""
     import importlib
@@ -677,7 +646,8 @@ def trace_cell(workload: str, seed: int, jobs: int, rehearse_rows: int,
 
     enable_compile_cache()  # for the compile ledger; the cache itself off:
     # executables whose metadata is the tree's (the module's docstring).
-    jax.config.update("jax_enable_compilation_cache", False)
+    if not cached:
+        jax.config.update("jax_enable_compilation_cache", False)
     loaded = harness.load_cell(workload)
     config, wl = loaded["config"], loaded["workload"]
     device = harness.device_block(int(loaded["cell"]["chips"]),
@@ -702,6 +672,101 @@ def trace_cell(workload: str, seed: int, jobs: int, rehearse_rows: int,
     return files[-1]
 
 
+#: The script's own number for what each joined metric sums.
+_JOINED = {
+    "exchange_ms": lambda m: m["exchange_ms"],
+    "fe_solve_job_ms": lambda m: m["scope_ms"][scopes.FE_SOLVE],
+    "re_solve_job_ms": lambda m: m["scope_ms"][scopes.RE_SOLVE],
+    "mf_solve_job_ms": lambda m: sum(
+        m["scope_ms"].get(s, 0.0) for s in scopes.MF_SCOPES),
+    "mf_kernel_ms": lambda m: sum(
+        v["ms"] for v in m["latent_class_ms"].values()
+        if v["path"] == "kernel"),
+    "fe_matvec_job_ms": lambda m: sum(
+        v for k, v in m["product_ms"].items()
+        if k.endswith("/" + scopes.FE_MATVEC)),
+    "fe_rmatvec_job_ms": lambda m: sum(
+        v for k, v in m["product_ms"].items()
+        if k.endswith("/" + scopes.FE_RMATVEC)),
+    "fe_score_ms": lambda m: (m["scope_ms"][scopes.FE_SCORE]
+                              + m["scope_ms"][scopes.CD_OBJECTIVE]),
+    "unscoped_ms": lambda m: m["unattributed_ms"],
+}
+
+
+def join(xplane: Path, workload: str, mean: dict, out=sys.stdout) -> dict:
+    """The cell's ``per_layer`` metrics that read the block's instruction
+    table, from the same ``.xplane.pb`` through the benchmark's own
+    reduction and readers, beside this script's number for the same scope
+    (a latent class on the kernel also runs what surrounds the call, so
+    ``mf_kernel_ms`` is below the script's kernel classes)."""
+    import importlib
+
+    from benchmark import scope_seconds, trace_reduce
+
+    ctx = {"trace": trace_reduce.reduce(trace_reduce.load(xplane))}
+    found = scope_seconds.by_scope(ctx)
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    print(f"\njoin of the benchmark's op_seconds ({ctx['trace']['traced_jobs']}"
+          " jobs) with the program's instruction table: "
+          + ("nothing (no table, or it covers under 95%)" if found is None
+             else f"coverage {100 * found['coverage']:.3f}%, total "
+                  f"{found['total']:.3f} ms a job, busy "
+                  f"{1e3 * ctx['trace']['busy_s'] / ctx['trace']['traced_jobs']:.3f}"),
+          file=out)
+    print("| metric | benchmark's reader, ms | this script, ms |", file=out)
+    print("| --- | --- | --- |", file=out)
+    values = {}
+    for m in bench["per_layer"]:
+        if m["name"] not in _JOINED or workload not in m.get(
+                "workloads", [workload]):
+            continue
+        value = importlib.import_module(
+            f"benchmark.metrics.{m['name']}").read(ctx)
+        values[m["name"]] = value
+        print(f"| `{m['name']}` | "
+              + ("nothing" if value is None else f"{value:.3f}")
+              + f" | {_JOINED[m['name']](mean):.3f} |", file=out)
+    return {"metrics": values, "by_scope": found}
+
+
+def disagreements(trace: dict, top: int = 12, out=sys.stdout) -> list:
+    """Where the trace's own path of an operation (the profiler's ``tf_op``)
+    and the program's instruction table place the same instruction name
+    under different leaf scopes: ``[name, ms a job, the trace's path, the
+    table's]``, the costliest first (the first chip's events)."""
+    from photon_ml_tpu.utils.compile_cache import instruction_scopes
+
+    table = instruction_scopes()
+    trace = unpack(trace)
+    jobs = job_spans(trace)
+    plane = next(p for p in trace["planes"]
+                 if p["name"].startswith(DEVICE_PLANE_PREFIXES))
+    differ: Dict[tuple, float] = {}
+    for name, s, d, path in (e for ln in plane["lines"]
+                             if ln["name"] == OPS_LINE for e in ln["events"]):
+        if not any(lo < s + d and s < hi for lo, hi, _ in jobs):
+            continue
+        name = short_name(name)
+        if name.startswith(CONTAINER_OPS):
+            continue
+        name = name.lstrip("%")
+        held = table.get(name)
+        if held is None or place(held)["leaf"] != place(path)["leaf"]:
+            key = (name, path, held)
+            differ[key] = differ.get(key, 0.0) + d / 1e6 / max(len(jobs), 1)
+    rows = sorted(([n, ms, path, held] for (n, path, held), ms
+                   in differ.items()), key=lambda r: -r[1])
+    print(f"\n{len(rows)} instruction names whose leaf scope differs between "
+          f"the trace's path and the table's, {sum(r[1] for r in rows):.3f} "
+          "ms a job:", file=out)
+    for n, ms, path, held in rows[:top]:
+        print(f"  {n} {ms:.3f} ms: trace `{path[-110:]}` | table "
+              + ("holds no such name" if held is None else f"`{held[-110:]}`"),
+              file=out)
+    return rows
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="glmix.fit")
@@ -716,15 +781,18 @@ def main(argv=None) -> int:
     ap.add_argument("--save-trace", action="store_true")
     ap.add_argument("--cut-jobs", type=int, default=0)
     ap.add_argument("--dump-stats", type=int, default=0)
+    ap.add_argument("--join", action="store_true")
+    ap.add_argument("--cached", action="store_true")
     args = ap.parse_args(argv)
 
+    xplane = None
     if args.from_json:
         trace = json.loads(args.from_json.read_text())
     else:
         args.out.mkdir(parents=True, exist_ok=True)
         xplane = args.from_xplane or trace_cell(
             args.workload, args.seed, args.jobs, args.rehearse_rows,
-            args.out / "profile")
+            args.out / "profile", args.cached)
         planes = read_xspace(xplane)
         if args.dump_stats:
             with open(args.out / "stats.txt", "w") as f:
@@ -754,6 +822,9 @@ def main(argv=None) -> int:
         print(f"trace_scopes: {e}", file=sys.stderr)
         return 1
     print_report(result)
+    if args.join and xplane is not None:
+        result["join"] = join(xplane, args.workload, result["mean"])
+        result["join"]["disagreements"] = disagreements(trace)
     if not args.from_json:
         result["compile_ledger"] = ledger
         (args.out / "scopes.json").write_text(json.dumps(result, indent=1))

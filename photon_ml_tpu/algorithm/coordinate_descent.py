@@ -44,6 +44,10 @@ from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.telemetry import scopes
 from photon_ml_tpu.telemetry.spans import phase
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils.compile_cache import (
+    dispatched_executable,
+    note_instructions,
+)
 from photon_ml_tpu.utils.tracing_guard import TracingGuard
 
 logger = logging.getLogger(__name__)
@@ -160,6 +164,9 @@ class CoordinateDescent:
         # cd_block programs: by iteration count for whole iterations, by
         # (iterations, first, stop) for a span of fewer coordinates
         self._block_fns: Dict[object, object] = {}
+        # block functions whose instruction table is still to be published:
+        # each leaves at its first dispatch (run())
+        self._table_due: set = set()
         self._val_scorer = None
         self._cold_cache = None
         # Shared retrace infrastructure (utils/tracing_guard.py): every
@@ -357,10 +364,27 @@ class CoordinateDescent:
                 sum(c.coded_slots for c in sparse),
                 sum(c.coded_entries for c in sparse))
         self._block_fns[cache_key] = fn
+        self._table_due.add(fn)
         self.tracing_guard.track(
             f"block:{n_iters}" if whole
             else f"block:{n_iters}:{first}-{stop}", fn)
         return fn
+
+    def _publish_table(self, fn, args) -> None:
+        """At a block function's FIRST dispatch, after its asynchronous call
+        has returned (the device is busy with it meanwhile): which scope
+        each of its compiled instructions belongs to, read from the
+        executable that now runs and kept as strings
+        (``utils.compile_cache.instruction_scopes``). Every later dispatch
+        of that function: one set lookup. Called after the dispatch, never
+        around it: no frame of it is above the solvers when they trace."""
+        if fn not in self._table_due:
+            return
+        self._table_due.discard(fn)
+        t0 = time.perf_counter()
+        note_instructions(
+            scopes.CD_BLOCK,
+            dispatched_executable(scopes.CD_BLOCK, fn, args), since=t0)
 
     def run(
         self,
@@ -594,9 +618,12 @@ class CoordinateDescent:
                     span = max(1, min(span, num_iterations - it))
                     t0 = time.perf_counter()
                     with phase(scopes.CD_DISPATCH):
-                        params, scores, objs, trs = self._fused_block_fn(span)(
-                            data_args, pdata_args, params, scores, base_key,
-                            np.uint32(step), rows)
+                        block_fn = self._fused_block_fn(span)
+                        block_args = (data_args, pdata_args, params, scores,
+                                      base_key, np.uint32(step), rows)
+                        params, scores, objs, trs = block_fn(*block_args)
+                        self._publish_table(block_fn, block_args)
+                        del block_args
                     pending_blocks.append((objs, trs))
                     # Host seconds to ENQUEUE the block (and to trace, lower
                     # and load it on the first call), split evenly: not any
@@ -631,9 +658,11 @@ class CoordinateDescent:
                     t0 = time.perf_counter()
                     with phase(scopes.CD_DISPATCH):
                         span_fn = self._fused_block_fn(1, ci, ci + 1)
-                        params, scores, objs, trs = span_fn(
-                            data_args, pdata_args, params, scores, base_key,
-                            step0, rows)
+                        block_args = (data_args, pdata_args, params, scores,
+                                      base_key, step0, rows)
+                        params, scores, objs, trs = span_fn(*block_args)
+                        self._publish_table(span_fn, block_args)
+                        del block_args
                     pending_blocks.append((objs, trs))
                     timings[n] += time.perf_counter() - t0
                     logger.info("iter %d coordinate %s enqueued (host "

@@ -12,6 +12,8 @@ on one clock.
 
 from __future__ import annotations
 
+import re
+
 PREFIX = "photon."
 
 # -- device scopes (jax.named_scope inside traced code) -----------------------
@@ -83,6 +85,71 @@ def re_size_class(rows: int) -> str:
     """Child of ``RE_SOLVE`` and of ``MF_LATENT``: one per bucket size
     class (padded rows)."""
     return f"r{int(rows)}"
+
+
+# -- an operation's place in the table -----------------------------------------
+#: Every leaf scope of the table; the factored coordinate's own only where a
+#: fit has such a coordinate.
+LEAF_SCOPES = DEVICE_SCOPES + MF_SCOPES
+_SIZE_CLASS = re.compile(r"^r\d+$")
+
+
+def place(path: str) -> dict:
+    """Where an operation counts, from its ``op_name`` path (the HLO
+    metadata ``jax.named_scope`` writes; a profiler's device event and the
+    block's instruction table, ``utils.compile_cache.instruction_scopes``,
+    carry the same string): ONE definition for ``dev_scripts/
+    trace_scopes.py``, the benchmark's readers and an operator.
+
+    ``leaf``: the innermost table scope on the path; ``coordinate``: its
+    ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
+    ``photon.re.solve`` or ``photon.mf.latent``; ``product``: the sparse product
+    (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
+    ``<leaf>/<product>``; ``part``: the matvec's coded or gathered slots
+    (PR 36), as ``<leaf>/<product>/<part>``; ``scoped``: under any
+    ``photon.*`` at all."""
+    leaf = coordinate = size_class = product = piece = None
+    parts = path.split("/")
+    for i, part in enumerate(parts):
+        if part in FE_PRODUCT_SCOPES and leaf:
+            product = f"{leaf}/{part}"
+        elif part in FE_MATVEC_PARTS and product:
+            piece = f"{product}/{part}"
+        elif part in LEAF_SCOPES:
+            leaf = part
+            if (part in (RE_SOLVE, MF_LATENT)
+                    and i + 1 < len(parts)
+                    and _SIZE_CLASS.match(parts[i + 1])):
+                size_class = parts[i + 1]
+        elif part.startswith(_CD):
+            coordinate = part
+    return {"leaf": leaf, "coordinate": coordinate, "size_class": size_class,
+            "product": product, "part": piece,
+            "scoped": leaf is not None or coordinate is not None}
+
+
+# Operations that are collectives. One the partitioner (or a compiler pass)
+# made is named after its opcode (``%all-reduce.12``: what the v5e prints,
+# looked at by hand, PR 31, JAX 0.9.0, ``PERF.md`` section 5); one the
+# program wrote is named after JAX's primitive and shows its opcode only
+# behind the `` = `` (``%psum_invariant.16 = f32[20000265]{...}
+# all-reduce(...)``: the divided exchange's two, PR 32). Either counts. An
+# asynchronous one is two events, ``<name>-start`` and ``<name>-done``:
+# both carry the prefix, both count.
+COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
+                       "all-to-all", "collective-permute")
+_COLLECTIVE_OPCODE = re.compile(
+    r" = (?:\([^=]*?\)|\S+) (?:%s)(?:-start|-done)?\("
+    % "|".join(COLLECTIVE_PREFIXES))
+
+
+def is_collective(name: str) -> bool:
+    """Whether a device event (its full HLO text: ``%name = shape
+    opcode(...)``; the name alone where that is all there is) is a
+    collective."""
+    short = name.split(" = ")[0].lstrip("%")
+    return (short.startswith(COLLECTIVE_PREFIXES)
+            or _COLLECTIVE_OPCODE.search(name) is not None)
 
 
 # -- the kernel and the jitted programs ---------------------------------------

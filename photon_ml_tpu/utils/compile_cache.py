@@ -13,12 +13,23 @@ monitoring events, kept per jitted function name, read by
 compiled, and for how long" from inside the program, in set-up and
 (should one happen) in a measured window. It costs a dict update per
 compile event and nothing in steady state.
+
+Beside the ledger sits the **instruction table** of a jitted function whose
+owner handed over its executable (``note_instructions``): which scope path
+(the ``op_name`` of the HLO metadata, which ``jax.named_scope`` writes) each
+compiled instruction carries, read ONCE from the optimized HLO text of the
+executable that runs, as plain strings. A profiler's device events carry an
+instruction's name (``%fusion.262``); the table says whose it is
+(``instruction_scopes``), so per-operation seconds summed by name resolve
+to the program's own names (``telemetry.scopes.place``).
 """
 
 from __future__ import annotations
 
 import os
+import re
 import threading
+import time
 from pathlib import Path
 from typing import Dict, Optional
 
@@ -56,6 +67,9 @@ _unclaimed = {"retrieval_s": 0.0, "cache_hits": 0}
 # (``note_partitions``): JAX's events carry a function's name and no more.
 _partitions: Dict[str, int] = {}
 _layouts: Dict[str, dict] = {}
+# The instruction table of the executable a function's owner handed over
+# last (``note_instructions``): strings and three numbers, nothing of JAX.
+_instructions: Dict[str, dict] = {}
 
 
 def _function_name(fun_name) -> str:
@@ -122,6 +136,172 @@ def note_layout(fun_name: str, layout: str, coded_slots: int = 0,
             fe_coded_entries=int(coded_entries))
 
 
+# Instructions that run the computations they name as device events of
+# their own (a ``fusion``'s ``calls=`` and a reducer's ``to_apply=`` are
+# insides: they never show as events).
+_CONTROL_FLOW = ("while", "conditional", "call", "async-start")
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|calls|true_computation|"
+    r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# an instruction's attributes end where its ``backend_config`` begins: a
+# kernel's ``custom-call`` carries its whole module there, megabytes that
+# are never searched
+_BACKEND_CONFIG = ", backend_config="
+
+
+def _opcode_at(line: str, i: int) -> str:
+    """The opcode of an instruction's text from ``i``, just behind its
+    `` = ``: what follows the shape (one word, or a tuple in balanced
+    parentheses) up to the operands' parenthesis."""
+    if line.startswith("(", i):
+        depth = 0
+        for j in range(i, len(line)):
+            c = line[j]
+            if c == "(":
+                depth += 1
+            elif c == ")":
+                depth -= 1
+                if not depth:
+                    i = j + 1
+                    break
+    else:
+        i = line.find(" ", i)
+    return line[i + 1:line.find("(", i + 1)]
+
+
+def parse_instructions(hlo_text: str) -> Dict[str, tuple]:
+    """``{instruction name: (opcode, op_name path)}`` of an optimized HLO
+    module's text, for the instructions that can show as device events:
+    those of the entry computation and of every computation a ``while``,
+    ``conditional`` or ``call`` reaches from it, not the insides of fused
+    computations. Names without the ``%``; where the compiler kept no path
+    on an instruction, its caller's (the ``while`` whose body it sits in),
+    ``""`` where that has none either."""
+    computations: Dict[str, list] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if not line:
+            continue
+        if line[0] != " ":
+            if line.endswith("{") and (line[0] == "%"
+                                       or line.startswith("ENTRY")):
+                is_entry = line.startswith("ENTRY")
+                head = line[6:] if is_entry else line
+                name = head.split(" ", 1)[0].lstrip("%")
+                current = computations.setdefault(name, [])
+                if is_entry:
+                    entry = name
+            else:
+                current = None
+            continue
+        if current is None:
+            continue
+        eq = line.find(" = ")
+        if eq < 0:
+            continue
+        name = line[:eq].split()[-1].lstrip("%")
+        opcode = _opcode_at(line, eq + 3)
+        end = line.find(_BACKEND_CONFIG, eq)
+        path = _OP_NAME.search(line, eq, end if end >= 0 else len(line))
+        called = ()
+        if opcode in _CONTROL_FLOW:
+            called = tuple(_CALLED.findall(line, eq))
+            for group in _BRANCHES.findall(line, eq):
+                called += tuple(c.strip().lstrip("%")
+                                for c in group.split(","))
+        current.append((name, opcode, path.group(1) if path else "", called))
+    # An instruction the compiler made with no path of its own (a copy into
+    # the fast memory space, its ``copy-start`` / ``copy-done``) inside a
+    # loop's body belongs to whatever scope the loop does: it takes the path
+    # of the instruction that runs its computation.
+    table: Dict[str, tuple] = {}
+    seen, stack = set(), [(entry, "")] if entry else []
+    while stack:
+        comp, callers_path = stack.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for name, opcode, path, called in computations.get(comp, ()):
+            path = path or callers_path
+            table[name] = (opcode, path)
+            stack.extend((c, path) for c in called)
+    return table
+
+
+def dispatched_executable(fun_name: str, fn, args):
+    """The executable a jitted function's call with ``args`` has just
+    dispatched: ``fn.lower(*args).compile()`` with THE SAME arrays, where
+    tracing, lowering and compiling are each a hit in JAX's own caches
+    (0.5 ms; with shapes in place of the arrays it would all run again).
+    JAX still fires one zero-length trace event for the lookup: the
+    ledger's row of ``fun_name`` keeps the ``traces`` and ``trace_s`` it
+    had, unless a lowering happened too (then the arguments did not match
+    the call's, it was a real retrace, and the row says so)."""
+    name = str(fun_name)
+    with _lock:
+        before = dict(_functions.get(name, ()))
+    compiled = fn.lower(*args).compile()
+    with _lock:
+        row = _functions.get(name)
+        if (before and row is not None
+                and row["lowerings"] == before["lowerings"]):
+            _totals["trace_s"] -= row["trace_s"] - before["trace_s"]
+            row.update(trace_s=before["trace_s"], traces=before["traces"])
+    return compiled
+
+
+def note_instructions(fun_name: str, compiled,
+                      since: Optional[float] = None) -> None:
+    """The owner of a jitted function hands over the executable it just
+    dispatched (``fn.lower(*the call's arguments).compile()``: every step a
+    cache hit): its optimized HLO text is parsed ONCE into the function's
+    instruction table, ``{instruction name: op_name path}`` with each
+    one's opcode beside it, and nothing else is kept: no ``Compiled``, no
+    argument, no closure. The ledger's row of the name gains the counts
+    ``instructions`` and ``scoped_instructions`` (those under a
+    ``photon.*`` name) and ``instructions_s``, the seconds the text and its
+    parse took (from ``since``, a ``time.perf_counter()`` reading, where the
+    owner began earlier: the executable's lookup). A later executable under
+    the same name replaces the table."""
+    from photon_ml_tpu.telemetry.scopes import PREFIX
+
+    t0 = time.perf_counter() if since is None else since
+    parsed = parse_instructions(compiled.as_text())
+    table = {
+        "scopes": {name: path for name, (_, path) in parsed.items()},
+        "opcodes": {name: opcode for name, (opcode, _) in parsed.items()},
+        "scoped": sum(1 for _, path in parsed.values()
+                      if PREFIX in path),
+    }
+    table["seconds"] = time.perf_counter() - t0
+    with _lock:
+        _instructions[str(fun_name)] = table
+
+
+def _table_field(fun_name: str, field: str) -> Dict[str, str]:
+    with _lock:
+        table = _instructions.get(str(fun_name))
+        return dict(table[field]) if table else {}
+
+
+def instruction_scopes(fun_name: str = "cd_block") -> Dict[str, str]:
+    """``{instruction name: op_name path}`` of the executable dispatched
+    last under this function name (``note_instructions``), names as a
+    profiler's device events carry them less the ``%`` (``fusion.262``);
+    ``{}`` where none was. ``telemetry.scopes.place(path)`` resolves a
+    path to the table's scopes."""
+    return _table_field(fun_name, "scopes")
+
+
+def instruction_opcodes(fun_name: str = "cd_block") -> Dict[str, str]:
+    """``{instruction name: opcode}`` of the same table (``fusion``,
+    ``custom-call``, ``all-reduce``, ...): a collective by its opcode,
+    whatever JAX named the instruction."""
+    return _table_field(fun_name, "opcodes")
+
+
 def compile_ledger(top: Optional[int] = None) -> dict:
     """``{"functions": {name: row}, "totals": {...}}`` since the process
     began listening (``enable_compile_cache``), or since ``reset``; with
@@ -135,7 +315,9 @@ def compile_ledger(top: Optional[int] = None) -> dict:
     and ``partitions``, the devices the program was lowered for
     (``note_partitions``; 1 where nobody said); ``fe_layout``,
     ``fe_coded_slots`` and ``fe_coded_entries`` where the function runs a
-    sparse fixed effect (``note_layout``).
+    sparse fixed effect (``note_layout``); ``instructions``,
+    ``scoped_instructions`` and ``instructions_s`` where its owner handed
+    over its executable (``note_instructions``).
     Names are the jitted functions' (``cd_block``), as JAX reports them.
     Totals add ``cache_requests``; requests minus hits were compiled."""
     with _lock:
@@ -144,6 +326,11 @@ def compile_ledger(top: Optional[int] = None) -> dict:
         for k, layout in _layouts.items():
             if k in rows:
                 rows[k].update(layout)
+        for k, table in _instructions.items():
+            if k in rows:
+                rows[k].update(instructions=len(table["scopes"]),
+                               scoped_instructions=table["scoped"],
+                               instructions_s=table["seconds"])
         totals = dict(_totals)
     if top is not None:
         cost = lambda r: r["trace_s"] + r["lower_s"] + r["backend_s"]
@@ -157,6 +344,7 @@ def reset_compile_ledger() -> None:
         _functions.clear()
         _partitions.clear()
         _layouts.clear()
+        _instructions.clear()
         _totals.update(_new_totals())
         _unclaimed.update(retrieval_s=0.0, cache_hits=0)
 

@@ -461,6 +461,30 @@ def test_the_exchanges_collectives_sit_under_their_scopes(fits):
     assert f"{scopes.RE_SCATTER}/shard_map/psum" in compiled
 
 
+def test_the_instruction_table_knows_the_exchanges_collectives(fits):
+    """The mesh block's instruction table (PR 40): a collective is known by
+    its OPCODE whatever JAX named the instruction (``psum_invariant.<n>`` is
+    an ``all-reduce``), and the exchange's two sit under their scopes, so a
+    sum of per-operation seconds by name counts them in the exchange."""
+    from photon_ml_tpu.utils.compile_cache import parse_instructions
+
+    table = parse_instructions(fits["four", 400]["compiled"])
+    collectives = {name: scopes.place(path)["leaf"]
+                   for name, (opcode, path) in table.items()
+                   if opcode.startswith(scopes.COLLECTIVE_PREFIXES)}
+    assert collectives
+    leaves = list(collectives.values())
+    assert leaves.count(scopes.RE_GATHER) == 1
+    assert scopes.RE_SCATTER in leaves or None in leaves  # merged: see above
+    by_name = [n for n in collectives if n.startswith("psum")]
+    assert by_name and not any(
+        n.startswith(scopes.COLLECTIVE_PREFIXES) for n in by_name)
+    # the one-device block has none, by either rule
+    one = parse_instructions(fits["one", 400]["compiled"])
+    assert not [n for n, (opcode, _) in one.items()
+                if opcode.startswith(scopes.COLLECTIVE_PREFIXES)]
+
+
 # -- (d) without a mesh the program is the one it was ---------------------------------
 
 # sha256 of ``cd_block``'s lowered text (``as_text()``: no locations) at

@@ -341,7 +341,7 @@ _BLOCK = "jit(cd_block)/while/body/closed_call"
     ("", None, None, None),
 ])
 def test_place_of_an_operation(path, leaf, coordinate, size_class):
-    where = trace_scopes.place(path)
+    where = scopes.place(path)
     assert (where["leaf"], where["coordinate"], where["size_class"]) == (
         leaf, coordinate, size_class)
     assert where["scoped"] == (leaf is not None or coordinate is not None)
@@ -366,7 +366,7 @@ def test_place_of_an_operation(path, leaf, coordinate, size_class):
     ("photon.fe.matvec.coded/select_n", None, None),
 ])
 def test_place_of_a_sparse_product_and_its_parts(tail, product, part):
-    where = trace_scopes.place(
+    where = scopes.place(
         f"{_BLOCK}/photon.cd.fixed/jit(_solve_fixed)/photon.fe.solve/{tail}")
     assert where["leaf"] == scopes.FE_SOLVE
     assert (where["product"], where["part"]) == (product, part)
@@ -392,7 +392,7 @@ def test_place_of_a_sparse_product_and_its_parts(tail, product, part):
     ("%multiply_reduce_fusion.318", False),
 ])
 def test_a_collective_is_known_by_name_or_by_opcode(event, want):
-    assert trace_scopes.is_collective(event) is want
+    assert scopes.is_collective(event) is want
 
 
 def _hand_trace():
@@ -670,7 +670,7 @@ def test_read_xspace_keeps_the_metadata_stats(tmp_path):
     # of the host's events only the photon.* and bench.* spans are kept
     assert flat["planes"][1]["lines"][0]["events"] == [
         ["bench.job", 0, 9000, ""]]
-    assert trace_scopes.place(tf_op.rstrip(":"))["leaf"] == scopes.FE_SOLVE
+    assert scopes.place(tf_op.rstrip(":"))["leaf"] == scopes.FE_SOLVE
 
 
 def test_run_puts_no_frame_of_its_own_above_the_solvers(monkeypatch):
