@@ -60,6 +60,8 @@ _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
 _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
 _M_FE_PRODUCTS = telemetry.counter(scopes.COUNTER_FE_PRODUCTS)
+_M_FE_CG_STEPS = telemetry.counter(scopes.COUNTER_FE_CG_STEPS)
+_M_FE_TRON_STEPS = telemetry.counter(scopes.COUNTER_FE_TRON_STEPS)
 _M_MF_ALTERNATIONS = telemetry.counter(scopes.COUNTER_MF_ALTERNATIONS)
 _M_MF_REFIT_ITERATIONS = telemetry.counter(
     scopes.COUNTER_MF_REFIT_ITERATIONS)
@@ -717,9 +719,13 @@ class CoordinateDescent:
                         c.sparse_work(lazy[name])[1]
                         for name, c in self.coordinates.items()))
                 if telemetry.enabled():
-                    # what the factored coordinates' updates ran: a fetch
-                    # of their trackers too
+                    # what the factored coordinates' updates and the
+                    # trust-region solves ran: a fetch of their trackers too
                     for name, c in self.coordinates.items():
+                        if c.tron:
+                            cg, attempted = c.tron_work(lazy[name])
+                            _M_FE_CG_STEPS.inc(cg)
+                            _M_FE_TRON_STEPS.inc(attempted)
                         if c.factored:
                             runs, its = c.factored_work(lazy[name])
                             _M_MF_ALTERNATIONS.inc(runs)

@@ -80,6 +80,10 @@ class Coordinate:
     # whether the coordinate's classes are solved at a latent width and
     # counted under ``training.mf.*`` (and then it has ``factored_work``)
     factored = False
+    # whether it is a fixed effect solved by TRON, counted under
+    # ``training.fe.cg_steps`` / ``.tron_steps`` (and then it has
+    # ``tron_work``)
+    tron = False
 
     @property
     def zero_start(self) -> bool:
@@ -219,16 +223,36 @@ class FixedEffectCoordinate(Coordinate):
         counts = layout_counts(self._batch.features)
         if counts is None:
             return None, 0
+        bounded = (self.lower_bounds is not None
+                   or self.upper_bounds is not None)
+        if self.tron:
+            # the first value and gradient; a margin pass and a trial's
+            # value and gradient an attempted iteration (and with bounds
+            # the realized step's product); a matvec and an rmatvec a CG
+            # step
+            cg, attempted = self.tron_work(trackers)
+            return counts, (2 * len(trackers) + (5 if bounded else 3)
+                            * attempted + 2 * cg)
         # A margin-cached L-BFGS solve of ``it`` iterations is ``it + 1``
         # matvec and ``it + 1`` rmatvec whatever its line search does; of
-        # a TRON, OWL-QN or bounded solve the iterations are not products.
-        cached = (self.config.optimizer_type.name == "LBFGS"
-                  and not self._l1 and self.lower_bounds is None
-                  and self.upper_bounds is None)
-        if not cached:
+        # an OWL-QN or bounded solve the iterations are not products.
+        if self._l1 or bounded:
             return counts, 0
         return counts, sum(2 * (int(np.asarray(tr.iterations)) + 1)
                            for tr in trackers)
+
+    @property
+    def tron(self) -> bool:
+        return self.config.optimizer_type.name == "TRON"
+
+    def tron_work(self, trackers=()):
+        """``(cg_steps, attempted)``: what the trust-region solves of
+        ``trackers`` ran, from the solver's own counts (``OptimizerResult.
+        cg_iterations`` / ``.attempted_iterations``)."""
+        counted = [tr for tr in trackers if tr.cg_iterations is not None]
+        return (sum(int(np.asarray(tr.cg_iterations)) for tr in counted),
+                sum(int(np.asarray(tr.attempted_iterations))
+                    for tr in counted))
 
     def _pad_d(self, arr, fill=0.0):
         """Zero-pad a [d] vector to the feature-sharded width (no-op

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from photon_ml_tpu.ops.features import FeatureMatrix
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.data.normalization import NormalizationContext
+from photon_ml_tpu.telemetry import scopes
 
 Array = jax.Array
 
@@ -184,15 +185,18 @@ class GLMObjective:
     ) -> Array:
         """H @ vec with precomputed curvature weights: exactly one
         matvec + one rmatvec (J v is affine: margin_direction), vs the
-        ~2x cost of jvp-of-grad which also re-derives the margin pass."""
-        jv = self.margin_direction(vec, batch)
-        return self._jt_product(d2 * jv, batch) + l2_weight * vec
+        ~2x cost of jvp-of-grad which also re-derives the margin pass.
+        Traced under ``photon.fe.hvp``: TRON's CG runs one a step."""
+        with jax.named_scope(scopes.FE_HVP):
+            jv = self.margin_direction(vec, batch)
+            return self._jt_product(d2 * jv, batch) + l2_weight * vec
 
     def make_tron_hvp(self, x: Array, batch: GLMBatch,
                       l2_weight: Array | float = 0.0):
         """Hessian-vector factory for minimize_tron's ``make_hvp`` hook:
-        margins + curvature computed once per outer iteration, each inner
-        CG product costs one matvec + one rmatvec. (Bound methods hash by
+        margins + curvature computed once per outer iteration (under the
+        solve's own scope), each inner CG product costs one matvec + one
+        rmatvec (under ``photon.fe.hvp``). (Bound methods hash by
         (instance, function), so this is a stable jit static argument for
         a persistent objective.)"""
         z = self.margins(x, batch)

@@ -182,6 +182,20 @@ class OptimizerResult:
     # the solver was asked to track them (the reference's ModelTracker state,
     # ml/supervised/model/ModelTracker.scala). None otherwise.
     coef_history: Optional[Array] = None
+    # What a trust-region solve did besides its accepted iterations
+    # (optimization/tron.py): the inner CG steps summed over its outer
+    # iterations, and the outer iterations it ran, accepted or rejected.
+    # None from the solvers that do not count them (L-BFGS, OWL-QN, the
+    # streamed and the batched-grid TRON).
+    cg_iterations: Optional[Array] = None  # i32
+    attempted_iterations: Optional[Array] = None  # i32
+    # The fused TRON's last outer iteration's CG: the point it ran at, the
+    # step s it returned and the residual r it carried (-g - H s by the
+    # Hessian-vector products it ran; over the free coordinates with
+    # bounds). None from the other solvers.
+    cg_point: Optional[Array] = None
+    cg_step: Optional[Array] = None
+    cg_residual: Optional[Array] = None
 
     @property
     def converged(self) -> Array:
@@ -194,6 +208,8 @@ class OptimizerResult:
         return (
             self.x, self.value, self.grad_norm, self.iterations, self.reason,
             self.value_history, self.grad_norm_history, self.coef_history,
+            self.cg_iterations, self.attempted_iterations,
+            self.cg_point, self.cg_step, self.cg_residual,
         ), None
 
     @classmethod

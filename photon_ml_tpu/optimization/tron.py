@@ -47,9 +47,9 @@ _CG_XI = 0.1  # inner CG stops at ||r|| <= xi ||g||
 def _truncated_cg(hvp, g, delta, max_cg, dtype):
     """Steihaug-Toint truncated CG: approximately solve H s = -g, ||s||<=delta.
 
-    Returns (s, r) with r the final residual -g - H s (needed for the
-    predicted-reduction formula). One hvp per iteration — the hot loop
-    (reference: TRON.scala:280-340).
+    Returns (s, r, k) with r the final residual -g - H s (needed for the
+    predicted-reduction formula) and k the CG steps taken. One hvp per
+    step — the hot loop (reference: TRON.scala:280-340).
     """
     d0 = -g
     s0 = jnp.zeros_like(g)
@@ -106,7 +106,7 @@ def _truncated_cg(hvp, g, delta, max_cg, dtype):
         return jax.tree.map(lambda a, b: jnp.where(st.done, a, b), st, new)
 
     final = lax.while_loop(cond, body, init)
-    return final.s, final.r
+    return final.s, final.r, final.k
 
 
 class _TronState(NamedTuple):
@@ -115,6 +115,11 @@ class _TronState(NamedTuple):
     g: Array
     delta: Array
     it: Array  # accepted iterations
+    attempted: Array  # outer iterations run, accepted or rejected
+    cg: Array  # CG steps (Hessian-vector products) over all of them
+    cg_x: Array  # the last outer iteration's CG: the point it ran at,
+    cg_s: Array  # the step it returned
+    cg_r: Array  # and the residual it carried, -g - H s
     fails: Array  # consecutive improvement failures
     reason: Array
     value_hist: Array
@@ -159,7 +164,9 @@ def _minimize_tron_impl(
 
     init = _TronState(
         x=x0, f=f0, g=g0, delta=gnorm0,
-        it=jnp.zeros((), jnp.int32), fails=jnp.zeros((), jnp.int32),
+        it=jnp.zeros((), jnp.int32), attempted=jnp.zeros((), jnp.int32),
+        cg=jnp.zeros((), jnp.int32), cg_x=x0, cg_s=jnp.zeros_like(x0),
+        cg_r=jnp.zeros_like(x0), fails=jnp.zeros((), jnp.int32),
         reason=jnp.where(
             gnorm0 <= 0.0, int(ConvergenceReason.GRADIENT_CONVERGED),
             int(ConvergenceReason.NOT_CONVERGED)).astype(jnp.int32),
@@ -197,7 +204,7 @@ def _minimize_tron_impl(
         else:
             g_cg, hvp_cg = st.g, hvp
 
-        s, r = _truncated_cg(hvp_cg, g_cg, st.delta, max_cg, dtype)
+        s, r, cg_steps = _truncated_cg(hvp_cg, g_cg, st.delta, max_cg, dtype)
 
         x_try = _project(st.x + s, lo, hi)
         s_real = x_try - st.x
@@ -265,6 +272,8 @@ def _minimize_tron_impl(
 
         new = _TronState(
             x=x_acc, f=f_acc, g=g_acc, delta=delta, it=it_new,
+            attempted=st.attempted + 1, cg=st.cg + cg_steps,
+            cg_x=st.x, cg_s=s, cg_r=r,
             fails=fails_new, reason=reason,
             value_hist=jnp.where(
                 accept, st.value_hist.at[it_new].set(f_acc), st.value_hist),
@@ -286,6 +295,8 @@ def _minimize_tron_impl(
         iterations=final.it, reason=final.reason,
         value_history=final.value_hist, grad_norm_history=final.gnorm_hist,
         coef_history=final.coef_hist,
+        cg_iterations=final.cg, attempted_iterations=final.attempted,
+        cg_point=final.cg_x, cg_step=final.cg_s, cg_residual=final.cg_r,
     )
 
 
@@ -770,6 +781,19 @@ def minimize_tron(
 
     Defaults mirror the reference (maxIter=15, tol=1e-5, <=20 CG iterations,
     <=5 improvement failures; ml/optimization/TRON.scala:258-264).
+
+    The result counts the work besides the accepted iterations:
+    ``cg_iterations``, the CG steps (one Hessian-vector product each) of
+    every outer iteration, and ``attempted_iterations``, the outer
+    iterations run, accepted or rejected (each evaluates one trial point).
+    Without bounds and with the GLM's ``make_hvp``, a solve reads the
+    matrix ``2 + 3 * attempted + 2 * cg`` times: the first value and
+    gradient; a margin pass, a trial's value and gradient an outer
+    iteration; a matvec and an rmatvec a CG step. It also keeps the last
+    outer iteration's CG: ``cg_point`` (where it ran), ``cg_step`` (the
+    step ``s`` it returned) and ``cg_residual`` (the ``r`` it carried, which
+    without bounds is ``-g - H s`` by the products it ran), so that the
+    product the solve spent its time in can be checked after the fact.
 
     ``make_hvp(x, *args) -> (v -> H v)``: optional specialized
     Hessian-vector factory, called once per outer iteration (its

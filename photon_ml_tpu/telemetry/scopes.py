@@ -31,6 +31,13 @@ FE_RMATVEC = "photon.fe.rmatvec"
 # FE_MATVEC, opened only where a matrix has a coded slot.
 FE_MATVEC_CODED = "photon.fe.matvec.coded"
 FE_MATVEC_GATHERED = "photon.fe.matvec.gathered"
+# one Hessian-vector product of a trust-region (TRON) solve, one a CG step:
+# with the margin-cached product (``GLMObjective.make_tron_hvp``) a matvec
+# and an rmatvec over the curvature weights of the outer iteration. A child
+# of FE_SOLVE (a random effect's TRON opens it under RE_SOLVE), never a
+# leaf: the margin pass and the trial's value and gradient of an outer
+# iteration stay under the solve's own name
+FE_HVP = "photon.fe.hvp"
 # the programs that count a sparse matrix on the device and lay it out
 # (``ops.features.sparse_rows_to_device``): at construction, never in a fit
 FE_LAYOUT = "photon.fe.layout"
@@ -105,13 +112,15 @@ def place(path: str) -> dict:
     ``photon.cd.<name>``; ``size_class``: the ``r<rows>`` under
     ``photon.re.solve`` or ``photon.mf.latent``; ``product``: the sparse product
     (``photon.fe.matvec`` / ``.rmatvec``) under the leaf, as
-    ``<leaf>/<product>``; ``part``: the matvec's coded or gathered slots
-    (PR 36), as ``<leaf>/<product>/<part>``; ``scoped``: under any
-    ``photon.*`` at all."""
+    ``<leaf>/<product>``, and so the trust-region solve's Hessian-vector
+    product (``photon.fe.hvp``; a sparse product inside it keeps its own
+    key); ``part``: the matvec's coded or gathered slots, as
+    ``<leaf>/<product>/<part>``;
+    ``scoped``: under any ``photon.*`` at all."""
     leaf = coordinate = size_class = product = piece = None
     parts = path.split("/")
     for i, part in enumerate(parts):
-        if part in FE_PRODUCT_SCOPES and leaf:
+        if (part in FE_PRODUCT_SCOPES or part == FE_HVP) and leaf:
             product = f"{leaf}/{part}"
         elif part in FE_MATVEC_PARTS and product:
             piece = f"{product}/{part}"
@@ -243,9 +252,19 @@ COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
 #: solvers' own counts: a margin-cached L-BFGS solve of ``it`` iterations is
 #: ``it + 1`` matvec and ``it + 1`` rmatvec (``OptimizerResult.iterations``);
 #: the block's scoring pass is one matvec more a sweep and is not counted
-#: here. 0 where no fixed effect is sparse; a TRON, OWL-QN or bounded solve
-#: (whose iterations are not products) adds nothing.
+#: here. A TRON solve is ``2 + 3 * attempted + 2 * cg`` products (its own
+#: counts, below; two more an attempted iteration with bounds). 0 where no
+#: fixed effect is sparse; an OWL-QN or bounded L-BFGS solve (whose
+#: iterations are not products) adds nothing.
 COUNTER_FE_PRODUCTS = "training.fe.products"
+#: Per run, over its fixed-effect coordinates' trust-region (TRON) solves,
+#: from the solvers' own counts: the inner CG steps, one Hessian-vector
+#: product (``photon.fe.hvp``) each (``OptimizerResult.cg_iterations``), and
+#: the outer iterations run, accepted or rejected
+#: (``OptimizerResult.attempted_iterations``). 0 where no fixed effect runs
+#: TRON.
+COUNTER_FE_CG_STEPS = "training.fe.cg_steps"
+COUNTER_FE_TRON_STEPS = "training.fe.tron_steps"
 #: Per run, over its factored coordinates' updates: the alternations (latent
 #: solves then a refit of B) they ran, and the solver iterations of those
 #: refits, from the trackers' ``iterations`` (a fetch: only while telemetry
