@@ -58,6 +58,7 @@ Array = jax.Array
 # telemetry is off).
 _M_RUNS = telemetry.counter(scopes.COUNTER_CD_RUNS)
 _M_COLD_STARTS = telemetry.counter(scopes.COUNTER_CD_COLD_STARTS)
+_M_DISPATCH_MOVES = telemetry.counter(scopes.COUNTER_CD_DISPATCH_MOVES)
 _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
 _M_FE_PRODUCTS = telemetry.counter(scopes.COUNTER_FE_PRODUCTS)
 _M_FE_CG_STEPS = telemetry.counter(scopes.COUNTER_FE_CG_STEPS)
@@ -169,6 +170,9 @@ class CoordinateDescent:
         # block functions whose instruction table is still to be published:
         # each leaves at its first dispatch (run())
         self._table_due: set = set()
+        # block function -> {argument leaf path: the sharding its compiled
+        # executable takes that leaf with}, read at its first dispatch
+        self._arg_shardings: Dict[object, dict] = {}
         self._val_scorer = None
         self._cold_cache = None
         # Shared retrace infrastructure (utils/tracing_guard.py): every
@@ -377,16 +381,29 @@ class CoordinateDescent:
         has returned (the device is busy with it meanwhile): which scope
         each of its compiled instructions belongs to, read from the
         executable that now runs and kept as strings
-        (``utils.compile_cache.instruction_scopes``). Every later dispatch
-        of that function: one set lookup. Called after the dispatch, never
-        around it: no frame of it is above the solvers when they trace."""
-        if fn not in self._table_due:
-            return
-        self._table_due.discard(fn)
-        t0 = time.perf_counter()
-        note_instructions(
-            scopes.CD_BLOCK,
-            dispatched_executable(scopes.CD_BLOCK, fn, args), since=t0)
+        (``utils.compile_cache.instruction_scopes``), and the sharding it
+        takes each argument leaf with. Every later dispatch of that
+        function: one set lookup, and while telemetry is on the count of
+        the argument leaves the dispatch had to move to those shardings
+        (``training.cd.dispatch_moves``; a leaf the executable does not
+        read is not moved). Called after the dispatch, never around it: no
+        frame of it is above the solvers when they trace."""
+        if fn in self._table_due:
+            self._table_due.discard(fn)
+            t0 = time.perf_counter()
+            compiled = dispatched_executable(scopes.CD_BLOCK, fn, args)
+            note_instructions(scopes.CD_BLOCK, compiled, since=t0)
+            self._arg_shardings[fn] = dict(
+                jax.tree_util.tree_flatten_with_path(
+                    compiled.input_shardings[0])[0])
+        if telemetry.enabled():
+            taken = self._arg_shardings[fn]
+            _M_DISPATCH_MOVES.inc(sum(
+                1 for path, leaf in jax.tree_util.tree_flatten_with_path(
+                    args)[0]
+                if isinstance(leaf, jax.Array) and path in taken
+                and not leaf.sharding.is_equivalent_to(taken[path],
+                                                       leaf.ndim)))
 
     def run(
         self,
@@ -510,6 +527,9 @@ class CoordinateDescent:
                     # tracing_guard's per_fn=1 invariant below).
                     params = {n: jax.tree.map(jnp.asarray, p)
                               for n, p in params.items()}
+                    # laid out as a cold start's are: one executable of
+                    # the block whatever the start
+                    params, _ = _place_params(self.coordinates, params)
 
             def _sync_models():
                 for m in names:
@@ -527,8 +547,9 @@ class CoordinateDescent:
                         self.coordinates[n].pure_score(data_args[n],
                                                        params[n]))
                     for n in names}
-                score_dtype = np.dtype(next(iter(scores.values())).dtype)
-                rows = self._training_rows(score_dtype)
+                first_score = next(iter(scores.values()))
+                score_dtype = np.dtype(first_score.dtype)
+                rows = self._training_rows(score_dtype, first_score.sharding)
 
             # Device-resident results of fused iteration BLOCKS, appended in
             # step order and fetched host-side in ONE transfer per sync point.
@@ -751,22 +772,31 @@ class CoordinateDescent:
         it replaces: each run needs vectors of its own, of exactly the
         shape and dtype ``pure_score`` returns (``jax.eval_shape``: a
         trace, nothing runs) and, where the coordinate's data spans
-        devices, with the sharding its compiled output carries."""
+        devices, with the sharding its compiled output carries.
+
+        Where a coordinate's data lies over a mesh, its parameters are laid
+        out here with the shardings the block takes them with
+        (``Coordinate.param_shardings``): left on one device, every cold
+        dispatch would move each leaf over the mesh again while the chips
+        wait. On one device they stay as ``jnp.asarray`` made them."""
         if self._cold_cache is not None:
             return self._cold_cache
         models = {n: c.initialize_model()
                   for n, c in self.coordinates.items()}
         params = {n: jax.tree.map(jnp.asarray, c.params_of(models[n]))
                   for n, c in self.coordinates.items()}
-        self._cold_cache = _ColdStart(models, params, tuple(
-            (n, _score_spec(c, data_args[n], params[n]))
-            for n, c in self.coordinates.items() if c.zero_start))
+        specs = tuple((n, _score_spec(c, data_args[n], params[n]))
+                      for n, c in self.coordinates.items() if c.zero_start)
+        params, placed = _place_params(self.coordinates, params)
+        telemetry.gauge(scopes.GAUGE_CD_COLD_PLACED_LEAVES).set(placed)
+        self._cold_cache = _ColdStart(models, params, specs)
         return self._cold_cache
 
-    def _training_rows(self, dtype) -> Tuple[Array, Array, Array]:
+    def _training_rows(self, dtype, sharding) -> Tuple[Array, Array, Array]:
         """(labels, offsets, weights) aligned with the global row order,
         taken from the first coordinate's data. Cached — built once per run,
-        kept in HBM."""
+        kept in HBM. Over a mesh they are laid out as the score vectors are
+        (``sharding``), as the block takes them."""
         cached = getattr(self, "_rows_cache", None)
         if cached is not None:
             return cached
@@ -784,6 +814,8 @@ class CoordinateDescent:
             # Random-effect-only: reconstruct from the blocks.
             rows = _rows_from_blocks(first.dataset)
             rows = tuple(r.astype(dtype) for r in rows)
+        if self._mesh() is not None:
+            rows = jax.device_put(rows, sharding)
         self._rows_cache = rows
         return rows
 
@@ -795,6 +827,18 @@ class _ColdStart(NamedTuple):
     models: Dict[str, object]
     params: Dict[str, object]
     score_specs: Tuple[Tuple[str, jax.ShapeDtypeStruct], ...]
+
+
+def _place_params(coordinates: Mapping[str, Coordinate], params):
+    """``params`` with the parameters of every coordinate that states its
+    ``param_shardings()`` laid out with them, in ONE ``device_put``, and the
+    number of leaves so placed."""
+    shardings = {n: s for n, c in coordinates.items()
+                 if (s := c.param_shardings()) is not None}
+    if not shardings:
+        return params, 0
+    placed = jax.device_put({n: params[n] for n in shardings}, shardings)
+    return {**params, **placed}, len(jax.tree.leaves(shardings))
 
 
 def _score_spec(coord: Coordinate, data, params) -> jax.ShapeDtypeStruct:

@@ -128,6 +128,15 @@ class Coordinate:
     def pure_score(self, data, params) -> Array:
         raise NotImplementedError
 
+    def param_shardings(self):
+        """The shardings the fused block takes ``params_of(model)`` with, as
+        a pytree of ``NamedSharding`` like the params, where the coordinate's
+        data lies over a mesh and the layout follows from that data alone;
+        None otherwise (one device, or a layout only the compiler decides).
+        ``CoordinateDescent`` places a run's parameters with it once, so no
+        dispatch of the block moves them."""
+        return None
+
     def sparse_work(self, trackers=()):
         """``(counts, products)``: what the sparse chooser counted and chose
         for this coordinate's matrix (``ops.features.LayoutCounts``; None
@@ -321,6 +330,15 @@ class FixedEffectCoordinate(Coordinate):
         from photon_ml_tpu.models.coefficients import Coefficients
         return model.update_model(
             model.glm.update_coefficients(Coefficients(params)))
+
+    def param_shardings(self):
+        # Rows shard, coefficients replicate. Feature-sharded coefficients
+        # are padded inside the block, so their layout is the compiler's.
+        if self.mesh is None or self.feature_sharding:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return NamedSharding(self.mesh, PartitionSpec())
 
     def pure_update(self, data, params, residual, rng_key):
         batch, normalization, lb, ub = data
@@ -739,6 +757,17 @@ class RandomEffectCoordinate(Coordinate):
 
     def model_of(self, params, model: RandomEffectModel):
         return model.with_coefs(list(params))
+
+    def param_shardings(self):
+        # each size class's [E, d] split by entity, like its block
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from photon_ml_tpu.parallel import DATA_AXIS
+
+        sharding = NamedSharding(self.mesh, PartitionSpec(DATA_AXIS))
+        return tuple(sharding for _ in self.dataset.blocks)
 
     def pure_update(self, data, params, residual, rng_key):
         # All bucket solves trace into the caller's single dispatch (vs one

@@ -248,6 +248,15 @@ COUNTER_CD_RUNS = "training.cd.runs"
 #: Runs that started cold (no ``initial_model``, no checkpoint restored):
 #: the runs whose initial scores were built and not computed.
 COUNTER_CD_COLD_STARTS = "training.cd.cold_starts"
+#: Gauge, set once per ``CoordinateDescent`` object by its first cold run:
+#: the parameter leaves the cold start placed with a coordinate's
+#: ``param_shardings()`` (1 + the size classes of a mesh GLMix fit; 0 on
+#: one device).
+GAUGE_CD_COLD_PLACED_LEAVES = "training.cd.cold_placed_leaves"
+#: Block-argument leaves at a dispatch whose sharding is not the one the
+#: compiled block takes its argument with: each is a transfer the dispatch
+#: makes before the block can run. Counted while telemetry is on.
+COUNTER_CD_DISPATCH_MOVES = "training.cd.dispatch_moves"
 #: Per run, the sparse products its fixed-effect solves ran, from the
 #: solvers' own counts: a margin-cached L-BFGS solve of ``it`` iterations is
 #: ``it + 1`` matvec and ``it + 1`` rmatvec (``OptimizerResult.iterations``);

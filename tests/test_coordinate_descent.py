@@ -209,11 +209,24 @@ def _factored_coordinates(data):
                                               num_factors=2))}
 
 
+def _mesh_coordinates(data):
+    """``build_coordinates``'s fit with both coordinates over four devices,
+    as ``GameEstimator(mesh=make_mesh(4))`` builds it."""
+    import dataclasses
+
+    from photon_ml_tpu.parallel import make_mesh
+
+    mesh = make_mesh(4)
+    return {n: dataclasses.replace(c, mesh=mesh)
+            for n, c in build_coordinates(data).items()}
+
+
 COLD_CASES = {
     "fixed+random": build_coordinates,
     "fixed": lambda data: {"fixed": build_coordinates(data)["fixed"]},
     "random": lambda data: {"perUser": build_coordinates(data)["perUser"]},
     "factored": _factored_coordinates,
+    "mesh": _mesh_coordinates,
 }
 
 
@@ -321,6 +334,88 @@ def test_cold_start_scores_nothing_before_the_block(rng, monkeypatch, case):
     assert compile_cache.compile_ledger() == before  # nothing new was needed
     assert second.objective_history == first.objective_history
     compile_cache.reset_compile_ledger()
+
+
+@pytest.mark.parametrize("case", sorted(COLD_CASES))
+def test_cold_parameters_lie_where_the_block_takes_them(rng, case):
+    """Over a mesh the cold start lays its parameters out once, with the
+    shardings the compiled block takes them with (``param_shardings``): a
+    later cold run's dispatch moves nothing between devices, and the block
+    compiles once for the object. On one device nothing is placed: the
+    parameters stay the uncommitted arrays ``jnp.asarray`` made."""
+    import jax
+
+    from photon_ml_tpu.algorithm.coordinate_descent import _zero_vectors
+
+    data, *_ = make_glmix_data(rng, n=320)
+    coords = COLD_CASES[case](data)
+    cd = CoordinateDescent(coords, TASK)
+    first = cd.run(1, seed=7)
+    with jax.transfer_guard_device_to_device("disallow"):
+        second = cd.run(1, seed=7)
+    assert second.objective_history == first.objective_history
+    assert cd.tracing_guard.counts() == {"block:1": 1}
+    start = cd._cold_cache
+    args = ({n: c.step_data() for n, c in coords.items()},
+            {n: c.penalty_data() for n, c in coords.items()},
+            start.params, _zero_vectors(start.score_specs),
+            jax.random.PRNGKey(7), np.uint32(0), cd._rows_cache)
+    taken = cd._fused_block_fn(1).lower(*args).compile().input_shardings
+    stated = {n: c.param_shardings() for n, c in coords.items()}
+    assert (case == "mesh") == any(s is not None for s in stated.values())
+    for n in coords:
+        leaves = jax.tree.leaves(start.params[n])
+        if stated[n] is None:
+            assert not any(x.committed for x in leaves)
+            assert all(len(x.sharding.device_set) == 1 for x in leaves)
+            continue
+        assert [x.sharding for x in leaves] == jax.tree.leaves(stated[n])
+        assert jax.tree.leaves(taken[0][2][n]) == jax.tree.leaves(stated[n])
+
+
+def test_placed_cold_start_is_bitwise_the_inferred_one(rng, monkeypatch,
+                                                       telemetry_on):
+    """Over four devices, two cold runs on one object (parameters placed
+    once) give bit for bit what a run handed the coordinates' own zero
+    models gives where no coordinate states its shardings: today's
+    uncommitted arrays, which every dispatch lays over the mesh again. The
+    objective history, the coefficients and the scores agree; the counters
+    tell the two apart."""
+    from photon_ml_tpu.telemetry import scopes
+
+    data, *_ = make_glmix_data(rng, n=330)  # no multiple of the mesh
+    coords = _mesh_coordinates(data)
+    cd = CoordinateDescent(coords, TASK)
+    cold = [cd.run(2, seed=5) for _ in range(2)]
+    moves = telemetry_on.counter(scopes.COUNTER_CD_DISPATCH_MOVES)
+    placed = telemetry_on.gauge(scopes.GAUGE_CD_COLD_PLACED_LEAVES)
+    leaves = 1 + len(coords["perUser"].dataset.blocks)
+    assert (placed.value, placed.calls, moves.value) == (leaves, 1, 0)
+    for cls in {type(c) for c in coords.values()}:
+        monkeypatch.setattr(cls, "param_shardings", lambda self: None)
+    inferred = CoordinateDescent(coords, TASK).run(
+        2, seed=5, initial_model=_initial_game_model(coords))
+    assert moves.value == leaves  # one dispatch, every parameter leaf
+    for run in cold:
+        assert run.objective_history == inferred.objective_history
+        for a, b in zip(_leaves(cd, run), _leaves(cd, inferred)):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        for n, c in coords.items():
+            assert np.array_equal(
+                np.asarray(c.score(run.model.get_model(n))),
+                np.asarray(c.score(inferred.model.get_model(n))))
+
+
+def test_nothing_is_placed_or_moved_on_one_device(rng, telemetry_on):
+    from photon_ml_tpu.telemetry import scopes
+
+    data, *_ = make_glmix_data(rng)
+    cd = CoordinateDescent(build_coordinates(data), TASK)
+    cd.run(1, seed=1)
+    cd.run(1, seed=1)
+    placed = telemetry_on.gauge(scopes.GAUGE_CD_COLD_PLACED_LEAVES)
+    assert (placed.value, placed.calls) == (0, 1)
+    assert telemetry_on.counter(scopes.COUNTER_CD_DISPATCH_MOVES).value == 0
 
 
 def test_zero_vectors_carry_what_pure_score_returns(rng):
@@ -456,6 +551,13 @@ def test_cold_start_counter_is_silent_while_telemetry_is_off(rng):
 
     telemetry.disable()
     telemetry.reset()
+    from photon_ml_tpu.telemetry import scopes
+
     data, *_ = make_glmix_data(rng)
     CoordinateDescent(build_coordinates(data), TASK).run(1)
     assert _counts(telemetry) == (0, 0)
+    # nor do the mesh placement's gauge and counter say anything
+    CoordinateDescent(_mesh_coordinates(data), TASK).run(1)
+    assert _counts(telemetry) == (0, 0)
+    assert telemetry.gauge(scopes.GAUGE_CD_COLD_PLACED_LEAVES).calls == 0
+    assert telemetry.counter(scopes.COUNTER_CD_DISPATCH_MOVES).value == 0
