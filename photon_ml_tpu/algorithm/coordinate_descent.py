@@ -63,6 +63,7 @@ _M_EXCHANGE_DIVIDED = telemetry.counter(scopes.COUNTER_RE_EXCHANGE_DIVIDED)
 _M_FE_PRODUCTS = telemetry.counter(scopes.COUNTER_FE_PRODUCTS)
 _M_FE_CG_STEPS = telemetry.counter(scopes.COUNTER_FE_CG_STEPS)
 _M_FE_TRON_STEPS = telemetry.counter(scopes.COUNTER_FE_TRON_STEPS)
+_M_FE_PASSES = telemetry.counter(scopes.COUNTER_FE_PASSES)
 _M_MF_ALTERNATIONS = telemetry.counter(scopes.COUNTER_MF_ALTERNATIONS)
 _M_MF_REFIT_ITERATIONS = telemetry.counter(
     scopes.COUNTER_MF_REFIT_ITERATIONS)
@@ -747,6 +748,7 @@ class CoordinateDescent:
                             cg, attempted = c.tron_work(lazy[name])
                             _M_FE_CG_STEPS.inc(cg)
                             _M_FE_TRON_STEPS.inc(attempted)
+                            _M_FE_PASSES.inc(c.tron_passes(lazy[name]))
                         if c.factored:
                             runs, its = c.factored_work(lazy[name])
                             _M_MF_ALTERNATIONS.inc(runs)

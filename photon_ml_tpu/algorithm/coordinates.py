@@ -81,8 +81,8 @@ class Coordinate:
     # counted under ``training.mf.*`` (and then it has ``factored_work``)
     factored = False
     # whether it is a fixed effect solved by TRON, counted under
-    # ``training.fe.cg_steps`` / ``.tron_steps`` (and then it has
-    # ``tron_work``)
+    # ``training.fe.cg_steps`` / ``.tron_steps`` / ``.passes`` (and then it
+    # has ``tron_work`` and ``tron_passes``)
     tron = False
 
     @property
@@ -232,16 +232,10 @@ class FixedEffectCoordinate(Coordinate):
         counts = layout_counts(self._batch.features)
         if counts is None:
             return None, 0
+        if self.tron:
+            return counts, self.tron_passes(trackers)
         bounded = (self.lower_bounds is not None
                    or self.upper_bounds is not None)
-        if self.tron:
-            # the first value and gradient; a margin pass and a trial's
-            # value and gradient an attempted iteration (and with bounds
-            # the realized step's product); a matvec and an rmatvec a CG
-            # step
-            cg, attempted = self.tron_work(trackers)
-            return counts, (2 * len(trackers) + (5 if bounded else 3)
-                            * attempted + 2 * cg)
         # A margin-cached L-BFGS solve of ``it`` iterations is ``it + 1``
         # matvec and ``it + 1`` rmatvec whatever its line search does; of
         # an OWL-QN or bounded solve the iterations are not products.
@@ -262,6 +256,12 @@ class FixedEffectCoordinate(Coordinate):
         return (sum(int(np.asarray(tr.cg_iterations)) for tr in counted),
                 sum(int(np.asarray(tr.attempted_iterations))
                     for tr in counted))
+
+    def tron_passes(self, trackers=()) -> int:
+        """The reads of X the trust-region solves of ``trackers`` made,
+        from the solver's own count (``OptimizerResult.feature_passes``)."""
+        return sum(int(np.asarray(tr.feature_passes)) for tr in trackers
+                   if tr.feature_passes is not None)
 
     def _pad_d(self, arr, fill=0.0):
         """Zero-pad a [d] vector to the feature-sharded width (no-op

@@ -172,6 +172,22 @@ class GLMObjective:
         u = batch.weights * self.loss.d1(z, batch.labels)
         return self._jt_product(u, batch) + l2_weight * coef
 
+    def margins_value_and_grad(
+        self, coef: Array, batch: GLMBatch, l2_weight: Array | float = 0.0,
+        z: Optional[Array] = None,
+    ) -> Tuple[Array, Array, Array]:
+        """``(z, value, gradient)`` at ``coef``: its margins (one matvec,
+        unless given as ``z``) and the value and gradient from them (one
+        rmatvec). The fused TRON's ``margins_value_and_grad`` hook: it
+        carries ``z`` through its loop and hands a trial point its margins
+        ``z + X s``."""
+        if z is None:
+            z = self.margins(coef, batch)
+        value = self.value_from_margins(z, jnp.vdot(coef, coef), batch,
+                                        l2_weight)
+        return z, value, self.gradient_from_margins(coef, z, batch,
+                                                    l2_weight)
+
     def curvature_from_margins(self, z: Array, batch: GLMBatch) -> Array:
         """d2_i = w_i l''(z_i, y_i) — the Gauss-Newton curvature weights,
         computed ONCE per outer TRON iteration and reused by every inner
@@ -187,9 +203,18 @@ class GLMObjective:
         matvec + one rmatvec (J v is affine: margin_direction), vs the
         ~2x cost of jvp-of-grad which also re-derives the margin pass.
         Traced under ``photon.fe.hvp``: TRON's CG runs one a step."""
+        return self.hessian_vector_and_margins(vec, d2, batch, l2_weight)[0]
+
+    def hessian_vector_and_margins(
+        self, vec: Array, d2: Array, batch: GLMBatch,
+        l2_weight: Array | float = 0.0,
+    ) -> Tuple[Array, Array]:
+        """``(H @ vec, J vec)``: `hessian_vector_from_margins` and the
+        direction's margins it made on the way (``margin_direction``), for
+        a caller that sums them (TRON's CG: ``X s`` beside ``s``)."""
         with jax.named_scope(scopes.FE_HVP):
             jv = self.margin_direction(vec, batch)
-            return self._jt_product(d2 * jv, batch) + l2_weight * vec
+            return self._jt_product(d2 * jv, batch) + l2_weight * vec, jv
 
     def make_tron_hvp(self, x: Array, batch: GLMBatch,
                       l2_weight: Array | float = 0.0):
@@ -202,6 +227,16 @@ class GLMObjective:
         z = self.margins(x, batch)
         d2 = self.curvature_from_margins(z, batch)
         return lambda v: self.hessian_vector_from_margins(
+            v, d2, batch, l2_weight)
+
+    def make_tron_hvp_at_margins(self, z: Array, batch: GLMBatch,
+                                 l2_weight: Array | float = 0.0):
+        """`make_tron_hvp` from the margins at the point, which the fused
+        TRON carries (its ``make_hvp`` where it has
+        ``margins_value_and_grad``): no margin pass; each product returns
+        the direction's margins too, ``v -> (H v, X v)``."""
+        d2 = self.curvature_from_margins(z, batch)
+        return lambda v: self.hessian_vector_and_margins(
             v, d2, batch, l2_weight)
 
     # -- second-order -----------------------------------------------------

@@ -196,6 +196,10 @@ class OptimizerResult:
     cg_point: Optional[Array] = None
     cg_step: Optional[Array] = None
     cg_residual: Optional[Array] = None
+    # The fused TRON's reads of the feature matrix, with the GLM's product:
+    # 2 + attempted + 2 * cg where it carries the margins (optimization/
+    # tron.py). None from the other solvers and the jvp-of-grad product.
+    feature_passes: Optional[Array] = None  # i32
 
     @property
     def converged(self) -> Array:
@@ -210,6 +214,7 @@ class OptimizerResult:
             self.value_history, self.grad_norm_history, self.coef_history,
             self.cg_iterations, self.attempted_iterations,
             self.cg_point, self.cg_step, self.cg_residual,
+            self.feature_passes,
         ), None
 
     @classmethod

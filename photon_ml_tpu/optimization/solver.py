@@ -64,13 +64,19 @@ def solve_glm(
         # L-BFGS on the 5k-entity benchmark block), so CG/quasi-Newton wins
         # on device. minimize_newton remains available for explicit use
         # (fast and robust on CPU f64).
+        # Margin-cached GLM Hessian-vector products: one matvec+rmatvec
+        # per CG step instead of jvp-of-grad's ~2x. Without bounds the
+        # loop carries the margins: the curvature weights and a trial's
+        # value come from them, not from passes over X.
+        bounded = lower_bounds is not None or upper_bounds is not None
         return minimize_tron(
             fun, coef0, args=(batch, l2_arr), max_iter=config.max_iterations,
             tol=config.tolerance, lower_bounds=lower_bounds,
             upper_bounds=upper_bounds, track_coefficients=track_coefficients,
-            # Margin-cached GLM Hessian-vector products: one
-            # matvec+rmatvec per CG step instead of jvp-of-grad's ~2x.
-            make_hvp=objective.make_tron_hvp)
+            make_hvp=(objective.make_tron_hvp if bounded
+                      else objective.make_tron_hvp_at_margins),
+            margins_value_and_grad=(
+                None if bounded else objective.margins_value_and_grad))
     if l1 > 0:
         if lower_bounds is not None or upper_bounds is not None:
             raise ValueError(

@@ -49,30 +49,36 @@ def _truncated_cg(hvp, g, delta, max_cg, dtype):
 
     Returns (s, r, k) with r the final residual -g - H s (needed for the
     predicted-reduction formula) and k the CG steps taken. One hvp per
-    step — the hot loop (reference: TRON.scala:280-340).
+    step — the hot loop (reference: TRON.scala:280-340). Where ``hvp``
+    also returns its direction's margins, ``d -> (H d, X d)``, the CG sums
+    them as it sums the step, and ``s`` is the pair ``(s, X s)``.
     """
     d0 = -g
     s0 = jnp.zeros_like(g)
     r0 = -g
     rtr0 = jnp.vdot(r0, r0)
     stop_norm = _CG_XI * jnp.linalg.norm(g)
+    out = jax.eval_shape(hvp, g)
+    xs0 = (jnp.zeros(out[1].shape, out[1].dtype)
+           if isinstance(out, tuple) else None)
 
     class CGState(NamedTuple):
         s: Array
+        xs: Optional[Array]  # X s, where the product reports X d
         r: Array
         d: Array
         rtr: Array
         k: Array
         done: Array
 
-    init = CGState(s0, r0, d0, rtr0, jnp.zeros((), jnp.int32),
+    init = CGState(s0, xs0, r0, d0, rtr0, jnp.zeros((), jnp.int32),
                    jnp.linalg.norm(r0) <= stop_norm)
 
     def cond(st: CGState):
         return jnp.logical_and(~st.done, st.k < max_cg)
 
     def body(st: CGState):
-        hd = hvp(st.d)
+        hd, xd = hvp(st.d) if st.xs is not None else (hvp(st.d), None)
         dhd = jnp.vdot(st.d, hd)
         # Guard: non-positive curvature direction -> march to the boundary.
         alpha = st.rtr / jnp.where(dhd > 0, dhd, jnp.asarray(1.0, dtype))
@@ -93,6 +99,7 @@ def _truncated_cg(hvp, g, delta, max_cg, dtype):
 
         step = jnp.where(crossed, tau, alpha)
         s_new = st.s + step * st.d
+        xs_new = None if xd is None else st.xs + step * xd
         r_new = st.r - step * hd
 
         rtr_new = jnp.vdot(r_new, r_new)
@@ -102,15 +109,18 @@ def _truncated_cg(hvp, g, delta, max_cg, dtype):
         done_new = jnp.logical_or(
             crossed, jnp.sqrt(rtr_new) <= stop_norm
         )
-        new = CGState(s_new, r_new, d_new, rtr_new, st.k + 1, done_new)
+        new = CGState(s_new, xs_new, r_new, d_new, rtr_new, st.k + 1,
+                      done_new)
         return jax.tree.map(lambda a, b: jnp.where(st.done, a, b), st, new)
 
     final = lax.while_loop(cond, body, init)
-    return final.s, final.r, final.k
+    s = final.s if final.xs is None else (final.s, final.xs)
+    return s, final.r, final.k
 
 
 class _TronState(NamedTuple):
     x: Array
+    z: Optional[Array]  # the margins at x, where the loop carries them
     f: Array
     g: Array
     delta: Array
@@ -132,14 +142,16 @@ class _TronState(NamedTuple):
     jax.jit,
     static_argnames=("fun", "max_iter", "tol", "max_cg",
                      "max_improvement_failures", "has_bounds",
-                     "track_coefficients", "make_hvp"),
+                     "track_coefficients", "make_hvp",
+                     "margins_value_and_grad"),
 )
 def _minimize_tron_impl(
     fun, x0, args, lower, upper, *, max_iter, tol, max_cg,
     max_improvement_failures, has_bounds, track_coefficients=False,
-    make_hvp=None,
+    make_hvp=None, margins_value_and_grad=None,
 ) -> OptimizerResult:
     vg = jax.value_and_grad(fun)
+    carried = margins_value_and_grad is not None
     dtype = x0.dtype
     lo = lower if has_bounds else None
     hi = upper if has_bounds else None
@@ -152,7 +164,11 @@ def _minimize_tron_impl(
         return jnp.linalg.norm(x - _project(x - g, lo, hi))
 
     x0 = _project(x0, lo, hi)
-    f0, g0 = vg(x0, *args)
+    if carried:
+        z0, f0, g0 = margins_value_and_grad(x0, *args)
+    else:
+        z0 = None
+        f0, g0 = vg(x0, *args)
     gnorm0 = proj_grad_norm(x0, g0)
     f0_scale = jnp.maximum(jnp.abs(f0), jnp.asarray(1e-30, dtype))
 
@@ -163,7 +179,7 @@ def _minimize_tron_impl(
                  if track_coefficients else None)
 
     init = _TronState(
-        x=x0, f=f0, g=g0, delta=gnorm0,
+        x=x0, z=z0, f=f0, g=g0, delta=gnorm0,
         it=jnp.zeros((), jnp.int32), attempted=jnp.zeros((), jnp.int32),
         cg=jnp.zeros((), jnp.int32), cg_x=x0, cg_s=jnp.zeros_like(x0),
         cg_r=jnp.zeros_like(x0), fails=jnp.zeros((), jnp.int32),
@@ -178,7 +194,12 @@ def _minimize_tron_impl(
         return st.reason == int(ConvergenceReason.NOT_CONVERGED)
 
     def body(st: _TronState):
-        if make_hvp is not None:
+        if carried:
+            # The GLM's product from the margins the loop carries: the
+            # curvature weights with no pass over X, and each CG step's
+            # X d returned beside H d.
+            hvp = make_hvp(st.z, *args)
+        elif make_hvp is not None:
             # Caller-specialized product (GLM: margin-cached, exactly one
             # matvec+rmatvec per CG step; curvature weights computed once
             # per outer iteration and hoisted out of the CG loop).
@@ -205,10 +226,18 @@ def _minimize_tron_impl(
             g_cg, hvp_cg = st.g, hvp
 
         s, r, cg_steps = _truncated_cg(hvp_cg, g_cg, st.delta, max_cg, dtype)
+        if carried:
+            # margins are affine in x: the trial's are z + X s, and its
+            # value and gradient cost one rmatvec
+            s, xs = s
+            z_try = st.z + xs
 
         x_try = _project(st.x + s, lo, hi)
         s_real = x_try - st.x
-        f_new, g_new = vg(x_try, *args)
+        if carried:
+            _, f_new, g_new = margins_value_and_grad(x_try, *args, z=z_try)
+        else:
+            f_new, g_new = vg(x_try, *args)
 
         gs = jnp.vdot(st.g, s_real)
         if has_bounds:
@@ -271,7 +300,8 @@ def _minimize_tron_impl(
         ).astype(jnp.int32)
 
         new = _TronState(
-            x=x_acc, f=f_acc, g=g_acc, delta=delta, it=it_new,
+            x=x_acc, z=jnp.where(accept, z_try, st.z) if carried else None,
+            f=f_acc, g=g_acc, delta=delta, it=it_new,
             attempted=st.attempted + 1, cg=st.cg + cg_steps,
             cg_x=st.x, cg_s=s, cg_r=r,
             fails=fails_new, reason=reason,
@@ -290,6 +320,14 @@ def _minimize_tron_impl(
         return jax.tree.map(lambda a, b: jnp.where(done, a, b), st, new)
 
     final = lax.while_loop(cond, body, init)
+    # Passes over X: the first value and gradient (2), an outer step's own
+    # (the trial's gradient where the margins are carried; else the
+    # margins for the curvature weights and the trial's value and
+    # gradient, and with bounds the realized step's product), two a CG
+    # step. Unknown for the jvp-of-grad product.
+    per_step = 1 if carried else 5 if has_bounds else 3
+    passes = (None if make_hvp is None
+              else 2 + per_step * final.attempted + 2 * final.cg)
     return OptimizerResult(
         x=final.x, value=final.f, grad_norm=jnp.linalg.norm(final.g),
         iterations=final.it, reason=final.reason,
@@ -297,6 +335,7 @@ def _minimize_tron_impl(
         coef_history=final.coef_hist,
         cg_iterations=final.cg, attempted_iterations=final.attempted,
         cg_point=final.cg_x, cg_step=final.cg_s, cg_residual=final.cg_r,
+        feature_passes=passes,
     )
 
 
@@ -776,6 +815,7 @@ def minimize_tron(
     upper_bounds: Optional[Array] = None,
     track_coefficients: bool = False,
     make_hvp: Optional[Callable] = None,
+    margins_value_and_grad: Optional[Callable] = None,
 ) -> OptimizerResult:
     """Minimize twice-differentiable ``fun(x, *args)`` from ``x0``.
 
@@ -785,12 +825,15 @@ def minimize_tron(
     The result counts the work besides the accepted iterations:
     ``cg_iterations``, the CG steps (one Hessian-vector product each) of
     every outer iteration, and ``attempted_iterations``, the outer
-    iterations run, accepted or rejected (each evaluates one trial point).
-    Without bounds and with the GLM's ``make_hvp``, a solve reads the
-    matrix ``2 + 3 * attempted + 2 * cg`` times: the first value and
-    gradient; a margin pass, a trial's value and gradient an outer
-    iteration; a matvec and an rmatvec a CG step. It also keeps the last
-    outer iteration's CG: ``cg_point`` (where it ran), ``cg_step`` (the
+    iterations run, accepted or rejected (each evaluates one trial point),
+    and, with the GLM's product, ``feature_passes``: the reads of the
+    matrix, ``2 + attempted + 2 * cg`` where the margins are carried (the
+    first margins and gradient; a trial's gradient an outer iteration; a
+    matvec and an rmatvec a CG step), ``2 + 3 * attempted + 2 * cg`` with
+    ``make_hvp`` alone (a margin pass and the trial's value and gradient
+    an outer iteration) and two more an outer iteration with bounds (the
+    realized step's product); None without ``make_hvp``. It also keeps the
+    last outer iteration's CG: ``cg_point`` (where it ran), ``cg_step`` (the
     step ``s`` it returned) and ``cg_residual`` (the ``r`` it carried, which
     without bounds is ``-g - H s`` by the products it ran), so that the
     product the solve spent its time in can be checked after the fact.
@@ -800,10 +843,25 @@ def minimize_tron(
     closed-over precomputations hoist out of the inner CG loop). Defaults
     to jvp-of-grad. Must be a STABLE callable (hashed as a static jit
     argument).
+
+    ``margins_value_and_grad(x, *args, z=None) -> (z, f, g)``: optional,
+    for a GLM without bounds (``GLMObjective.margins_value_and_grad``): the
+    margins at ``x`` (affine in ``x``; made where ``z`` is not given) and
+    the value and gradient from them. With it the loop carries the margins
+    at its point: ``make_hvp`` is then called with them, ``make_hvp(z,
+    *args)`` (``GLMObjective.make_tron_hvp_at_margins``), its product
+    returns the direction's margins too, ``v -> (H v, X v)``, and a trial's
+    margins are ``z + X s`` from the CG's own products. Projection onto
+    bounds is not affine, so bounds take the point's ``make_hvp``.
     """
     x0 = jnp.asarray(x0)
     dtype = x0.dtype
     has_bounds = lower_bounds is not None or upper_bounds is not None
+    if margins_value_and_grad is not None and (has_bounds
+                                               or make_hvp is None):
+        raise ValueError(
+            "carried margins need the product from them (make_hvp) and no "
+            "bounds: a projected step's margins are not z + X s")
     d = x0.shape[-1]
     lo = (jnp.full((d,), -jnp.inf, dtype) if lower_bounds is None
           else jnp.asarray(lower_bounds, dtype))
@@ -813,5 +871,5 @@ def minimize_tron(
         fun, x0, args, lo, hi, max_iter=max_iter, tol=tol, max_cg=max_cg,
         max_improvement_failures=max_improvement_failures,
         has_bounds=has_bounds, track_coefficients=track_coefficients,
-        make_hvp=make_hvp,
+        make_hvp=make_hvp, margins_value_and_grad=margins_value_and_grad,
     )

@@ -32,11 +32,12 @@ FE_RMATVEC = "photon.fe.rmatvec"
 FE_MATVEC_CODED = "photon.fe.matvec.coded"
 FE_MATVEC_GATHERED = "photon.fe.matvec.gathered"
 # one Hessian-vector product of a trust-region (TRON) solve, one a CG step:
-# with the margin-cached product (``GLMObjective.make_tron_hvp``) a matvec
-# and an rmatvec over the curvature weights of the outer iteration. A child
+# with the margin-cached product (``GLMObjective.make_tron_hvp_at_margins``,
+# under bounds ``make_tron_hvp``) a matvec and an rmatvec over the
+# curvature weights of the outer iteration. A child
 # of FE_SOLVE (a random effect's TRON opens it under RE_SOLVE), never a
-# leaf: the margin pass and the trial's value and gradient of an outer
-# iteration stay under the solve's own name
+# leaf: the trial's gradient of an outer iteration (and under bounds its
+# margin pass and the trial's value) stays under the solve's own name
 FE_HVP = "photon.fe.hvp"
 # the programs that count a sparse matrix on the device and lay it out
 # (``ops.features.sparse_rows_to_device``): at construction, never in a fit
@@ -261,8 +262,7 @@ COUNTER_CD_DISPATCH_MOVES = "training.cd.dispatch_moves"
 #: solvers' own counts: a margin-cached L-BFGS solve of ``it`` iterations is
 #: ``it + 1`` matvec and ``it + 1`` rmatvec (``OptimizerResult.iterations``);
 #: the block's scoring pass is one matvec more a sweep and is not counted
-#: here. A TRON solve is ``2 + 3 * attempted + 2 * cg`` products (its own
-#: counts, below; two more an attempted iteration with bounds). 0 where no
+#: here. A TRON solve is its own ``feature_passes`` (below). 0 where no
 #: fixed effect is sparse; an OWL-QN or bounded L-BFGS solve (whose
 #: iterations are not products) adds nothing.
 COUNTER_FE_PRODUCTS = "training.fe.products"
@@ -270,10 +270,13 @@ COUNTER_FE_PRODUCTS = "training.fe.products"
 #: from the solvers' own counts: the inner CG steps, one Hessian-vector
 #: product (``photon.fe.hvp``) each (``OptimizerResult.cg_iterations``), and
 #: the outer iterations run, accepted or rejected
-#: (``OptimizerResult.attempted_iterations``). 0 where no fixed effect runs
-#: TRON.
+#: (``OptimizerResult.attempted_iterations``), and the passes over the
+#: feature matrix they made (``OptimizerResult.feature_passes``: 2 + attempted
+#: + 2 cg a solve where the loop carries the margins, as it does without
+#: bounds). 0 where no fixed effect runs TRON.
 COUNTER_FE_CG_STEPS = "training.fe.cg_steps"
 COUNTER_FE_TRON_STEPS = "training.fe.tron_steps"
+COUNTER_FE_PASSES = "training.fe.passes"
 #: Per run, over its factored coordinates' updates: the alternations (latent
 #: solves then a refit of B) they ran, and the solver iterations of those
 #: refits, from the trackers' ``iterations`` (a fetch: only while telemetry

@@ -8,6 +8,7 @@ attempted) against a hand count, and the last CG where it ran; the
 Hessian-vector products' scope in the block's instruction table; the
 counters under telemetry; and a TRON solve's sparse products."""
 
+import collections
 import json
 from pathlib import Path
 
@@ -306,7 +307,7 @@ def test_the_other_solvers_leave_the_counts_empty():
                 minimize_owlqn(f, jnp.zeros(3), l1_weight=0.1)):
         assert res.cg_iterations is None and res.attempted_iterations is None
         assert res.cg_point is None and res.cg_step is None
-        assert res.cg_residual is None
+        assert res.cg_residual is None and res.feature_passes is None
 
 
 def test_the_last_cg_is_reported_where_it_ran():
@@ -367,6 +368,9 @@ def test_the_counters_under_telemetry(sound, ref):
     counts = sound["counts"]
     assert counts[scopes.COUNTER_FE_CG_STEPS] == 2 * ref["cg_steps"]
     assert counts[scopes.COUNTER_FE_TRON_STEPS] == 2 * ref["attempted"] == 10
+    # the margins ride the loop: 2 + T + 2 K reads of X a solve
+    assert counts[scopes.COUNTER_FE_PASSES] == 2 * (
+        2 + ref["attempted"] + 2 * ref["cg_steps"])
     # a dense matrix: no sparse product is counted
     assert counts.get(scopes.COUNTER_FE_PRODUCTS, 0) == 0
 
@@ -378,6 +382,7 @@ def test_an_lbfgs_fit_counts_no_trust_region_work(problem):
     counts = telemetry.snapshot()["counters"]
     assert counts.get(scopes.COUNTER_FE_CG_STEPS, 0) == 0
     assert counts.get(scopes.COUNTER_FE_TRON_STEPS, 0) == 0
+    assert counts.get(scopes.COUNTER_FE_PASSES, 0) == 0
 
 
 # -- a TRON solve over a sparse matrix: its products counted ------------------
@@ -416,10 +421,358 @@ def test_sparse_work_counts_a_tron_solves_products():
     assert attempted >= 4 and cg >= attempted
     layout, products = coord.sparse_work([tracker])
     assert layout is not None
-    assert products == 2 + 3 * attempted + 2 * cg
+    # the loop carries the margins: an outer step reads X once, for its
+    # trial's gradient
+    assert products == int(tracker.feature_passes) == 2 + attempted + 2 * cg
     assert counts[scopes.COUNTER_FE_PRODUCTS] == products
     assert counts[scopes.COUNTER_FE_CG_STEPS] == cg
     assert counts[scopes.COUNTER_FE_TRON_STEPS] == attempted
+    assert counts[scopes.COUNTER_FE_PASSES] == products
+
+
+# -- the margins the fused TRON carries ---------------------------------------
+
+TASKS = ("LOGISTIC_REGRESSION", "POISSON_REGRESSION", "LINEAR_REGRESSION")
+LAYOUTS = ("dense", "ell", "csr")
+
+
+def _glm_problem(task, layout, n=300, k=4, d=12, seed=5):
+    """A GLM over ``n`` rows of ``k`` non-zeros out of ``d`` columns, its
+    matrix in ``layout``, float64: the objective and the batch."""
+    import scipy.sparse as sp
+
+    from photon_ml_tpu.ops.features import (
+        DenseFeatures,
+        csr_from_scipy,
+        sparse_rows_to_device,
+    )
+    from photon_ml_tpu.ops.glm_objective import GLMObjective, make_batch
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.types import TaskType
+
+    rng = np.random.default_rng(seed)
+    cols = rng.integers(0, d, (n, k))
+    vals = rng.normal(0, 0.5, (n, k))
+    dense = np.zeros((n, d))
+    np.add.at(dense, (np.arange(n)[:, None], cols), vals)
+    truth = rng.normal(0, 1, d)
+    z = dense @ truth
+    y = {"LOGISTIC_REGRESSION": (rng.random(n) < 1 / (1 + np.exp(-z))),
+         "POISSON_REGRESSION": rng.poisson(np.exp(0.5 * z)),
+         "LINEAR_REGRESSION": z + rng.normal(0, 0.3, n)}[task]
+    features = {
+        "dense": lambda: DenseFeatures(jnp.asarray(dense)),
+        "ell": lambda: sparse_rows_to_device(
+            jnp.asarray(cols, jnp.int32), jnp.asarray(vals), d),
+        "csr": lambda: csr_from_scipy(sp.csr_matrix(dense),
+                                      dtype=jnp.float64),
+    }[layout]()
+    batch = make_batch(features, jnp.asarray(y, jnp.float64),
+                       jnp.asarray(rng.normal(0, 0.1, n)),
+                       jnp.asarray(rng.uniform(0.5, 1.5, n)))
+    return GLMObjective(loss_for_task(TaskType(task))), batch
+
+
+def _tron_config(max_iter=6):
+    from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
+
+    return GLMOptimizationConfiguration.parse(
+        f"{max_iter},1e-12,0.5,1.0,TRON,L2")
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("task", TASKS)
+def test_the_carried_margins_solve_as_the_point_s_own(task, layout):
+    """``solve_glm``'s TRON, which carries the margins, against the same
+    solve given the product from the point alone (a margin pass and the
+    trial's value and gradient an outer step): the same steps, CG steps
+    and coefficients, and two passes over X an outer step fewer."""
+    from photon_ml_tpu.optimization import minimize_tron
+    from photon_ml_tpu.optimization.solver import solve_glm
+
+    with jax.enable_x64(True):
+        obj, batch = _glm_problem(task, layout)
+        if layout == "ell":
+            assert type(batch.features).__name__ == "SlotMajorEllFeatures"
+        x0 = jnp.zeros(batch.features.shape[1])
+        carried = solve_glm(obj, batch, _tron_config(), x0)
+        own = minimize_tron(obj.value, x0, args=(batch, 0.5), max_iter=6,
+                            tol=1e-12, make_hvp=obj.make_tron_hvp)
+    for field in ("iterations", "attempted_iterations", "cg_iterations",
+                  "reason"):
+        assert int(getattr(carried, field)) == int(getattr(own, field))
+    t, k = int(own.attempted_iterations), int(own.cg_iterations)
+    assert t >= 3 and k > t
+    np.testing.assert_allclose(carried.x, own.x, rtol=1e-6,
+                               atol=1e-6 * float(jnp.linalg.norm(own.x)))
+    assert float(carried.value) == pytest.approx(float(own.value), rel=1e-9)
+    assert int(carried.feature_passes) == 2 + t + 2 * k
+    assert int(own.feature_passes) == 2 + 3 * t + 2 * k
+
+
+def test_vmapped_carried_solves_are_each_solve_alone():
+    """A random effect's bucket: ``solve_glm``'s TRON vmapped over three
+    entities, each lane's margins carried, against each entity solved
+    alone, with the carried margins and with the point's product."""
+    from photon_ml_tpu.optimization import minimize_tron
+    from photon_ml_tpu.optimization.solver import solve_glm
+
+    with jax.enable_x64(True):
+        problems = [_glm_problem("LOGISTIC_REGRESSION", "dense", n=120,
+                                 seed=seed) for seed in (1, 2, 3)]
+        obj = problems[0][0]
+        x0 = jnp.zeros(12)
+        stacked = jax.tree.map(lambda *a: jnp.stack(a),
+                               *[b for _, b in problems])
+        lanes = jax.vmap(lambda b: solve_glm(obj, b, _tron_config(), x0))(
+            stacked)
+        for e, (_, batch) in enumerate(problems):
+            alone = solve_glm(obj, batch, _tron_config(), x0)
+            own = minimize_tron(obj.value, x0, args=(batch, 0.5), max_iter=6,
+                                tol=1e-12, make_hvp=obj.make_tron_hvp)
+            for field in ("iterations", "attempted_iterations",
+                          "cg_iterations", "feature_passes"):
+                assert int(getattr(lanes, field)[e]) == int(
+                    getattr(alone, field))
+            assert int(alone.cg_iterations) == int(own.cg_iterations)
+            np.testing.assert_allclose(lanes.x[e], alone.x, rtol=1e-9,
+                                       atol=1e-12)
+            np.testing.assert_allclose(alone.x, own.x, rtol=1e-6, atol=1e-6)
+
+
+class _Recording:
+    """``GLMObjective.margins_value_and_grad`` that hands every point and
+    the margins the solve used there to the host (``seen``)."""
+
+    def __init__(self, objective):
+        self.objective = objective
+        self.seen = []
+
+    def __call__(self, x, batch, l2, z=None):
+        out = self.objective.margins_value_and_grad(x, batch, l2, z=z)
+        jax.debug.callback(lambda a, b: self.seen.append(
+            (np.asarray(a), np.asarray(b))), x, out[0])
+        return out
+
+
+def test_the_carried_margins_stay_the_points_after_five_outer_steps(
+        cell, problem):
+    """The cell's problem at this file's size, float32: the margins a
+    trial is valued at are the last point's plus ``X s`` from the CG's
+    products; after five accepted outer steps of twenty CG steps each they
+    stay within float32 rounding of a fresh ``X x`` at their point."""
+    from benchmark.reference import tron_glm
+    from photon_ml_tpu.ops.features import DenseFeatures
+    from photon_ml_tpu.ops.glm_objective import GLMBatch, GLMObjective
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.optimization import minimize_tron
+    from photon_ml_tpu.types import TaskType
+
+    l2 = tron_glm.optimizer_of(cell[0]["fixed"]["optimizer"])["l2"]
+    with jax.enable_x64(False):
+        obj = GLMObjective(loss_for_task(TaskType.LOGISTIC_REGRESSION))
+        recording = _Recording(obj)
+        batch = GLMBatch(DenseFeatures(problem.x), problem.labels,
+                         problem.offsets, problem.weights)
+        res = minimize_tron(
+            obj.value, jnp.zeros(D, jnp.float32), args=(batch, l2),
+            max_iter=5, tol=1e-9, make_hvp=obj.make_tron_hvp_at_margins,
+            margins_value_and_grad=recording)
+        jax.effects_barrier()
+        at, z = next((a, b) for a, b in reversed(recording.seen)
+                     if np.array_equal(a, np.asarray(res.x)))
+        fresh = np.asarray(obj.margins(jnp.asarray(at), batch))
+    assert int(res.iterations) == int(res.attempted_iterations) == 5
+    assert int(res.cg_iterations) == 100  # every CG at its cap
+    assert len(recording.seen) == 6  # the first point and five trials
+    drift = np.linalg.norm(z - fresh) / np.linalg.norm(fresh)
+    assert drift < 2e-6, drift  # 2.5e-7 read here
+
+
+_COUNTED = collections.Counter()
+
+
+def _tick(kind):
+    jax.debug.callback(lambda: _COUNTED.update([kind]))
+
+
+@jax.custom_vjp
+def _counted_matvec(inner, v):
+    _tick("matvec")
+    return inner.matvec(v)
+
+
+def _counted_matvec_fwd(inner, v):
+    return _counted_matvec(inner, v), inner
+
+
+def _counted_matvec_bwd(inner, u):
+    _tick("rmatvec")
+    return jax.tree.map(jnp.zeros_like, inner), inner.rmatvec(u)
+
+
+_counted_matvec.defvjp(_counted_matvec_fwd, _counted_matvec_bwd)
+
+
+@jax.tree_util.register_pytree_node_class
+class CountingFeatures:
+    """A feature matrix that counts, as they run, its matvecs and rmatvecs,
+    those that differentiation makes of a matvec among them."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    @property
+    def shape(self):
+        return self.inner.shape
+
+    def matvec(self, v):
+        return _counted_matvec(self.inner, v)
+
+    def rmatvec(self, u):
+        _tick("rmatvec")
+        return self.inner.rmatvec(u)
+
+    def tree_flatten(self):
+        return (self.inner,), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
+@pytest.mark.parametrize("path, per_step", [
+    ("carried", 1), ("point", 3), ("bounded", 5)])
+def test_feature_passes_are_the_passes_the_solve_ran(path, per_step):
+    """``OptimizerResult.feature_passes`` against the matvecs and rmatvecs
+    counted as they ran: ``2 + T + 2 K`` where the loop carries the margins
+    (``solve_glm`` without bounds), ``2 + 3 T + 2 K`` with the point's
+    product alone, ``2 + 5 T + 2 K`` under bounds (``solve_glm`` with
+    them: the realized step's product too)."""
+    from photon_ml_tpu.ops.glm_objective import GLMBatch
+    from photon_ml_tpu.optimization import minimize_tron
+    from photon_ml_tpu.optimization.solver import solve_glm
+
+    with jax.enable_x64(True):
+        obj, plain = _glm_problem("LOGISTIC_REGRESSION", "dense")
+        batch = GLMBatch(CountingFeatures(plain.features), plain.labels,
+                         plain.offsets, plain.weights)
+        d = plain.features.shape[1]
+        x0 = jnp.zeros(d)
+        _COUNTED.clear()
+        if path == "point":
+            res = minimize_tron(obj.value, x0, args=(batch, 0.5), max_iter=6,
+                                tol=1e-12, make_hvp=obj.make_tron_hvp)
+        else:
+            bounds = ((jnp.full(d, -0.8), jnp.full(d, 0.8))
+                      if path == "bounded" else (None, None))
+            res = solve_glm(obj, batch, _tron_config(), x0, *bounds)
+        jax.effects_barrier()
+    t, k = int(res.attempted_iterations), int(res.cg_iterations)
+    assert t >= 3 and k >= t
+    assert _COUNTED["matvec"] + _COUNTED["rmatvec"] == int(
+        res.feature_passes) == 2 + per_step * t + 2 * k
+    # one rmatvec a gradient and a product: the first, a trial's, a CG
+    # step's and under bounds the realized step's
+    assert _COUNTED["rmatvec"] == 1 + t + k + (t if path == "bounded" else 0)
+
+
+def test_the_jvp_of_grad_product_counts_no_passes():
+    from photon_ml_tpu.optimization import minimize_tron
+
+    with jax.enable_x64(True):
+        obj, batch = _glm_problem("LOGISTIC_REGRESSION", "dense")
+        res = minimize_tron(obj.value, jnp.zeros(12), args=(batch, 0.5),
+                            max_iter=3)
+    assert res.feature_passes is None and int(res.cg_iterations) > 0
+
+
+def test_carried_margins_refuse_bounds_and_a_missing_product():
+    from photon_ml_tpu.optimization import minimize_tron
+
+    with jax.enable_x64(True):
+        obj, batch = _glm_problem("LOGISTIC_REGRESSION", "dense")
+        for kw in ({"lower_bounds": jnp.full(12, -1.0),
+                    "make_hvp": obj.make_tron_hvp_at_margins}, {}):
+            with pytest.raises(ValueError, match="carried margins"):
+                minimize_tron(
+                    obj.value, jnp.zeros(12), args=(batch, 0.5),
+                    margins_value_and_grad=obj.margins_value_and_grad, **kw)
+
+
+# Bitwise what the trust-region body gave before the loop could carry the
+# margins (float64 on the CPU), where it does not: under bounds, with the
+# point's product alone and for an objective that is no GLM.
+UNCARRIED = {
+    "bounded": {
+        "x": [
+            "0x1.999999999999ap-1",
+            "-0x1.999999999999ap-1",
+            "-0x1.999999999999ap-1",
+            "-0x1.65f278f212264p-3",
+            "0x1.999999999999ap-1",
+            "0x1.999999999999ap-1",
+            "-0x1.999999999999ap-1",
+            "0x1.999999999999ap-1",
+            "0x1.3ca8ea6014923p-1",
+            "-0x1.523c217596a08p-2",
+            "0x1.999999999999ap-1",
+            "0x1.999999999999ap-1"],
+        "value": "0x1.5797d7b660c3dp+7",
+        "counts": [6, 6, 7]},
+    "point": {
+        "x": [
+            "0x1.54a9c04c0fe94p+0",
+            "-0x1.366eee35e8767p+0",
+            "-0x1.0592949b1c25bp+1",
+            "-0x1.16d47687e1a5ep-2",
+            "0x1.2f74ee94ed5e0p+0",
+            "0x1.9067fa4eac54bp-1",
+            "-0x1.dddabf477f9dep+0",
+            "0x1.0013c33317bc3p+1",
+            "0x1.5181259abfb74p-1",
+            "-0x1.ecfee5f22b5eap-2",
+            "0x1.0c9f1861e1187p+0",
+            "0x1.d900eb685a079p+0"],
+        "value": "0x1.430fd065b8a7cp+7",
+        "counts": [6, 6, 12]},
+    "not_glm": {
+        "x": [
+            "-0x1.cdeb083def580p-11",
+            "0x1.1c1d522a3b900p-10"],
+        "value": "0x1.0000082ebc774p+1",
+        "counts": [3, 4, 5]},
+}
+
+
+def _uncarried(path):
+    from photon_ml_tpu.optimization import minimize_tron
+    from photon_ml_tpu.optimization.solver import solve_glm
+
+    with jax.enable_x64(True):
+        if path == "not_glm":
+            res = minimize_tron(
+                lambda x: jnp.sqrt(1 + x[0] ** 2) + jnp.sqrt(1 + x[1] ** 2),
+                jnp.asarray([2.0, 1.5]), max_iter=3, tol=1e-30)
+        else:
+            obj, batch = _glm_problem("LOGISTIC_REGRESSION", "dense")
+            x0 = jnp.zeros(12)
+            if path == "bounded":
+                res = solve_glm(obj, batch, _tron_config(), x0,
+                                jnp.full(12, -0.8), jnp.full(12, 0.8))
+            else:
+                res = minimize_tron(obj.value, x0, args=(batch, 0.5),
+                                    max_iter=6, tol=1e-12,
+                                    make_hvp=obj.make_tron_hvp)
+    return {"x": [float(v).hex() for v in np.asarray(res.x)],
+            "value": float(res.value).hex(),
+            "counts": [int(res.iterations), int(res.attempted_iterations),
+                       int(res.cg_iterations)]}
+
+
+@pytest.mark.parametrize("path", sorted(UNCARRIED))
+def test_the_uncarried_paths_are_bitwise_as_they_were(path):
+    assert _uncarried(path) == UNCARRIED[path]
 
 
 # -- the configuration --------------------------------------------------------
