@@ -14,7 +14,9 @@ This kernel runs the ENTIRE solve — margins, batched-Armijo line search,
 two-loop direction, cautious history updates, convergence bookkeeping —
 for 128 entities per grid step, with all state resident in VMEM/registers.
 The only HBM traffic is one read of the entity block and one write of the
-results. Grid steps pipeline across entity tiles.
+results. Grid steps pipeline across entity tiles. Every loop over the
+bucket's r rows is rolled (_row_loop: steps of ROWS_PER_STEP rows), so
+the traced body is the same size at any r of two steps or more.
 
 Layout: entities along the 128-lane axis; every array the kernel touches
 is 2-D [sublanes, 128] (Mosaic's native vreg shape — 3-D contractions do
@@ -60,6 +62,7 @@ from photon_ml_tpu.optimization.convergence import (
 )
 from photon_ml_tpu.optimization.owlqn import pseudo_gradient
 from photon_ml_tpu.telemetry import scopes
+from photon_ml_tpu.utils.compile_cache import note_kernel_body
 
 Array = jax.Array
 
@@ -79,13 +82,30 @@ VMEM_GUARD_BYTES = 10 << 20
 
 # Scoped-VMEM limit handed to Mosaic. Its default on v5e is 16 MiB, and
 # the kernel's real working set — the window buffers
-# entity_solver_vmem_bytes estimates, plus Mosaic's own stack for the
-# unrolled row loop and the per-row line-search blocks — runs 2-4x that
-# estimate: at the default the v5e compiler refused buckets the routing
-# guard admits (r=256 d=32, r=512 d=8, r=4 d=512). Half of v5e's 128 MiB
-# VMEM compiles everything VMEM_GUARD_BYTES lets through;
-# tests/test_mosaic_aot.py pins that boundary with the real compiler.
+# entity_solver_vmem_bytes estimates, plus Mosaic's own stack — ran 2-4x
+# that estimate while the row loops were unrolled: at the default the v5e
+# compiler refused buckets the routing guard admits (r=256 d=32, r=512
+# d=8, r=4 d=512). Half of v5e's 128 MiB VMEM compiles everything
+# VMEM_GUARD_BYTES lets through; tests/test_mosaic_aot.py pins that
+# boundary with the real compiler and reads the rolled kernel's need.
 VMEM_LIMIT_BYTES = 64 << 20
+
+# Rows a chunk of the kernels' row loops handles: one sublane tile of an
+# [r, L] block.
+ROW_CHUNK = 8
+# Rows a step of the rolled row loops covers: ROWS_PER_STEP / ROW_CHUNK
+# chunks unrolled inside one step. A step of 8 rows costs the L-BFGS
+# kernel ~25% of its time on a v5e (each step pays its reductions'
+# latency); at 64 the compiler's schedule keeps L-BFGS and OWL-QN within
+# 3% of the unrolled body's bundles (TRON at width 8: +10-15%), and a
+# bucket of fewer than 128 rows stays a static unroll.
+ROWS_PER_STEP = 64
+# [r, L] VMEM scratch blocks a kernel gets: the blocks its row loops
+# build or read by chunk of rows. Every kernel's _row_sweeps takes one;
+# the L-BFGS/OWL-QN line search's price reads the margins and the
+# direction's products from two more, TRON's product its curvature
+# weights from one.
+_SCRATCH_ROWS = {"lbfgs": 3, "owlqn": 3, "tron": 2}
 
 
 def entity_solver_vmem_bytes(
@@ -99,7 +119,8 @@ def entity_solver_vmem_bytes(
     Normalization adds double-buffered factor/shift tiles; bounds add
     lower/upper tiles. Keep callers' eligibility checks on THIS function
     so the guard and the kernel cannot disagree about the working set.
-    What Mosaic really allocates is 2-4x this (see VMEM_LIMIT_BYTES)."""
+    What Mosaic really allocates is 1.25-1.75x this at the guard's
+    extremes (tests/test_mosaic_aot.py; see VMEM_LIMIT_BYTES)."""
     units = 2 * r * d + 2 * m * d + 8 * d + 8 * r + 2 * (max_line_search + 1)
     units += 2  # scalars / slack
     if normalized:
@@ -122,6 +143,14 @@ class _KState(NamedTuple):
     reason: Array  # [1, L] i32
     gnorm: Array  # [1, L]
     k: Array  # scalar i32 loop counter
+
+
+def _count_eqns(jaxpr) -> int:
+    """Equations of a jaxpr, those of the jaxprs inside its equations
+    (loop bodies, branches) included."""
+    return sum(1 + sum(_count_eqns(sub)
+                       for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
 
 
 def _rsum(a):
@@ -181,6 +210,76 @@ def _sel(mask, a, b):
     return b + m * (a - b)
 
 
+def _row_loop(r, chunk, carry):
+    """``carry = chunk(start, n, carry)`` over the rows 0..r-1 in order, n
+    rows a call, n = ROW_CHUNK but for the last: ``lax.fori_loop`` over
+    steps of ROWS_PER_STEP rows (``start`` traced, a multiple of
+    ROW_CHUNK, so every [n, L] access is whole sublane tiles), then the
+    rows left after the last whole step, static. A traced body holds one
+    step's rows whatever r is; a bucket of fewer than two steps' rows is
+    a static unroll of its rows."""
+
+    def chunks(start, rows, carry):
+        for k in range(0, rows, ROW_CHUNK):
+            at = (start + k if isinstance(start, int)
+                  else pl.multiple_of(start + k, ROW_CHUNK))
+            carry = chunk(at, min(ROW_CHUNK, rows - k), carry)
+        return carry
+
+    steps = r // ROWS_PER_STEP
+    if steps < 2:
+        return chunks(0, r, carry)
+    carry = lax.fori_loop(
+        0, steps, lambda s, c: chunks(s * ROWS_PER_STEP, ROWS_PER_STEP, c),
+        carry)
+    return chunks(steps * ROWS_PER_STEP, r - steps * ROWS_PER_STEP, carry)
+
+
+def _rows_of(ref, start, n):
+    """Rows start..start+n-1 of an [r, L] ref."""
+    return ref[pl.ds(start, n), :]
+
+
+def _normalize_rows(r, x_ref, fac, shf):
+    """x' = (x - shift) .* factor over the block's r rows, in place in
+    ``x_ref`` (this grid step's window of x, which nothing reads again),
+    once before any sweep reads a row."""
+
+    def chunk(start, n, carry):
+        for j in range(n):
+            x_ref[start + j] = (x_ref[start + j] - shf) * fac
+        return carry
+
+    _row_loop(r, chunk, ())
+
+
+def _row_sweeps(r, x_ref, rows_ref):
+    """The kernels' two sweeps over the bucket's rows, read from
+    ``x_ref`` chunk by chunk. ``products(c)`` is the [r, L] block of
+    <x_i, c>, built in ``rows_ref``; ``accumulate(g, u_ref)`` is
+    g + sum_i x_i u_i with the rows added in order 0..r-1."""
+
+    def products(c):
+        def chunk(start, n, carry):
+            rows_ref[pl.ds(start, n), :] = jnp.concatenate(
+                [_rsum(x_ref[start + j] * c) for j in range(n)], axis=0)
+            return carry
+
+        _row_loop(r, chunk, ())
+        return rows_ref[:]
+
+    def accumulate(g, u_ref):
+        def chunk(start, n, g):
+            u = _rows_of(u_ref, start, n)
+            for j in range(n):
+                g = g + x_ref[start + j] * u[j:j + 1]
+            return g
+
+        return _row_loop(r, chunk, g)
+
+    return products, accumulate
+
+
 def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
                  m: int, c1: float, max_line_search: int,
                  owlqn: bool = False, normalized: bool = False,
@@ -195,7 +294,8 @@ def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
     def kernel(l2_ref, l1_ref, x_ref, y_ref, off_ref, w_ref, c0_ref,
                *refs):
         # Optional inputs trail the fixed seven, in declaration order:
-        # [factor, shift] when normalized, [lower, upper] when bounded.
+        # [factor, shift] when normalized, [lower, upper] when bounded;
+        # then the five outputs and the [r, L] scratch (_SCRATCH_ROWS).
         i = 0
         if normalized:
             factor_ref, shift_ref = refs[i], refs[i + 1]
@@ -204,25 +304,23 @@ def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
             lb_ref, ub_ref = refs[i], refs[i + 1]
             i += 2
         (out_c_ref, out_f_ref, out_gnorm_ref, out_it_ref,
-         out_reason_ref) = refs[i:]
+         out_reason_ref, rows_ref, z_ref, zp_ref) = refs[i:]
 
         yv = y_ref[:]  # [r, L]
         off = off_ref[:]
         w = w_ref[:]
         l2 = l2_ref[0]
         l1 = l1_ref[0]
-        x_rows = [x_ref[i] for i in range(r)]  # each [d, L]
         if normalized:
             # Normalization folds in as a one-time transform of the x
-            # rows already resident in VMEM: x' = (x - shift) .* factor
+            # block resident in VMEM: x' = (x - shift) .* factor
             # (data/normalization.py's algebra, NormalizationContext.
             # scala:38-83). Everything downstream — margins, gradients,
             # curvature, the line search — is the plain un-normalized
             # kernel on x'. Solve-space coefficients; the coordinate
             # back-transforms outside.
-            fac = factor_ref[:]  # [d, L]
-            shf = shift_ref[:]
-            x_rows = [(xr - shf) * fac for xr in x_rows]
+            _normalize_rows(r, x_ref, factor_ref[:], shift_ref[:])
+        products, accumulate = _row_sweeps(r, x_ref, rows_ref)
         if bounded:
             lb = lb_ref[:]  # [d, L]
             ub = ub_ref[:]
@@ -231,18 +329,14 @@ def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
                 return jnp.minimum(jnp.maximum(c, lb), ub)
 
         def margins(c):
-            return jnp.concatenate(
-                [_rsum(x_rows[i] * c) for i in range(r)], axis=0) + off
+            return products(c) + off
 
         def value_from(z, csq):
             return _rsum(w * loss.loss(z, yv)) + 0.5 * l2 * csq
 
         def grad_from(c, z):
-            u = w * loss.d1(z, yv)  # [r, L]
-            g = l2 * c
-            for i in range(r):
-                g = g + x_rows[i] * u[i:i + 1]
-            return g
+            rows_ref[:] = w * loss.d1(z, yv)  # [r, L]
+            return accumulate(l2 * c, rows_ref)
 
         def pseudo_grad(c, g):
             # optimization/owlqn.py's pseudo_gradient is pure elementwise
@@ -474,6 +568,9 @@ def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
             direction = _sel(dg >= 0, -st.g, direction)
 
             zp = margins(direction) - off  # [r, L]
+            # price() reads both by chunk of rows
+            z_ref[:] = st.z
+            zp_ref[:] = zp
             xx = _rsum(st.c * st.c)
             xp = _rsum(st.c * direction)
             pp = _rsum(direction * direction)
@@ -492,11 +589,18 @@ def _make_kernel(loss: PointwiseLoss, *, r: int, max_iter: int, tol: float,
             # tier 1 (lax.cond — the tail's r-row sweep is the single
             # most expensive block in the kernel).
             def price(ts):
-                data_t = jnp.zeros_like(ts)
-                for i in range(r):
-                    z_ti = st.z[i:i + 1] + ts * zp[i:i + 1]  # [T, L]
-                    data_t = data_t + w[i:i + 1] * loss.loss(
-                        z_ti, yv[i:i + 1])
+                def chunk(start, n, data_t):
+                    zc, zpc = _rows_of(z_ref, start, n), _rows_of(
+                        zp_ref, start, n)
+                    wc, yc = _rows_of(w_ref, start, n), _rows_of(
+                        y_ref, start, n)
+                    for j in range(n):
+                        z_ti = zc[j:j + 1] + ts * zpc[j:j + 1]  # [T, L]
+                        data_t = data_t + wc[j:j + 1] * loss.loss(
+                            z_ti, yc[j:j + 1])
+                    return data_t
+
+                data_t = _row_loop(r, chunk, jnp.zeros_like(ts))
                 csq_t = xx + 2.0 * ts * xp + ts * ts * pp
                 f_t = data_t + 0.5 * l2 * csq_t
                 armijo = jnp.logical_and(f_t <= st.f + c1 * ts * gp,
@@ -583,7 +687,7 @@ def _make_tron_kernel(loss: PointwiseLoss, *, r: int, max_iter: int,
     interpolation, improvement-failure budget), vectorized over lanes
     with a nested masked CG while-loop. The Gauss-Newton product uses
     margin-cached curvature weights computed once per outer iteration:
-    Hv = X^T (d2w * (X v)) + l2 v — two r-row sweeps per CG step.
+    Hv = X^T (d2w * (X v)) + l2 v — one r-row sweep per CG step.
     Normalization folds in as the same one-time x' = (x - shift).*factor
     transform as the L-BFGS kernel (margins, gradients and Hv all see
     x'). Box constraints mirror optimization/tron.py's projected variant
@@ -608,16 +712,14 @@ def _make_tron_kernel(loss: PointwiseLoss, *, r: int, max_iter: int,
             lb_ref, ub_ref = refs[i], refs[i + 1]
             i += 2
         (out_c_ref, out_f_ref, out_gnorm_ref, out_it_ref,
-         out_reason_ref) = refs[i:]
+         out_reason_ref, rows_ref, d2w_ref) = refs[i:]
         yv = y_ref[:]
         off = off_ref[:]
         w = w_ref[:]
         l2 = l2_ref[0]
-        x_rows = [x_ref[i] for i in range(r)]
         if normalized:
-            fac = factor_ref[:]
-            shf = shift_ref[:]
-            x_rows = [(xr - shf) * fac for xr in x_rows]
+            _normalize_rows(r, x_ref, factor_ref[:], shift_ref[:])
+        products, accumulate = _row_sweeps(r, x_ref, rows_ref)
         if bounded:
             lb = lb_ref[:]  # [d, L]
             ub = ub_ref[:]
@@ -626,18 +728,14 @@ def _make_tron_kernel(loss: PointwiseLoss, *, r: int, max_iter: int,
                 return jnp.minimum(jnp.maximum(c, lb), ub)
 
         def margins(c):
-            return jnp.concatenate(
-                [_rsum(x_rows[i] * c) for i in range(r)], axis=0) + off
+            return products(c) + off
 
         def value_from(z, csq):
             return _rsum(w * loss.loss(z, yv)) + 0.5 * l2 * csq
 
         def grad_from(c, z):
-            u = w * loss.d1(z, yv)
-            g = l2 * c
-            for i in range(r):
-                g = g + x_rows[i] * u[i:i + 1]
-            return g
+            rows_ref[:] = w * loss.d1(z, yv)
+            return accumulate(l2 * c, rows_ref)
 
         def stat_norm(c, g):
             # Stationarity: raw gradient norm unconstrained, projected-
@@ -673,16 +771,21 @@ def _make_tron_kernel(loss: PointwiseLoss, *, r: int, max_iter: int,
             active = reason == not_conv
 
             # Curvature weights once per outer iteration (margin-cached).
-            d2w = w * loss.d2(z, yv)  # [r, L]
+            d2w_ref[:] = w * loss.d2(z, yv)  # [r, L]
 
             def hvp(v):
-                u = jnp.concatenate(
-                    [_rsum(x_rows[i] * v) for i in range(r)], axis=0)
-                u = d2w * u
-                hv = l2 * v
-                for i in range(r):
-                    hv = hv + x_rows[i] * u[i:i + 1]
-                return hv
+                # One sweep: a chunk's products <x_i, v>, weighted, then
+                # added back row by row (the same operations, in the same
+                # order, as a sweep of products and then one of sums).
+                def chunk(start, n, hv):
+                    x = [x_ref[start + j] for j in range(n)]
+                    u = _rows_of(d2w_ref, start, n) * jnp.concatenate(
+                        [_rsum(xj * v) for xj in x], axis=0)
+                    for j in range(n):
+                        hv = hv + x[j] * u[j:j + 1]
+                    return hv
+
+                return _row_loop(r, chunk, l2 * v)
 
             if bounded:
                 # Active-set reduction (tron.py:174-188): coordinates
@@ -969,6 +1072,7 @@ def pallas_entity_lbfgs(
                             lambda i: (0,) * len(trail) + (i,),
                             memory_space=pltpu.VMEM)
 
+    name = scopes.KERNEL if mode == "lbfgs" else f"{scopes.KERNEL}_{mode}"
     out_shapes = (
         jax.ShapeDtypeStruct((d, ep), dtype),   # coef
         jax.ShapeDtypeStruct((1, ep), dtype),   # value
@@ -976,7 +1080,7 @@ def pallas_entity_lbfgs(
         jax.ShapeDtypeStruct((1, ep), jnp.int32),  # iterations
         jax.ShapeDtypeStruct((1, ep), jnp.int32),  # reason
     )
-    c_l, f_l, gn_l, it_l, reason_l = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -986,17 +1090,31 @@ def pallas_entity_lbfgs(
         ] + [bspec(d) for _ in extra_inputs],
         out_specs=(bspec(d), bspec(1), bspec(1), bspec(1), bspec(1)),
         out_shape=out_shapes,
+        scratch_shapes=[pltpu.VMEM((r, LANES), dtype)] * _SCRATCH_ROWS[mode],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         # The device trace's event for this kernel: the benchmark sums
         # the operations whose name starts with scopes.KERNEL (a mode
         # suffix may follow it, another prefix may not).
-        name=(scopes.KERNEL if mode == "lbfgs"
-              else f"{scopes.KERNEL}_{mode}"),
-    )(jnp.asarray(l2_weight, dtype).reshape(1),
-      jnp.asarray(l1_weight, dtype).reshape(1),
-      x_l, y_l, off_l, w_l, c0_l, *extra_inputs)
+        name=name,
+    )
+    args = (jnp.asarray(l2_weight, dtype).reshape(1),
+            jnp.asarray(l1_weight, dtype).reshape(1),
+            x_l, y_l, off_l, w_l, c0_l, *extra_inputs)
+    # The kernel body is traced once, here; the call is then bound from
+    # that trace, and the compile ledger reads the body's size off it.
+    # Every driver's metrics.json carries that ledger (``compile``), so a
+    # set-up that grows can be told from a body that grew with its rows
+    # again: a body's trace time follows its equations.
+    traced = jax.make_jaxpr(call)(*args)
+    (body,) = [eqn.params["jaxpr"] for eqn in traced.jaxpr.eqns
+               if eqn.primitive.name == "pallas_call"]
+    note_kernel_body(name, variant=mode + "+norm" * normalized
+                     + "+bounds" * bounded, rows=r, width=d,
+                     eqns=_count_eqns(body))
+    c_l, f_l, gn_l, it_l, reason_l = jax.core.eval_jaxpr(
+        traced.jaxpr, traced.consts, *args)
 
     return OptimizerResult(
         x=jnp.moveaxis(c_l, -1, 0)[:e],

@@ -31,7 +31,7 @@ import re
 import threading
 import time
 from pathlib import Path
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 _DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
@@ -67,6 +67,8 @@ _unclaimed = {"retrieval_s": 0.0, "cache_hits": 0}
 # (``note_partitions``): JAX's events carry a function's name and no more.
 _partitions: Dict[str, int] = {}
 _layouts: Dict[str, dict] = {}
+# One record a kernel body traced (``note_kernel_body``), in trace order.
+_kernel_bodies: List[dict] = []
 # The instruction table of the executable a function's owner handed over
 # last (``note_instructions``): strings and three numbers, nothing of JAX.
 _instructions: Dict[str, dict] = {}
@@ -134,6 +136,20 @@ def note_layout(fun_name: str, layout: str, coded_slots: int = 0,
         _layouts[str(fun_name)] = dict(
             fe_layout=str(layout), fe_coded_slots=int(coded_slots),
             fe_coded_entries=int(coded_entries))
+
+
+def note_kernel_body(kernel: str, variant: str, rows: int, width: int,
+                     eqns: int) -> None:
+    """The owner of a Pallas kernel says, each time it traces the kernel's
+    body, the body's static shape and how many equations its jaxpr holds
+    (those inside its loops included): the ledger's ``kernel_bodies``
+    lists one ``{kernel, variant, rows, width, eqns}`` a trace. Tracing
+    time goes with the equations, so this says what a body costs to trace
+    and whether that grows with its rows."""
+    with _lock:
+        _kernel_bodies.append(dict(kernel=str(kernel), variant=str(variant),
+                                   rows=int(rows), width=int(width),
+                                   eqns=int(eqns)))
 
 
 # Instructions that run the computations they name as device events of
@@ -319,7 +335,9 @@ def compile_ledger(top: Optional[int] = None) -> dict:
     ``scoped_instructions`` and ``instructions_s`` where its owner handed
     over its executable (``note_instructions``).
     Names are the jitted functions' (``cd_block``), as JAX reports them.
-    Totals add ``cache_requests``; requests minus hits were compiled."""
+    Totals add ``cache_requests``; requests minus hits were compiled.
+    ``kernel_bodies``: a record a Pallas kernel body traced
+    (``note_kernel_body``), in trace order."""
     with _lock:
         rows = {k: dict(v, partitions=_partitions.get(k, 1))
                 for k, v in _functions.items()}
@@ -332,11 +350,12 @@ def compile_ledger(top: Optional[int] = None) -> dict:
                                scoped_instructions=table["scoped"],
                                instructions_s=table["seconds"])
         totals = dict(_totals)
+        bodies = [dict(b) for b in _kernel_bodies]
     if top is not None:
         cost = lambda r: r["trace_s"] + r["lower_s"] + r["backend_s"]
         keep = sorted(rows, key=lambda k: -cost(rows[k]))[:top]
         rows = {k: rows[k] for k in keep}
-    return {"functions": rows, "totals": totals}
+    return {"functions": rows, "totals": totals, "kernel_bodies": bodies}
 
 
 def reset_compile_ledger() -> None:
@@ -345,6 +364,7 @@ def reset_compile_ledger() -> None:
         _partitions.clear()
         _layouts.clear()
         _instructions.clear()
+        _kernel_bodies.clear()
         _totals.update(_new_totals())
         _unclaimed.update(retrieval_s=0.0, cache_hits=0)
 

@@ -491,3 +491,50 @@ def test_mesh_sharded_coordinate_with_shift_normalization(rng):
                                   jax.random.PRNGKey(0))
     assert any(np.abs(np.asarray(c)).max() > 1e-5
                for c in model.local_coefs)
+
+
+# Rows past the kernel's chunk of 8, its loop stepping 8 rows at a time:
+# one static chunk and a tail (12), a rolled loop and a tail (20);
+# normalization applied where a row is read.
+@pytest.mark.parametrize("mode,opt", [
+    ("lbfgs", OptimizerType.LBFGS),
+    ("tron", OptimizerType.TRON),
+])
+@pytest.mark.parametrize("r", [12, 20])
+def test_kernel_bounds_with_normalization_across_row_chunks(
+        rng, monkeypatch, mode, opt, r):
+    from photon_ml_tpu.ops import pallas_entity_solver as K
+
+    monkeypatch.setattr(K, "ROWS_PER_STEP", 8)  # read at trace time
+    K.pallas_entity_lbfgs.clear_cache()
+    dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+    e, d = 17, 4
+    scale = np.array([1.0, 8.0, 0.2, 3.0])
+    x, y, off, w = _bucket(rng, e, r, d, dtype, scale=scale)
+    factors, shifts = _standardization_arrays(rng, e, r, d, x, dtype)
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+    obj = GLMObjective(loss)
+    cfg = GLMOptimizationConfiguration(
+        max_iterations=40, tolerance=1e-8, regularization_weight=0.5,
+        regularization_context=RegularizationContext(RegularizationType.L2),
+        optimizer_type=opt)
+    coef0 = jnp.zeros((e, d), dtype)
+    lb = jnp.full((e, d), -0.08, dtype)
+    ub = jnp.full((e, d), 0.15, dtype)
+
+    res_k = pallas_entity_lbfgs(
+        loss, jnp.asarray(x), jnp.asarray(y), jnp.asarray(off),
+        jnp.asarray(w), coef0, 0.5, factors=factors, shifts=shifts,
+        lower=lb, upper=ub, max_iter=40, tol=1e-8, mode=mode,
+        interpret=True)
+    res_v = _vmapped(obj, cfg, jnp.asarray(x), jnp.asarray(y),
+                     jnp.asarray(off), jnp.asarray(w), coef0,
+                     factors=factors, shifts=shifts, lb=lb, ub=ub)
+    monkeypatch.undo()
+    K.pallas_entity_lbfgs.clear_cache()
+
+    np.testing.assert_allclose(np.asarray(res_k.value),
+                               np.asarray(res_v.value),
+                               rtol=gold(1e-7, f32_floor=2e-4))
+    np.testing.assert_allclose(np.asarray(res_k.x), np.asarray(res_v.x),
+                               atol=gold(1e-5, f32_floor=8e-3))

@@ -226,6 +226,56 @@ def test_compiles_for_v5e(one_chip, tpu_lowering, build, args, has_kernel):
     assert (KERNEL_MARKER in compiled.as_text()) == has_kernel
 
 
+# A bucket over several grid steps, so that the windows are double-buffered
+# as in a real bucket's solve (at 128 entities, one step, the compiler places
+# whole operands in VMEM outside the scoped limit).
+VMEM_NEED_ENTITIES = 1024
+
+
+@pytest.mark.parametrize("mode,nb,which", [
+    ("lbfgs", PLAIN, "max_r"), ("lbfgs", NORM_BOUNDS, "max_d"),
+    ("lbfgs", PLAIN, "max_estimate")])
+def test_guard_extreme_vmem_need(one_chip, tpu_lowering, monkeypatch,
+                                 record_property, mode, nb, which):
+    """The scoped VMEM the compiler needs for the kernel at each of the
+    guard's extremes, against ``entity_solver_vmem_bytes``: the least limit,
+    in eighths of the estimate, at which it compiles (recorded as
+    ``vmem_need_over_estimate``). With its row loops rolled the kernel
+    compiles from 1.75x (max_r), 1.5x (max_d) and 1.25x (max_estimate);
+    with them unrolled it needed 3.99x, 1.72x and 1.95x. Twice the
+    estimate must do, well inside ``VMEM_LIMIT_BYTES``."""
+    from photon_ml_tpu.ops import pallas_entity_solver as K
+
+    r, d = _guard_extreme(which, nb)
+    estimate = entity_solver_vmem_bytes(r, d, 4, normalized=nb, bounded=nb)
+    assert 2 * estimate < K.VMEM_LIMIT_BYTES
+
+    def compiles(eighths):
+        # the limit is read when the kernel is traced
+        monkeypatch.setattr(K, "VMEM_LIMIT_BYTES", estimate * eighths // 8)
+        K.pallas_entity_lbfgs.clear_cache()
+        try:
+            _compile_kernel(one_chip, mode, VMEM_NEED_ENTITIES, r, d, nb)
+        except Exception as e:  # noqa: BLE001 — the compiler's refusal
+            if "vmem" not in str(e).lower():
+                raise
+            return False
+        return True
+
+    try:
+        lo, hi = 0, 16
+        assert compiles(hi)
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            lo, hi = (lo, mid) if compiles(mid) else (mid, hi)
+    finally:
+        monkeypatch.undo()
+        K.pallas_entity_lbfgs.clear_cache()
+    record_property("vmem_need_over_estimate", hi / 8)
+    print(f"r={r} d={d}: compiles from {hi / 8:.3f}x the estimate "
+          f"({estimate * hi / 8 / 2**20:.2f} of {estimate / 2**20:.2f} MiB)")
+
+
 def test_kernel_compiles_under_shard_map(topo, tpu_lowering):
     compiled = _kernel_under_shard_map(topo)
     assert KERNEL_MARKER in compiled.as_text()

@@ -5,6 +5,8 @@ the random-effect coordinate builds, and checks solutions match the
 portable solver to solver tolerance.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -556,3 +558,108 @@ def test_vmem_oversize_bucket_keeps_vmapped_path(monkeypatch, rng):
     big = jax.ShapeDtypeStruct((100, 400, 128), jnp.float32)  # ~26 MB tile
     assert _use_pallas_entity_solver(obj, cfg, small)
     assert not _use_pallas_entity_solver(obj, cfg, big)
+
+
+@pytest.fixture
+def rows_per_step(monkeypatch):
+    """Set the kernel's rows per loop step for one test: read when the
+    kernel is traced, so the jitted entry point's traces are dropped on
+    the way in and out."""
+    from photon_ml_tpu.ops import pallas_entity_solver as K
+
+    def set_step(rows):
+        if rows is not None:
+            monkeypatch.setattr(K, "ROWS_PER_STEP", rows)
+        K.pallas_entity_lbfgs.clear_cache()
+
+    yield set_step
+    monkeypatch.undo()
+    K.pallas_entity_lbfgs.clear_cache()
+
+
+# Rows against the kernel's row loops (chunks of ROW_CHUNK = 8 rows, steps
+# of ROWS_PER_STEP): fewer than a chunk (4), a chunk and a tail (12), a
+# rolled loop of 8-row steps and a tail (20), of 16-row steps and a tail
+# (36), and at the kernel's own step (None: 136 rows, two steps and a
+# chunk).
+@pytest.mark.parametrize("mode,r,step", [
+    ("lbfgs", 4, 8), ("lbfgs", 12, 8), ("lbfgs", 20, 8), ("lbfgs", 36, 16),
+    ("lbfgs", 136, None), ("owlqn", 20, 8), ("tron", 20, 8)])
+def test_row_chunks_match_vmapped(rng, rows_per_step, mode, r, step):
+    rows_per_step(step)
+    dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+    e, d = 11, 5
+    x, y, off, w = _bucket(rng, e, r, d, dtype)
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+    obj = GLMObjective(loss)
+    reg, lam = RegularizationContext(RegularizationType.L2), 0.7
+    if mode == "owlqn":
+        reg, lam = RegularizationContext(
+            RegularizationType.ELASTIC_NET, 0.5), 1.5
+    cfg = GLMOptimizationConfiguration(
+        max_iterations=40, tolerance=1e-8, regularization_weight=lam,
+        regularization_context=reg,
+        optimizer_type=(OptimizerType.TRON if mode == "tron"
+                        else OptimizerType.LBFGS))
+    coef0 = np.zeros((e, d), dtype)
+
+    res_k = pallas_entity_lbfgs(
+        loss, jnp.asarray(x), jnp.asarray(y), jnp.asarray(off),
+        jnp.asarray(w), jnp.asarray(coef0), reg.l2_weight(lam),
+        reg.l1_weight(lam), max_iter=40, tol=1e-8, mode=mode,
+        interpret=True)
+
+    def fit_one(c0, xe, ye, oe, we):
+        return solve_glm(obj, GLMBatch(DenseFeatures(xe), ye, oe, we),
+                         cfg, c0)
+
+    res_v = jax.vmap(fit_one)(jnp.asarray(coef0), jnp.asarray(x),
+                              jnp.asarray(y), jnp.asarray(off),
+                              jnp.asarray(w))
+    # each mode's tolerances as its own parity test above has them
+    value_rtol, x_atol = {
+        "lbfgs": (gold(1e-8, f32_floor=1e-4), gold(1e-5, f32_floor=5e-3)),
+        "owlqn": (gold(1e-7, f32_floor=1e-4), gold(1e-6, f32_floor=5e-3)),
+        "tron": (gold(1e-7, f32_floor=2e-4), gold(1e-4, f32_floor=1e-2)),
+    }[mode]
+    np.testing.assert_allclose(np.asarray(res_k.value),
+                               np.asarray(res_v.value), rtol=value_rtol)
+    np.testing.assert_allclose(np.asarray(res_k.x), np.asarray(res_v.x),
+                               atol=x_atol)
+
+
+@pytest.mark.parametrize("variant", ["lbfgs", "owlqn", "tron",
+                                     "lbfgs+norm", "lbfgs+bounds"])
+def test_kernel_body_does_not_grow_with_rows(variant):
+    """The compile ledger's record of each kernel body traced: rows,
+    width and the equations of its jaxpr. From two loop steps on
+    (ROWS_PER_STEP rows each) the body holds one step's rows whatever r
+    is, so 256, 512 and 1,024 rows trace the same body, and 32 rows (a
+    static unroll) hold less than half as many equations as it does
+    rather than an eighth (unrolled, the body grew about eightfold from
+    32 rows to 256)."""
+    from photon_ml_tpu.ops.pallas_entity_solver import ROWS_PER_STEP
+    from photon_ml_tpu.utils import compile_cache
+
+    mode, _, extra = variant.partition("+")
+    e, d = 128, 32
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    kw = {"norm": {"factors": arg(e, d), "shifts": arg(e, d)},
+          "bounds": {"lower": arg(e, d), "upper": arg(e, d)}}.get(extra, {})
+    rows = (32, 256, 512, 1024)
+    assert 2 * ROWS_PER_STEP <= 256
+    bodies = []
+    for r in rows:
+        jax.make_jaxpr(functools.partial(
+            pallas_entity_lbfgs, loss_for_task(TaskType.LOGISTIC_REGRESSION),
+            max_iter=20, tol=1e-6, mode=mode, interpret=True, **kw))(
+            arg(e, r, d), arg(e, r), arg(e, r), arg(e, r), arg(e, d),
+            arg(), arg())
+        bodies.append(compile_cache.compile_ledger()["kernel_bodies"][-1])
+    assert [(b["rows"], b["width"], b["variant"]) for b in bodies] == [
+        (r, d, variant) for r in rows]
+    assert all(b["kernel"].startswith("pallas_entity_lbfgs")
+               for b in bodies)
+    eqns = [b["eqns"] for b in bodies]
+    assert eqns[1] == eqns[2] == eqns[3]
+    assert 0 < eqns[0] < eqns[1] < 2.5 * eqns[0]
